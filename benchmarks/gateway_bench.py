@@ -19,6 +19,7 @@ import common  # noqa: E402
 from repro.serve.gateway import frontend as fe  # noqa: E402
 from repro.serve.gateway.gateway import GatewayConfig, MicroBatchGateway  # noqa: E402
 from repro.serve.gateway.sensors import FleetConfig, SensorFleet  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--out", default=str(pathlib.Path(__file__).parent
                                          / "BENCH_gateway.json"))
     args = ap.parse_args()
+    enable_compile_cache()
     rates = [float(r) for r in args.rates.split(",")]
 
     results = []

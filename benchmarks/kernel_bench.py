@@ -79,4 +79,6 @@ def run(quiet: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -79,6 +79,7 @@ from repro.serve import engine  # noqa: E402
 from repro.serve.gateway.gateway import PromptGateway  # noqa: E402
 from repro.serve.gateway.sensors import Arrival  # noqa: E402
 from repro.serve.gateway.slots import ContinuousBatcher, make_adapter  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def kv_bytes_per_slot(cfg, max_len: int) -> int:
@@ -469,6 +470,7 @@ def main():
                          "the series to a vacuous 1-slice run)")
     ap.add_argument("--out", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     if not args.out:
         args.out = str(pathlib.Path(__file__).parent /
                        ("BENCH_disagg.json" if args.disagg

@@ -45,6 +45,7 @@ from repro.serve.gateway.gateway import (GatewayConfig, MicroBatchGateway,  # no
 from repro.serve.gateway.sensors import (Arrival, FleetConfig,  # noqa: E402
                                          SensorFleet)
 from repro.serve.gateway.slots import ContinuousBatcher, make_adapter  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 OVERHEAD_BUDGET = 0.05        # traced run may cost at most 5% wall-clock
 SLO_EXTRA_BUDGET = 0.01       # burn-rate evaluation may add at most 1% more
@@ -294,6 +295,7 @@ def main():
     ap.add_argument("--out", default=str(pathlib.Path(__file__).parent
                                          / "BENCH_obs.json"))
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.endpoints, args.duration, args.rate = 8, 1.0, 16.0
         args.repeats, args.lm_repeats = 8, 8

@@ -37,6 +37,7 @@ import numpy as np  # noqa: E402
 from repro import configs  # noqa: E402
 from repro.models import lm  # noqa: E402
 from repro.serve.gateway.slots import make_adapter  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def time_insert(ad, mk_prompt, max_new, repeats, want_skip):
@@ -115,6 +116,7 @@ def main():
     ap.add_argument("--out", default=str(pathlib.Path(__file__).parent
                                          / "BENCH_prefix.json"))
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.shared, args.repeats = [0, 2, 4], 3
 

@@ -13,6 +13,8 @@ def main() -> None:
     from benchmarks import (bipolar_vs_split, kernel_bench, roofline,
                             table1_multiplier_mse, table2_adder_mse,
                             table3_accuracy, table3_energy)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     sections = [
         ("table1", table1_multiplier_mse.run),

@@ -17,6 +17,7 @@ from repro.core.sc_layer import SCConfig
 from repro.data import mnist_synth
 from repro.models import lenet
 from repro.train import optim
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--full-lenet", action="store_true",
                     help="paper-size LeNet (32/64 filters); default reduced")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (lenet.LeNetConfig() if args.full_lenet
            else lenet.LeNetConfig(conv1_filters=16, conv2_filters=32,

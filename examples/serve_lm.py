@@ -15,6 +15,7 @@ from repro import configs
 from repro.data.tokens import batch_at
 from repro.models import lm
 from repro.serve import engine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.smoke_config(args.arch)
     params, _ = lm.init(jax.random.key(0), cfg, {})

@@ -24,6 +24,7 @@ from repro.serve.gateway.gateway import (GatewayConfig,  # noqa: E402
                                          MicroBatchGateway)
 from repro.serve.gateway.sensors import FleetConfig, SensorFleet  # noqa: E402
 from repro.serve.spec import ServeSpec, make_gateway  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def run_frames(events, frontend: str, bits: int, duration: float,
@@ -104,6 +105,7 @@ def main():
                          "model) — deterministic overload for incident/SLO "
                          "demos and CI")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = metrics = slo_mon = flight = incident = None
     if args.trace or args.slo or args.health_out or args.flight \

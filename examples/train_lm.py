@@ -7,6 +7,7 @@ Run:  PYTHONPATH=src python examples/train_lm.py [--steps 200]
 import argparse
 
 from repro.launch import train
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -14,6 +15,7 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_lm_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
     # ~100M params: a width-512, 8-layer llama-style decoder
     import repro.configs.stablelm_3b as base
     import repro.models.lm as lm

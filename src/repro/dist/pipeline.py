@@ -15,7 +15,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -58,7 +57,7 @@ def gpipe_apply(stage_fn, params, xs, mesh, axis: str = "stage"):
     pspec = jax.tree.map(
         lambda a: P(*((axis,) + (None,) * (a.ndim - 1))), params)
     xspec = P(*((None,) * xs.ndim))
-    return shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(pspec, xspec), out_specs=xspec,
-        check_rep=False)(params, xs)
+        check_vma=False)(params, xs)

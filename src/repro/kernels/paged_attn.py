@@ -80,8 +80,11 @@ def _paged_kernel(tables_ref, lens_ref, win_ref, q_ref, k_ref, v_ref, *rest,
     if splice:
         # the current token's K/V row, overlaid at its position instead of
         # pre-written into the arena: the sweep reads live blocks only and
-        # the arena write stays a single post-scan row per layer
-        here = (pos == lens_ref[b] - 1).reshape(bs, 1, 1)
+        # the arena write stays a single post-scan row per layer.  The
+        # mask is built as a (bs, 1, 1) column directly: Mosaic cannot
+        # reshape the (1, bs) position row into one
+        row = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
+        here = row == lens_ref[b] - 1
         k = jnp.where(here, k1_ref[0].astype(jnp.float32)[None], k)
         v = jnp.where(here, v1_ref[0].astype(jnp.float32)[None], v)
     kt = jnp.swapaxes(k, 0, 1)                    # (Hkv, bs, D)
@@ -259,7 +262,9 @@ def _paged_state_kernel(tables_ref, lens_ref, win_ref, q0_ref, q_ref, k_ref,
     pos = q0_ref[b] + j * bs + \
         jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
     if splice:
-        here = (pos == lens_ref[b] - 1).reshape(bs, 1, 1)
+        row = q0_ref[b] + j * bs + \
+            jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
+        here = row == lens_ref[b] - 1
         k = jnp.where(here, k1_ref[0].astype(jnp.float32)[None], k)
         v = jnp.where(here, v1_ref[0].astype(jnp.float32)[None], v)
     kt = jnp.swapaxes(k, 0, 1)                    # (Hkv, bs, D)
@@ -290,8 +295,8 @@ def _paged_state_kernel(tables_ref, lens_ref, win_ref, q0_ref, q_ref, k_ref,
     @pl.when(j == nb - 1)
     def _finish():
         acc_ref[0] = acc_scr[...]
-        m_ref[0] = m_scr[...]
-        l_ref[0] = l_scr[...]
+        m_ref[0, 0] = m_scr[...]
+        l_ref[0, 0] = l_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -325,7 +330,9 @@ def paged_decode_attention_with_state(q, k_arena, v_arena, tables, lens, *,
     if q0 is None:
         q0 = jnp.zeros((B,), jnp.int32)
     row = pl.BlockSpec((1, Hq, D), lambda b, j, t, ln, w, z: (b, 0, 0))
-    hrow = pl.BlockSpec((1, Hq), lambda b, j, t, ln, w, z: (b, 0))
+    # m and l leave as (B, 1, Hq): a (1, Hq) block of a (B, Hq) array is
+    # neither 8-row aligned nor the whole array, which Mosaic refuses
+    hrow = pl.BlockSpec((1, 1, Hq), lambda b, j, t, ln, w, z: (b, 0, 0))
     blk = pl.BlockSpec((1, bs, Hkv, D),
                        lambda b, j, t, ln, w, z: (t[b, j], 0, 0, 0))
     kv_row = pl.BlockSpec((1, Hkv, D), lambda b, j, t, ln, w, z: (b, 0, 0))
@@ -336,7 +343,7 @@ def paged_decode_attention_with_state(q, k_arena, v_arena, tables, lens, *,
     if splice:
         operands += tuple(new_kv)
         in_specs += [kv_row, kv_row]
-    return pl.pallas_call(
+    acc, m, l = pl.pallas_call(
         functools.partial(_paged_state_kernel, bs=bs, nb=nb, n_rep=n_rep,
                           scale=scale, splice=splice),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -351,10 +358,11 @@ def paged_decode_attention_with_state(q, k_arena, v_arena, tables, lens, *,
             ],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, Hq, D), jnp.float32),
-                   jax.ShapeDtypeStruct((B, Hq), jnp.float32),
-                   jax.ShapeDtypeStruct((B, Hq), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, 1, Hq), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, Hq), jnp.float32)],
         interpret=interpret,
     )(*operands)
+    return acc, m[:, 0], l[:, 0]
 
 
 def _cascade_prefix_kernel(tables_ref, glen_ref, win_ref, ll_ref, q_ref,
@@ -379,7 +387,7 @@ def _cascade_prefix_kernel(tables_ref, glen_ref, win_ref, ll_ref, q_ref,
     # one key set per group, one validity row per lane: the chain bound is
     # shared (group_len), the window bound is each lane's own length
     valid = pos < glen_ref[g]                                  # (1, 1, bs)
-    lane_len = ll_ref[0].reshape(Lc, 1, 1)                     # (Lc, 1, 1)
+    lane_len = ll_ref[0][:, :, None]                           # (Lc, 1, 1)
     valid = valid & (pos >= lane_len - win_ref[0])             # (Lc, 1, bs)
 
     kt = jnp.swapaxes(k, 0, 1)                    # (Hkv, bs, D)
@@ -444,7 +452,9 @@ def cascade_prefix_attention(qg, k_arena, v_arena, group_tables, group_len,
     win = jnp.where(jnp.asarray(window, jnp.int32) == 0, NO_WINDOW,
                     jnp.asarray(window, jnp.int32)).reshape(1)
     qrow = pl.BlockSpec((1, Lc, Hq, D), lambda g, j, t, gl, w: (g, 0, 0, 0))
-    lrow = pl.BlockSpec((1, Lc), lambda g, j, t, gl, w: (g, 0))
+    # lane lens enter as (G, Lc, 1): a (1, Lc) block of a (G, Lc) array is
+    # neither 8-row aligned nor the whole array, which Mosaic refuses
+    lrow = pl.BlockSpec((1, Lc, 1), lambda g, j, t, gl, w: (g, 0, 0))
     blk = pl.BlockSpec((1, bs, Hkv, D),
                        lambda g, j, t, gl, w: (t[g, j], 0, 0, 0))
     grow = pl.BlockSpec((1, Lc, Hq, D), lambda g, j, t, gl, w: (g, 0, 0, 0))
@@ -468,16 +478,17 @@ def cascade_prefix_attention(qg, k_arena, v_arena, group_tables, group_len,
                    jax.ShapeDtypeStruct((G, Lc, Hq), jnp.float32)],
         interpret=interpret,
     )(jnp.asarray(group_tables, jnp.int32), jnp.asarray(group_len, jnp.int32),
-      win, jnp.asarray(lane_lens, jnp.int32), qg, k_arena, v_arena)
+      win, jnp.asarray(lane_lens, jnp.int32).reshape(G, Lc, 1), qg, k_arena,
+      v_arena)
 
 
 def _merge_kernel(acc1_ref, m1_ref, l1_ref, acc2_ref, m2_ref, l2_ref, o_ref):
-    m1 = m1_ref[0]
-    m2 = m2_ref[0]
+    m1 = m1_ref[0, 0]
+    m2 = m2_ref[0, 0]
     m = jnp.maximum(m1, m2)
     c1 = jnp.exp(m1 - m)
     c2 = jnp.exp(m2 - m)
-    l = c1 * l1_ref[0] + c2 * l2_ref[0]
+    l = c1 * l1_ref[0, 0] + c2 * l2_ref[0, 0]
     acc = c1[:, None] * acc1_ref[0] + c2[:, None] * acc2_ref[0]
     o_ref[0] = acc / jnp.maximum(l, 1e-30)[:, None]
 
@@ -498,7 +509,9 @@ def merge_attn_states(acc1, m1, l1, acc2, m2, l2, *,
     interpret = resolve_interpret(interpret)
     B, Hq, D = acc1.shape
     arow = pl.BlockSpec((1, Hq, D), lambda b: (b, 0, 0))
-    hrow = pl.BlockSpec((1, Hq), lambda b: (b, 0))
+    # m and l enter as (B, 1, Hq), the layout the state kernel writes
+    hrow = pl.BlockSpec((1, 1, Hq), lambda b: (b, 0, 0))
+    col = lambda a: a.reshape(B, 1, Hq)
     return pl.pallas_call(
         _merge_kernel,
         grid=(B,),
@@ -506,4 +519,4 @@ def merge_attn_states(acc1, m1, l1, acc2, m2, l2, *,
         out_specs=arow,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), jnp.float32),
         interpret=interpret,
-    )(acc1, m1, l1, acc2, m2, l2)
+    )(acc1, col(m1), col(l1), acc2, col(m2), col(l2))

@@ -6,8 +6,11 @@ intermediate (N,)-bool materialization in HBM.  The comparator's code
 sequence (ramp / van-der-Corput / reversed-Gray / LFSR) is a small constant
 (N int32 = 1KiB at 8-bit) broadcast to every grid cell.
 
-Per grid cell: levels tile (blk,) int32 and codes (N,) int32 produce a
-(blk, N/32) uint32 tile: bit t of word w = (codes[32w+t] < level).
+Per grid cell: a levels column (blk, 1) int32 and the codes row (1, N)
+int32 produce a (blk, N/32) tile: bit t of word w = (codes[32w+t] < level).
+Each word is the sum of its 32 distinct powers of two, taken in int32 (Mosaic
+has no unsigned reductions; with no carries the bits are the uint32 word's),
+and the wrapper reinterprets the tile as uint32.
 """
 from __future__ import annotations
 
@@ -18,15 +21,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _sng_pack_kernel(lvl_ref, codes_ref, out_ref, *, length: int):
-    lvl = lvl_ref[...]                       # (blk,)
-    codes = codes_ref[...]                   # (length,)
-    nw = length // 32
-    codes2 = codes.reshape(nw, 32)           # (nw, 32)
-    bits = (codes2[None, :, :] < lvl[:, None, None]).astype(jnp.uint32)
-    weights = (jnp.uint32(1) << jax.lax.broadcasted_iota(jnp.uint32,
-                                                         (1, 1, 32), 2))
-    out_ref[...] = jnp.sum(bits * weights, axis=-1).astype(jnp.uint32)
+def _sng_pack_kernel(lvl_ref, codes_ref, out_ref):
+    lvl = lvl_ref[...]                       # (blk, 1)
+    codes = codes_ref[...]                   # (1, length)
+    blk, nw = out_ref.shape
+    t = jax.lax.broadcasted_iota(jnp.int32, codes.shape, 1)
+    # bit t of the whole stream, weighted into its word: 2**(t % 32)
+    bits = (codes < lvl).astype(jnp.int32) << (t & 31)        # (blk, length)
+    col = jax.lax.broadcasted_iota(jnp.int32, (blk, nw), 1)
+    out = jnp.zeros((blk, nw), jnp.int32)
+    for w in range(nw):
+        word = jnp.sum(jnp.where((t >> 5) == w, bits, 0), axis=-1,
+                       keepdims=True)                          # (blk, 1)
+        out = jnp.where(col == w, word, out)
+    out_ref[...] = out
 
 
 @functools.partial(jax.jit, static_argnames=("length", "block", "interpret"))
@@ -41,14 +49,15 @@ def sng_pack_pallas(levels: jax.Array, codes: jax.Array, *, length: int,
     M = levels.shape[0]
     assert M % block == 0
     nw = length // 32
-    return pl.pallas_call(
-        functools.partial(_sng_pack_kernel, length=length),
+    out = pl.pallas_call(
+        _sng_pack_kernel,
         grid=(M // block,),
         in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((length,), lambda i: (0,)),
+            pl.BlockSpec((block, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, length), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block, nw), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, nw), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((M, nw), jnp.int32),
         interpret=interpret,
-    )(levels, codes)
+    )(levels.reshape(M, 1), codes.reshape(1, length))
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)

@@ -9,12 +9,8 @@ import jax
 
 
 def _make(shape: tuple[int, ...], axes: tuple[str, ...]):
-    # axis_types landed after jax 0.4.x; Auto is the default either way.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

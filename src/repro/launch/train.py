@@ -33,6 +33,7 @@ from repro import configs
 from repro.ckpt import manager as ckpt
 from repro.data.tokens import TokenPipeline
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.train import optim
@@ -68,6 +69,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (configs.smoke_config(args.arch) if args.smoke
            else configs.config(args.arch))

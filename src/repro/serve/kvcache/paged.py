@@ -252,16 +252,14 @@ class PagedKVSlotAdapter:
         # donate the arena (and dense cache) through every call that rebinds
         # it, so the .at[].set updates alias in place instead of holding a
         # second full arena copy — the whole point of the fixed byte budget.
-        # CPU XLA cannot donate (it would only warn), so gate on backend.
-        dn = jax.default_backend() != "cpu"
-        self._scatter = jax.jit(self._scatter_impl,
-                                donate_argnums=(0,) if dn else ())
-        self._copy = jax.jit(self._copy_impl,
-                             donate_argnums=(0,) if dn else ())
+        # Every backend donates, the CPU included, so the CPU tests see the
+        # chip's semantics: a stale reference to a donated arena is deleted.
+        self._scatter = jax.jit(self._scatter_impl, donate_argnums=(0,))
+        self._copy = jax.jit(self._copy_impl, donate_argnums=(0,))
         self._write_block = jax.jit(self._write_block_impl,
-                                    donate_argnums=(0,) if dn else ())
+                                    donate_argnums=(0,))
         tick = self._tick_inplace_impl if self.inplace else self._tick_impl
-        self._decode = jax.jit(tick, donate_argnums=(1, 2) if dn else ())
+        self._decode = jax.jit(tick, donate_argnums=(1, 2))
         # the cascade tick sits NEXT TO the flat one, not instead of it:
         # a tick on which no chain is shared by >= 2 lanes degrades to
         # self._decode — the *same* executable, hence bitwise.  jit
@@ -269,7 +267,7 @@ class PagedKVSlotAdapter:
         # chain / lane / suffix counts), a fixed set in the steady state.
         if self.backend == "cascade":
             self._decode_cascade = jax.jit(
-                self._tick_cascade_impl, donate_argnums=(1, 2) if dn else ())
+                self._tick_cascade_impl, donate_argnums=(1, 2))
 
     # -- jitted bodies ------------------------------------------------------
 

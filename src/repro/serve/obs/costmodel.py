@@ -74,16 +74,13 @@ def analyze(fn, args) -> dict | None:
     or None when the backend offers no analysis (interpret mode, non-XLA
     paths) — callers degrade, they never crash.
 
-    Normalizes the per-version shape drift: ``cost_analysis()`` returns a
-    dict on newer jax, a one-element list of dicts on older, and empty /
-    None / key-less dicts where the backend has nothing to say.
+    ``cost_analysis()`` returns a dict, or None / an empty or key-less
+    dict where the backend has nothing to say.
     """
     try:
         ca = fn.lower(*args).compile().cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     flops = float(ca.get("flops", 0.0) or 0.0)

@@ -86,7 +86,7 @@ def test_analyze_counts_flops_and_bytes_of_real_jit():
 def test_analyze_degrades_to_none_when_backend_offers_nothing():
     assert obs.analyze(_fake_fn(exc=RuntimeError("no analysis")), ()) is None
     assert obs.analyze(_fake_fn(result=None), ()) is None
-    assert obs.analyze(_fake_fn(result=[]), ()) is None        # old-jax empty
+    assert obs.analyze(_fake_fn(result=[]), ()) is None        # not a dict
     assert obs.analyze(_fake_fn(result={}), ()) is None
     assert obs.analyze(_fake_fn(result={"other": 1.0}), ()) is None
     assert obs.analyze(
@@ -95,9 +95,11 @@ def test_analyze_degrades_to_none_when_backend_offers_nothing():
 
 def test_analyze_normalizes_old_jax_list_shape_and_partial_dicts():
     full = {"flops": 5.0, "bytes accessed": 10.0}
-    assert obs.analyze(_fake_fn(result=[full]), ()) == \
-        obs.analyze(_fake_fn(result=full), ()) == \
+    assert obs.analyze(_fake_fn(result=full), ()) == \
         {"flops": 5.0, "bytes": 10.0}
+    # the installed jax returns a dict; a list-shaped result is no
+    # analysis the attributor can read, so the stage degrades
+    assert obs.analyze(_fake_fn(result=[full]), ()) is None
     # bytes with no FLOP count is still useful (traffic-only verdict)
     assert obs.analyze(_fake_fn(result={"bytes accessed": 128.0}), ()) == \
         {"flops": 0.0, "bytes": 128.0}

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jex_core
 
 from repro import configs
 from repro.models import lm
@@ -303,9 +304,9 @@ def _gather_out_sizes(jaxpr, out):
         for p in eqn.params.values():
             vals = p if isinstance(p, (list, tuple)) else (p,)
             for v in vals:
-                if isinstance(v, jax.core.ClosedJaxpr):
+                if isinstance(v, jex_core.ClosedJaxpr):
                     _gather_out_sizes(v.jaxpr, out)
-                elif isinstance(v, jax.core.Jaxpr):
+                elif isinstance(v, jex_core.Jaxpr):
                     _gather_out_sizes(v, out)
     return out
 

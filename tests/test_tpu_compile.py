@@ -1,0 +1,123 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e, at the
+widths the served models use.  Nothing runs: Mosaic refuses here what it
+would refuse on the chip (block shapes, layouts, unsupported ops), so these
+tests guard the chip at no chip time.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process at a time may load the TPU library, and every
+test worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.kernels import paged_attn as pk
+
+# (Hq, Hkv, D): stablelm-3b (MHA) and hymba-1.5b (GQA, Hkv=5)
+WIDTHS = {"stablelm-3b": (32, 32, 80), "hymba-1.5b": (25, 5, 64)}
+LANES, NB, BS, BLOCKS = 8, 32, 16, 257     # 8 lanes x 512 tokens, block 16
+LENET_PATCHES, LENET_K, LENET_O = 32 * 784, 25, 32   # LeNet-5 conv1
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(sharding, fn, *shapes, **jit_kw):
+    """Compile ``fn`` for the described chip; return the executable text."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return jax.jit(fn, **jit_kw).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_paged_decode_kernel_with_new_row_compiles(one_chip, width):
+    Hq, Hkv, D = WIDTHS[width]
+    bf, i32 = jnp.bfloat16, jnp.int32
+    text = _compile(
+        one_chip,
+        lambda q, k, v, t, ln, k1, v1: pk.paged_decode_attention(
+            q, k, v, t, ln, window=jnp.int32(1024), new_kv=(k1, v1),
+            interpret=False),
+        ((LANES, Hq, D), bf), ((BLOCKS, BS, Hkv, D), bf),
+        ((BLOCKS, BS, Hkv, D), bf), ((LANES, NB), i32), ((LANES,), i32),
+        ((LANES, Hkv, D), bf), ((LANES, Hkv, D), bf))
+    assert "tpu_custom_call" in text
+
+
+def test_scatter_kv_rows_compiles(one_chip):
+    Hq, Hkv, D = WIDTHS["stablelm-3b"]
+    arena = ((32, BLOCKS, 1, BS, Hkv, D), jnp.bfloat16)
+    rows = ((32, LANES, Hkv, D), jnp.bfloat16)
+    text = _compile(
+        one_chip,
+        lambda ka, va, kr, vr, w, o: pk.scatter_kv_rows(
+            ka, va, kr, vr, w, o, interpret=False),
+        arena, arena, rows, rows, ((LANES,), jnp.int32),
+        ((LANES,), jnp.int32), donate_argnums=(0, 1))
+    assert "tpu_custom_call" in text
+
+
+def _cascade_call(kernel, Hq, Hkv, D):
+    """(fn, operand shapes) of one cascade kernel: 2 groups of 4 lanes."""
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    arena = ((BLOCKS, BS, Hkv, D), bf)
+    if kernel == "prefix":
+        G, Lc = 2, 4
+        return (lambda qg, k, v, gt, gl, ll: pk.cascade_prefix_attention(
+                    qg, k, v, gt, gl, ll, window=jnp.int32(1024),
+                    interpret=False),
+                [((G, Lc, Hq, D), bf), arena, arena, ((G, 8), i32),
+                 ((G,), i32), ((G, Lc), i32)])
+    if kernel == "suffix":
+        return (lambda q, k, v, t, ln, q0, k1, v1:
+                    pk.paged_decode_attention_with_state(
+                        q, k, v, t, ln, q0=q0, new_kv=(k1, v1),
+                        interpret=False),
+                [((LANES, Hq, D), bf), arena, arena, ((LANES, NB), i32),
+                 ((LANES,), i32), ((LANES,), i32), ((LANES, Hkv, D), bf),
+                 ((LANES, Hkv, D), bf)])
+    acc, ml = ((LANES, Hq, D), f32), ((LANES, Hq), f32)
+    return (lambda a1, m1, l1, a2, m2, l2: pk.merge_attn_states(
+                a1, m1, l1, a2, m2, l2, interpret=False),
+            [acc, ml, ml, acc, ml, ml])
+
+
+@pytest.mark.parametrize("kernel", ["prefix", "suffix", "merge"])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_cascade_kernel_compiles(one_chip, kernel, width):
+    fn, shapes = _cascade_call(kernel, *WIDTHS[width])
+    assert "tpu_custom_call" in _compile(one_chip, fn, *shapes)
+
+
+@pytest.mark.parametrize("out_kernels", [LENET_O, 2 * LENET_O],
+                         ids=["one_bank", "pos_neg_banks"])
+@pytest.mark.parametrize("bits", [5, 8])
+def test_sc_datapath_compiles_at_lenet_first_layer(one_chip, bits,
+                                                   out_kernels):
+    text = _compile(
+        one_chip,
+        lambda x, w: ops.sc_dot_from_levels(x, w, bits, interpret=False),
+        ((LENET_PATCHES, LENET_K), jnp.int32),
+        ((LENET_K, out_kernels), jnp.int32))
+    assert text.count("tpu_custom_call") >= 3      # two SNG packs + the dot
+
+
+def test_sng_pack_compiles(one_chip):
+    N = 256
+    text = _compile(
+        one_chip,
+        lambda lv, codes: ops.sng_pack(lv, codes, N, interpret=False),
+        ((LENET_PATCHES * LENET_K,), jnp.int32), ((N,), jnp.int32))
+    assert "tpu_custom_call" in text
