@@ -174,6 +174,7 @@ def scatter_kv_rows(k_arena, v_arena, k_rows, v_rows, wbids, offs, *,
                    jax.ShapeDtypeStruct(v_arena.shape, v_arena.dtype)],
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
+        name="scatter_kv_rows",
     )(jnp.asarray(wbids, jnp.int32), jnp.asarray(offs, jnp.int32),
       k_rows, v_rows, k_arena, v_arena)
 
@@ -234,6 +235,7 @@ def paged_decode_attention(q, k_arena, v_arena, tables, lens, *,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), v_arena.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*operands)
 
 

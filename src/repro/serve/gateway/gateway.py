@@ -61,13 +61,18 @@ class MicroBatchGateway:
         self.spec = spec
         self.params = params if params is not None else \
             lenet.init(jax.random.key(seed), spec.lenet)
-        # one fixed-shape entry point per bucket (never recompiles)
-        self._sensor_fns = {
-            bs: jax.jit(lambda p, x, _s=spec: fe.sensor_stage(p, x, _s))
-            for bs in cfg.bucket_sizes}
-        self._gateway_fns = {
-            bs: jax.jit(lambda p, x, _s=spec: fe.gateway_stage(p, x, _s))
-            for bs in cfg.bucket_sizes}
+        # one fixed-shape entry point per bucket (never recompiles): a
+        # function object of its own per bucket, since jit caches by
+        # function, named so the trace's programs tell the stages apart
+        self._sensor_fns, self._gateway_fns = {}, {}
+        for bs in cfg.bucket_sizes:
+            def sensor_stage(p, x):
+                return fe.sensor_stage(p, x, spec)
+
+            def gateway_stage(p, x):
+                return fe.gateway_stage(p, x, spec)
+            self._sensor_fns[bs] = jax.jit(sensor_stage)
+            self._gateway_fns[bs] = jax.jit(gateway_stage)
         self._frame_energy_nj = fe.frame_energy_nj(spec)
         self._link_bytes = fe.link_bytes_per_frame(spec)
         self._sensor_lat = fe.sensor_latency_s(spec)

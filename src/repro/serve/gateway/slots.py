@@ -48,6 +48,7 @@ import numpy as np
 from repro.models.lm import LMConfig
 from repro.serve import engine
 from repro.serve.kvcache.pool import PoolExhausted
+from repro.serve.obs.tracer import NO_SPAN
 
 
 @dataclasses.dataclass
@@ -316,6 +317,12 @@ class ContinuousBatcher:
         if validate is not None:        # paged: whole-pool capacity bound
             validate(len(req.prompt), req.max_new_tokens)
         self.pending.append(req)
+        tr = self.tracer
+        if tr is not None and tr.innermost(tid=req.uid) is None:
+            # driven directly (no gateway loop opened the lifecycle at
+            # arrival): the wait runs from submit
+            tr.begin("request", tid=req.uid)
+            tr.begin("queue_wait", tid=req.uid)
 
     def _admissible(self, req: Request) -> bool:
         can = getattr(self.adapter, "can_admit", None)
@@ -374,63 +381,25 @@ class ContinuousBatcher:
         and retire at-capacity / EOS-at-prefill lanes, but skip the
         batched decode — admitted lanes keep their prefill token staged in
         ``last_token`` and wait for the router to hand them off to a
-        decode slice.  The default path is untouched."""
+        decode slice.  The default path is untouched.
+
+        With a tracer attached the step is a ``tick`` span on the engine
+        track, holding ``admit``, the adapter's ``tick.prepare`` /
+        ``tick.dispatch`` / ``tick.sample`` and ``retire``."""
         tr = self.tracer
-        if tr is not None:
-            tr.begin("tick", pid=self.trace_pid, tid=0)
+        if tr is None:
+            return self._step(decode, None)
+        with tr.span("tick", pid=self.trace_pid, tid=0) as sp:
+            return self._step(decode, sp)
+
+    def _step(self, decode: bool, sp) -> list[Request]:
+        tr = self.tracer
         finished: list[Request] = []
-        stalled = False                 # FIFO: head can't admit -> stop
-        for slot in range(self.n_slots):
-            while self.active[slot] is None and self.pending and not stalled:
-                if not self._admissible(self.pending[0]):
-                    stalled = True      # blocks free up as requests retire
-                    break
-                req = self.pending.popleft()
-                req.t_dequeue = self._now()
-                if tr is not None:
-                    if tr.innermost(tid=req.uid) != "queue_wait":
-                        # submitted before the tracer was wired (direct
-                        # batcher submit, pre-run queueing): open the
-                        # lifecycle late so the rest of it is traced
-                        tr.begin("request", tid=req.uid,
-                                 args={"late_open": True})
-                        tr.begin("queue_wait", tid=req.uid)
-                    tr.end("queue_wait", tid=req.uid)
-                    tr.begin("prefill", tid=req.uid,
-                             args={"prompt_len": len(req.prompt)})
-                    # chunk spans from the paged adapter's fold land on
-                    # this request's track without threading uids through
-                    tr.set_ctx(req.uid)
-                try:
-                    tok = self.adapter.insert(
-                        slot, np.asarray(req.prompt, np.int32),
-                        max_new=req.max_new_tokens)
-                except PoolExhausted:
-                    # insert rolled its allocations back; requeue at the
-                    # head and let retirements free blocks (can_admit makes
-                    # this unreachable, but admission must degrade to
-                    # queueing, never to a crashed serving loop)
-                    self.pending.appendleft(req)
-                    stalled = True
-                    if tr is not None:
-                        tr.end("prefill", tid=req.uid,
-                               args={"admitted": False})
-                        tr.begin("queue_wait", tid=req.uid)
-                    break
-                req.t_admit = self._now()
-                if tr is not None:
-                    tr.end("prefill", tid=req.uid,
-                           args={"slot": slot})
-                req.generated.append(tok)
-                if req.done:            # EOS fired on the prefill token
-                    self._stamp_stats(slot, req)
-                    self.adapter.clear(slot)
-                    finished.append(req)
-                    continue
-                if tr is not None:
-                    tr.begin("decode", tid=req.uid)
-                self.active[slot] = req
-                self.last_token[slot] = tok
+        with (tr.span("admit", pid=self.trace_pid, tid=0) if tr is not None
+              else NO_SPAN) as admit:
+            admitted, prompt_tokens = self._admit(finished)
+            if admit is not None:
+                admit.set(admitted=admitted, prompt_tokens=prompt_tokens)
         # a slot whose context filled every KV block cannot take another
         # token — surface it as finished instead of letting its next write
         # be silently clamped onto the final (possibly shared) block
@@ -448,35 +417,106 @@ class ContinuousBatcher:
         self.last_active = int(active.sum())
         self.peak_active = max(self.peak_active, self.last_active)
         if not active.any():
-            if tr is not None:
-                tr.end("tick", pid=self.trace_pid, tid=0,
-                       args={"active": 0, "finished": len(finished)})
+            if sp is not None:
+                sp.set(active=0, finished=len(finished))
             return finished
         if not decode:
-            if tr is not None:
-                tr.end("tick", pid=self.trace_pid, tid=0,
-                       args={"active": self.last_active,
-                             "finished": len(finished), "decode": False})
+            if sp is not None:
+                sp.set(active=self.last_active, finished=len(finished),
+                       decode=False)
             return finished
-        toks = self.adapter.decode(self.last_token, active)
-        for slot, req in enumerate(self.active):
-            if req is None:
-                continue
-            tok = int(toks[slot])
-            req.generated.append(tok)
-            self.last_token[slot] = tok
-            if req.done:
-                self._stamp_stats(slot, req)
-                self._retire_trace(req, "done")
-                finished.append(req)
-                self.active[slot] = None
-                self.adapter.clear(slot)
-                self.last_token[slot] = 0
         if tr is not None:
-            tr.end("tick", pid=self.trace_pid, tid=0,
-                   args={"active": self.last_active,
-                         "finished": len(finished)})
+            # the adapter's tick.* spans land on this engine track; the
+            # request tracks are the default lane again after it
+            tr.set_ctx(0, pid=self.trace_pid)
+        toks = self.adapter.decode(self.last_token, active)
+        if tr is not None:
+            tr.set_ctx(0)
+        with (tr.span("retire", pid=self.trace_pid, tid=0) if tr is not None
+              else NO_SPAN):
+            for slot, req in enumerate(self.active):
+                if req is None:
+                    continue
+                tok = int(toks[slot])
+                req.generated.append(tok)
+                self.last_token[slot] = tok
+                if req.done:
+                    self._stamp_stats(slot, req)
+                    self._retire_trace(req, "done")
+                    finished.append(req)
+                    self.active[slot] = None
+                    self.adapter.clear(slot)
+                    self.last_token[slot] = 0
+        if sp is not None:
+            sp.set(active=self.last_active, finished=len(finished))
         return finished
+
+    def _admit(self, finished: list[Request]) -> tuple[int, int]:
+        """Fill free slots from the queue (FIFO; the head that cannot be
+        admitted stops admission).  Requests whose prefill token already
+        finishes them join ``finished``.  Returns the requests prefilled
+        and their prompt tokens."""
+        tr = self.tracer
+        admitted = prompt_tokens = 0
+        stalled = False                 # FIFO: head can't admit -> stop
+        for slot in range(self.n_slots):
+            while self.active[slot] is None and self.pending and not stalled:
+                if not self._admissible(self.pending[0]):
+                    stalled = True      # blocks free up as requests retire
+                    break
+                req = self.pending.popleft()
+                req.t_dequeue = self._now()
+                if tr is not None:
+                    if tr.innermost(tid=req.uid) != "queue_wait":
+                        # submitted before the tracer was wired (pre-run
+                        # queueing): open the lifecycle late so the rest
+                        # of it is traced
+                        tr.begin("request", tid=req.uid,
+                                 args={"late_open": True})
+                        tr.begin("queue_wait", tid=req.uid)
+                    tr.end("queue_wait", tid=req.uid)
+                    # chunk spans from the paged adapter's fold land on
+                    # this request's track without threading uids through
+                    tr.set_ctx(req.uid)
+                with (tr.span("prefill", tid=req.uid,
+                              prompt_len=len(req.prompt))
+                      if tr is not None else NO_SPAN) as pre:
+                    try:
+                        tok = self.adapter.insert(
+                            slot, np.asarray(req.prompt, np.int32),
+                            max_new=req.max_new_tokens)
+                    except PoolExhausted:
+                        # insert rolled its allocations back; requeue at
+                        # the head and let retirements free blocks
+                        # (can_admit makes this unreachable, but admission
+                        # must degrade to queueing, never to a crashed
+                        # serving loop)
+                        tok = None
+                        if pre is not None:
+                            pre.set(admitted=False)
+                    else:
+                        req.t_admit = self._now()
+                        if pre is not None:
+                            pre.set(slot=slot)
+                if tok is None:
+                    self.pending.appendleft(req)
+                    stalled = True
+                    if tr is not None:
+                        tr.begin("queue_wait", tid=req.uid)
+                    break
+                admitted += 1
+                prompt_tokens += len(req.prompt)
+                req.generated.append(tok)
+                if req.done:            # EOS fired on the prefill token
+                    self._stamp_stats(slot, req)
+                    self.adapter.clear(slot)
+                    finished.append(req)
+                    continue
+                if tr is not None:
+                    tr.begin("decode", tid=req.uid)
+                self.active[slot] = req
+                self.last_token[slot] = tok
+        return admitted, prompt_tokens
 
     def run(self) -> list[Request]:
         """Drain the queue; returns all completed requests."""

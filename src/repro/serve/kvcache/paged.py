@@ -83,6 +83,7 @@ from repro.models.lm import LMConfig
 from repro.serve import engine
 from repro.serve.backend import resolve_backend
 from repro.serve.kvcache.pool import (TRASH_BLOCK, BlockPool, PoolExhausted)
+from repro.serve.obs.tracer import NO_SPAN
 
 
 def _pad_seq(a: jax.Array, target: int) -> jax.Array:
@@ -106,10 +107,9 @@ def chunk_fold_fn(cfg: LMConfig) -> Callable:
     """The shared jitted ``engine.prefill_chunked`` step for ``cfg``."""
     fn = _CHUNK_FOLDS.get(cfg)
     if fn is None:
-        fn = jax.jit(
-            lambda p, batch, cache, q: engine.prefill_chunked(
-                cfg, p, batch, cache, q),
-            static_argnums=(3,))
+        def prefill_chunk(p, batch, cache, q):
+            return engine.prefill_chunked(cfg, p, batch, cache, q)
+        fn = jax.jit(prefill_chunk, static_argnums=(3,))
         _CHUNK_FOLDS[cfg] = fn
     return fn
 
@@ -238,7 +238,9 @@ class PagedKVSlotAdapter:
                           for key, a in self.cache.items()}
             self.params = jax.device_put(params, rep)
 
-        self._prefill = jax.jit(lambda p, b: engine.prefill(cfg, p, b))
+        def prefill(p, b):
+            return engine.prefill(cfg, p, b)
+        self._prefill = jax.jit(prefill)
         # the chunked-prefill fold: one step per prompt block.  jit
         # specializes per (q_offset, chunk/prefix shape) — a fixed bucket
         # set in the steady state, shared by cold and resumed folds (that
@@ -360,13 +362,10 @@ class PagedKVSlotAdapter:
             c = min(self.bs, P - q)
             batch = {"tokens": jnp.asarray(
                 np.asarray(prompt[q:q + c], np.int32)[None])}
-            if self.tracer is not None:
-                self.tracer.begin("prefill_chunk")
-            cache, logits = self._chunk_fn(self.params, batch, cache, q)
-            if self.tracer is not None:
-                self.tracer.end("prefill_chunk",
-                                args={"q0": q, "tokens": c,
-                                      "prefix_hit": False})
+            with (self.tracer.span("prefill_chunk", q0=q, tokens=c,
+                                   prefix_hit=False)
+                  if self.tracer is not None else NO_SPAN):
+                cache, logits = self._chunk_fn(self.params, batch, cache, q)
             q += c
             if (self.cfg.family == "hybrid" and q % self.bs == 0
                     and q // self.bs <= n_full):
@@ -851,6 +850,41 @@ class PagedKVSlotAdapter:
         }
 
     def decode(self, tokens: np.ndarray, active: np.ndarray) -> np.ndarray:
+        """One batched tick.  With a tracer attached its host phases are
+        spans: ``tick.prepare`` (write targets, copy-on-write, the cascade
+        plan, the host-to-device arguments), ``tick.dispatch`` (the call of
+        the jitted tick) and ``tick.sample`` (argmax and its readback,
+        where the host waits for the device)."""
+        tr = self.tracer
+        with (tr.span("tick.prepare") if tr is not None
+              else NO_SPAN) as sp:
+            active, wbids, meta = self._write_targets(active)
+            args = (jnp.asarray(self.tables),
+                    jnp.asarray(tokens, jnp.int32)[:, None],
+                    jnp.asarray(active, bool), jnp.asarray(wbids))
+            if sp is not None:
+                sp.set(active=int(active.sum()),
+                       live_tokens=int(self.lens[active].sum()))
+        with (tr.span("tick.dispatch") if tr is not None else NO_SPAN):
+            if meta is None:
+                # no chain shared by >= 2 lanes: degrade to the flat
+                # in-place tick — the *same* jitted executable, hence
+                # bitwise-equal
+                self.arena, self.cache, logits = self._decode(
+                    self.params, self.arena, self.cache, *args)
+            else:
+                self.arena, self.cache, logits = self._decode_cascade(
+                    self.params, self.arena, self.cache, *args, meta)
+        self.lens[active] += 1
+        self.last_logits = logits           # (n_slots, vocab) — parity tests
+        with (tr.span("tick.sample") if tr is not None else NO_SPAN):
+            return np.asarray(jnp.argmax(logits, -1))
+
+    def _write_targets(self, active: np.ndarray):
+        """The tick's host-side plan: the lanes that write, each lane's
+        arena write block (after copy-on-write of a shared block) and,
+        under cascade, the shared-chain metadata.  Returns (active,
+        wbids, meta)."""
         active = np.asarray(active, bool).copy()
         wbids = np.full(self.n_slots, TRASH_BLOCK, np.int32)
         for slot in np.nonzero(active)[0]:
@@ -887,21 +921,7 @@ class PagedKVSlotAdapter:
             self.last_groups = len(groups)
             if groups:
                 meta = self._cascade_meta(groups)
-        if meta is None:
-            # no chain shared by >= 2 lanes: degrade to the flat in-place
-            # tick — the *same* jitted executable, hence bitwise-equal
-            self.arena, self.cache, logits = self._decode(
-                self.params, self.arena, self.cache, jnp.asarray(self.tables),
-                jnp.asarray(tokens, jnp.int32)[:, None],
-                jnp.asarray(active, bool), jnp.asarray(wbids))
-        else:
-            self.arena, self.cache, logits = self._decode_cascade(
-                self.params, self.arena, self.cache, jnp.asarray(self.tables),
-                jnp.asarray(tokens, jnp.int32)[:, None],
-                jnp.asarray(active, bool), jnp.asarray(wbids), meta)
-        self.lens[active] += 1
-        self.last_logits = logits           # (n_slots, vocab) — parity tests
-        return np.asarray(jnp.argmax(logits, -1))
+        return active, wbids, meta
 
     # -- telemetry -----------------------------------------------------------
 

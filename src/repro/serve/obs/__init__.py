@@ -4,10 +4,11 @@ Low-overhead instrumentation for the serving stack (gateway, slot batcher,
 paged KV adapter, sharded router):
 
   tracer.py     per-request lifecycle spans over the virtual serving clock
-                (arrival -> queue wait -> prefill chunks -> decode ticks ->
-                migration -> completion), each completion carrying a
-                stage-attributed energy breakdown that sums *bitwise* to the
-                conserved telemetry ledger.
+                or a wall clock (arrival -> queue wait -> prefill chunks ->
+                decode ticks -> migration -> completion), each completion
+                carrying a stage-attributed energy breakdown that sums
+                *bitwise* to the conserved telemetry ledger; scoped spans
+                (the tick and its host phases) mirrored to the profiler.
   metrics.py    named counters / gauges / histograms (capped reservoir
                 retention, explicit ``n_dropped``) with periodic interval
                 snapshots — occupancy-over-time curves instead of end-only
@@ -53,8 +54,9 @@ that the guards really short-circuit (tests/test_obs.py, tests/test_slo.py).
 """
 from repro.serve.obs.metrics import MetricsRegistry
 from repro.serve.obs.recompile import RecompileDetector
-from repro.serve.obs.tracer import (ENGINE_PID, REQUESTS_PID, SimClock,
-                                    Tracer, callback_count)
+from repro.serve.obs.tracer import (ENGINE_PID, NO_SPAN, REQUESTS_PID,
+                                    SimClock, Tracer, WallClock,
+                                    callback_count)
 from repro.serve.obs.flight import FlightRecorder
 from repro.serve.obs import critpath
 from repro.serve.obs.critpath import (aggregate_critical_paths,
@@ -85,7 +87,7 @@ from repro.serve.obs.export import (SpanStreamWriter, chrome_trace,
 
 __all__ = [
     "ENGINE_PID", "MetricsRegistry", "RecompileDetector", "REQUESTS_PID",
-    "SimClock", "Tracer", "callback_count",
+    "NO_SPAN", "SimClock", "Tracer", "WallClock", "callback_count",
     "BurnWindow", "PressureEvent", "PressureSignal", "SLObjective",
     "SLOMonitor", "SLOPolicy",
     "DEFAULT_RIDGE", "analyze", "attribute", "span_for", "stage_energy",

@@ -25,6 +25,16 @@ addends, in the same order, that the telemetry ledger folded into the
 request's ``energy_nj``), so :meth:`Tracer.assert_energy_conserved` can
 check the span stream against ``Telemetry.fleet_energy_nj`` **bitwise**.
 
+Wall clock: a loop that steps the batcher on real time (the benchmark,
+an operator's own loop) gives the tracer a :class:`WallClock`; stamps are
+then ``time.perf_counter()`` seconds and ``anchor``/``release`` do
+nothing.  Spans that open and close within one call go through
+:meth:`Tracer.span`, which also opens a ``jax.profiler.TraceAnnotation``
+named ``repro:<name>`` around the body, so they land on the profiler's
+host plane on the same timeline as the device's ops.  Spans that cross
+steps (``request``, ``queue_wait``, ``decode``) stay ``begin``/``end``,
+in memory only: a profiler annotation must nest on its thread.
+
 Zero-cost-when-disabled contract: nothing in the serving stack calls into
 this module unless a tracer was explicitly attached; every public method
 bumps a module-level counter (:func:`callback_count`) so the test suite can
@@ -32,7 +42,10 @@ pin "disabled tracing == zero Python-level callbacks" exactly.
 """
 from __future__ import annotations
 
+import contextlib
 import time
+
+import jax
 
 REQUESTS_PID = 0          # request lifecycle tracks (tid = request uid)
 ENGINE_PID = 1            # engine track of slice 0 (1 + slice_idx generally)
@@ -71,8 +84,43 @@ class SimClock:
             self.t = t
 
 
+class WallClock:
+    """Real time: ``t`` reads ``time.perf_counter()``, the profiler's host
+    clock, and ``advance`` does nothing."""
+
+    __slots__ = ()
+
+    @property
+    def t(self) -> float:
+        return time.perf_counter()
+
+    def advance(self, t: float) -> None:
+        pass
+
+
+# what a hot path enters in place of ``Tracer.span`` when no tracer is
+# attached: ``with (tr.span(...) if tr is not None else NO_SPAN) as sp``
+# yields None and makes no obs call
+NO_SPAN = contextlib.nullcontext()
+
+
+class _Scope:
+    """The handle :meth:`Tracer.span` yields: ``set`` adds args to the
+    span and to its profiler annotation before they close."""
+
+    __slots__ = ("args", "_ann")
+
+    def __init__(self, ann):
+        self.args: dict = {}
+        self._ann = ann
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+
 class Tracer:
-    """Strictly-nested span recorder with sim-clock timestamps."""
+    """Strictly-nested span recorder over a virtual or a wall clock."""
 
     def __init__(self, clock: SimClock | None = None, sink=None,
                  retain: bool = True):
@@ -118,6 +166,8 @@ class Tracer:
         events spread over the tick's (measured) virtual extent."""
         global _N_CALLBACKS
         _N_CALLBACKS += 1
+        if isinstance(self.clock, WallClock):
+            return                      # stamps are wall time already
         self._anchor_sim = self.clock.t
         self._anchor_wall = time.perf_counter()
 
@@ -181,6 +231,23 @@ class Tracer:
             span["args"].update(args)
         self._emit(span)
         return span
+
+    @contextlib.contextmanager
+    def span(self, name: str, *, pid: int | None = None,
+             tid: int | None = None, **args):
+        """A span around the body of a ``with``, recorded as ``begin`` and
+        ``end`` record it and mirrored to the profiler as a
+        ``TraceAnnotation("repro:<name>", **args)``.  Yields a handle whose
+        ``set(**args)`` adds args at the end.  The in-memory close checks
+        nesting as :meth:`end` does; if the body raises, the span stays
+        open in memory and the annotation closes."""
+        global _N_CALLBACKS
+        _N_CALLBACKS += 1
+        self.begin(name, pid=pid, tid=tid, args=args)
+        with jax.profiler.TraceAnnotation(f"repro:{name}", **args) as ann:
+            scope = _Scope(ann)
+            yield scope
+        self.end(name, pid=pid, tid=tid, args=scope.args)
 
     def instant(self, name: str, *, pid: int | None = None,
                 tid: int | None = None, t: float | None = None,

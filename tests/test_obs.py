@@ -5,6 +5,7 @@ time-series, SLO stats in report(), drop reasons, the recompile detector,
 and Chrome trace-event export validity."""
 import dataclasses
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +95,50 @@ def test_sim_clock_is_monotone():
     assert c.t == 2.0
 
 
+def test_wall_clock_stamps_are_monotone_wall_time():
+    c = obs.WallClock()
+    t0 = time.perf_counter()
+    a = c.t
+    time.sleep(0.002)
+    b = c.t
+    t1 = time.perf_counter()
+    assert t0 <= a < b <= t1 and b - a >= 0.002
+    c.advance(b + 100.0)                # a wall clock is not advanced
+    assert c.t < b + 100.0
+    tr = obs.Tracer(clock=c)
+    tr.anchor()                         # no virtual window to anchor
+    t2 = time.perf_counter()
+    stamp = tr.now()
+    assert t2 <= stamp <= time.perf_counter()
+    tr.release()
+    tr.begin("a", tid=1)
+    time.sleep(0.001)
+    tr.end("a", tid=1)
+    (span,) = tr.spans("a")
+    assert span["ts"] >= t2 and span["dur"] >= 0.001
+
+
+def test_span_nests_records_end_args_and_rejects_bad_close():
+    tr = obs.Tracer(clock=obs.WallClock())
+    c0 = obs.callback_count()
+    with tr.span("outer", tid=3, active=2) as sp:
+        with tr.span("inner", tid=3):
+            pass
+        sp.set(finished=1)
+    assert obs.callback_count() > c0    # every entry point charges
+    spans = {s["name"]: s for s in tr.spans()}
+    assert spans["outer"]["args"] == {"active": 2, "finished": 1}
+    outer, inner = spans["outer"], spans["inner"]
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+    tr.assert_nested()
+    # a span left open inside the body makes the close fail, as end does
+    with pytest.raises(AssertionError, match="must nest"):
+        with tr.span("x", tid=4):
+            tr.begin("y", tid=4)
+    assert tr.innermost(tid=4) == "y"
+
+
 # ==========================================================================
 # Zero-cost-when-disabled: no tracer attached -> zero obs callbacks.
 # ==========================================================================
@@ -110,6 +155,24 @@ def test_disabled_tracing_makes_zero_callbacks():
     # SLO stamps still work without a tracer (bare SimClock path) ...
     assert all(r.t_admit >= 0 for r in tel.records)
     # ... and not one Python-level tracer callback was made
+    assert obs.callback_count() == c0
+
+
+def test_disabled_tracing_direct_batcher_zero_callbacks():
+    """The tick's scoped spans (admit, tick.prepare / dispatch / sample,
+    retire, prefill, prefill_chunk) make no obs call with no tracer."""
+    cfg, params = _setup()
+    ad = make_adapter(cfg, params, n_slots=2, max_len=16, paged=True,
+                      block_size=BS)
+    b = ContinuousBatcher(ad)
+    rng = np.random.default_rng(4)
+    c0 = obs.callback_count()
+    for uid in range(3):
+        b.submit(Request(uid=uid, prompt=rng.integers(
+            0, cfg.vocab, 6).astype(np.int32), max_new_tokens=3))
+    done = b.run()
+    assert sorted(r.uid for r in done) == [0, 1, 2]
+    assert all(len(r.generated) == 3 for r in done)
     assert obs.callback_count() == c0
 
 
@@ -415,3 +478,81 @@ def test_chrome_trace_validator_catches_structural_breaks():
     assert any("non-numeric ts" in e for e in errs)
     with pytest.raises(AssertionError, match="invalid trace"):
         obs.write_chrome_trace("/dev/null", obs.Tracer())  # empty events
+
+
+# ==========================================================================
+# Wall-clock spans on the profiler's host plane; queue wait from submit.
+# ==========================================================================
+
+def _host_events(trace_dir) -> list[tuple[str, int, int]]:
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro:")]
+
+
+def test_tick_spans_on_the_profiler_host_plane(tmp_path):
+    cfg, params = _setup()
+    ad = make_adapter(cfg, params, n_slots=2, max_len=16, paged=True,
+                      block_size=BS)
+    b = ContinuousBatcher(ad)
+    rng = np.random.default_rng(5)
+
+    def prompt():
+        return rng.integers(0, cfg.vocab, 6).astype(np.int32)
+    b.submit(Request(uid=0, prompt=prompt(), max_new_tokens=2))
+    b.run()                                 # compile outside the trace
+    tracer = obs.Tracer(clock=obs.WallClock())
+    b.tracer = ad.tracer = tracer
+    b.submit(Request(uid=1, prompt=prompt(), max_new_tokens=4))
+    jax.profiler.start_trace(str(tmp_path))
+    b.step()                                # admit, decode, retire
+    jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    (tick,) = [e for e in events if e[0] == "repro:tick"]
+    for name in ("repro:admit", "repro:prefill", "repro:prefill_chunk",
+                 "repro:tick.prepare", "repro:tick.dispatch",
+                 "repro:tick.sample", "repro:retire"):
+        inside = [e for e in events if e[0] == name]
+        assert inside, name
+        assert all(tick[1] <= a and e <= tick[2] for _, a, e in inside)
+    # the tick's children run one after another
+    order = [e[0] for e in sorted(events, key=lambda e: e[1])
+             if e[0] in ("repro:admit", "repro:tick.prepare",
+                         "repro:tick.dispatch", "repro:tick.sample",
+                         "repro:retire")]
+    assert order == ["repro:admit", "repro:tick.prepare",
+                     "repro:tick.dispatch", "repro:tick.sample",
+                     "repro:retire"]
+    # the in-memory spans: the tick's children on the engine track
+    kids = {s["name"]: s for s in tracer.spans() if s["pid"] == 1}
+    assert kids["tick"]["args"] == {"active": 1, "finished": 0}
+    assert kids["admit"]["args"] == {"admitted": 1, "prompt_tokens": 6}
+    assert kids["tick.prepare"]["args"] == {"active": 1, "live_tokens": 6}
+    assert {"tick.dispatch", "tick.sample", "retire"} <= set(kids)
+    b.tracer = ad.tracer = None
+
+
+def test_queue_wait_runs_from_submit_when_driven_directly():
+    cfg, params = _setup()
+    ad = make_adapter(cfg, params, n_slots=1, max_len=16, paged=True,
+                      block_size=BS)
+    b = ContinuousBatcher(ad)
+    tracer = obs.Tracer(clock=obs.WallClock())
+    b.tracer = ad.tracer = tracer
+    t0 = time.perf_counter()
+    b.submit(Request(uid=7, prompt=np.arange(5, dtype=np.int32),
+                     max_new_tokens=2))
+    time.sleep(0.01)
+    b.run()
+    (wait,) = tracer.spans("queue_wait")
+    assert t0 <= wait["ts"] and wait["dur"] >= 0.01
+    assert tracer.innermost(tid=7) == "request"     # no gateway closes it
+    assert "late_open" not in tracer.open_spans()[0]["args"]
+    b.tracer = ad.tracer = None
