@@ -56,8 +56,9 @@ NEG_INF = -1e30
 NO_WINDOW = 1 << 30
 
 
-def _paged_kernel(tables_ref, lens_ref, win_ref, q_ref, k_ref, v_ref, *rest,
-                  bs: int, nb: int, n_rep: int, scale: float, splice: bool):
+def _paged_kernel(tables_ref, lens_ref, win_ref, layer_ref, q_ref, k_ref,
+                  v_ref, *rest, bs: int, nb: int, n_rep: int, scale: float,
+                  splice: bool):
     if splice:
         k1_ref, v1_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -72,8 +73,8 @@ def _paged_kernel(tables_ref, lens_ref, win_ref, q_ref, k_ref, v_ref, *rest,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0].astype(jnp.float32)              # (Hq, D)
-    k = k_ref[0].astype(jnp.float32)              # (bs, Hkv, D)
-    v = v_ref[0].astype(jnp.float32)              # (bs, Hkv, D)
+    k = k_ref[0, 0, 0].astype(jnp.float32)        # (bs, Hkv, D)
+    v = v_ref[0, 0, 0].astype(jnp.float32)        # (bs, Hkv, D)
     Hq, D = q.shape
     Hkv = k.shape[1]
     pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
@@ -181,9 +182,14 @@ def scatter_kv_rows(k_arena, v_arena, k_rows, v_rows, wbids, offs, *,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_arena, v_arena, tables, lens, *,
-                           window=None, new_kv=None,
+                           window=None, new_kv=None, layer=None,
                            interpret: bool | None = None):
-    """q: (B, Hq, D); k_arena, v_arena: (num_blocks, bs, Hkv, D);
+    """q: (B, Hq, D); k_arena, v_arena: (num_blocks, bs, Hkv, D), or with
+    ``layer`` (may be traced) the whole ``(L, num_blocks, 1, bs, Hkv, D)``
+    arena of ``engine.init_paged_arena``, read at that layer: the serving
+    tick hands the arena over as it lies in HBM, since slicing one layer
+    out of it inside the layer scan would copy every block, live or not,
+    once per layer;
     tables: (B, nb) int32 arena block ids; lens: (B,) int32 valid lengths.
     ``window``: optional scalar (may be traced — the per-layer
     sliding/global selection is data-dependent inside a layer scan); only
@@ -199,7 +205,11 @@ def paged_decode_attention(q, k_arena, v_arena, tables, lens, *,
     from repro.kernels.ops import resolve_interpret
     interpret = resolve_interpret(interpret)
     B, Hq, D = q.shape
-    _, bs, Hkv, _ = k_arena.shape
+    if layer is None:               # one layer's arena, read as L = 1
+        k_arena, v_arena, layer = k_arena[None, :, None], \
+            v_arena[None, :, None], 0
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+    bs, Hkv = k_arena.shape[3:5]
     nb = tables.shape[1]
     n_rep = Hq // Hkv
     scale = D ** -0.5
@@ -207,13 +217,13 @@ def paged_decode_attention(q, k_arena, v_arena, tables, lens, *,
         window = NO_WINDOW
     win = jnp.where(jnp.asarray(window, jnp.int32) == 0, NO_WINDOW,
                     jnp.asarray(window, jnp.int32)).reshape(1)
-    row = pl.BlockSpec((1, Hq, D), lambda b, j, t, ln, w: (b, 0, 0))
-    blk = pl.BlockSpec((1, bs, Hkv, D),
-                       lambda b, j, t, ln, w: (t[b, j], 0, 0, 0))
-    kv_row = pl.BlockSpec((1, Hkv, D), lambda b, j, t, ln, w: (b, 0, 0))
+    row = pl.BlockSpec((1, Hq, D), lambda b, j, t, ln, w, y: (b, 0, 0))
+    blk = pl.BlockSpec((1, 1, 1, bs, Hkv, D),
+                       lambda b, j, t, ln, w, y: (y[0], t[b, j], 0, 0, 0, 0))
+    kv_row = pl.BlockSpec((1, Hkv, D), lambda b, j, t, ln, w, y: (b, 0, 0))
     splice = new_kv is not None
     operands = (jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32),
-                win, q, k_arena, v_arena)
+                win, lyr, q, k_arena, v_arena)
     in_specs = [row, blk, blk]
     if splice:
         operands += tuple(new_kv)
@@ -222,11 +232,11 @@ def paged_decode_attention(q, k_arena, v_arena, tables, lens, *,
         functools.partial(_paged_kernel, bs=bs, nb=nb, n_rep=n_rep,
                           scale=scale, splice=splice),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B, nb),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, Hq, D),
-                                   lambda b, j, t, ln, w: (b, 0, 0)),
+                                   lambda b, j, t, ln, w, y: (b, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((Hq,), jnp.float32),      # running max
                 pltpu.VMEM((Hq,), jnp.float32),      # running sum

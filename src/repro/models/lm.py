@@ -526,7 +526,7 @@ def _attn_apply(cfg: LMConfig, p, x, positions, *, causal=True, window=0,
 
 def attn_decode_paged(cfg: LMConfig, p, x1, k_blocks, v_blocks, tables, pos,
                       *, window=0, kernel=None, interpret=None,
-                      scales=None, backend=None, cascade=None):
+                      scales=None, backend=None, cascade=None, layer=None):
     """One-token decode attention for a batch of slots, reading K/V in
     place from one layer's slice of the paged block arena.
 
@@ -561,6 +561,10 @@ def attn_decode_paged(cfg: LMConfig, p, x1, k_blocks, v_blocks, tables, pos,
     metadata for the last — see :mod:`repro.serve.backend`) is the read-
     path dispatch forwarded to :func:`nn.attention.attend_decode_paged`;
     ``kernel=True`` survives as the deprecated alias for "pallas".
+
+    ``layer`` (``"pallas"`` only): ``k_blocks``/``v_blocks`` are the whole
+    layer-leading ``(L, num_blocks, 1, bs, Hkv, Dh)`` arena, handed to the
+    kernel as it lies in HBM, and the kernel reads this layer of it.
     """
     if backend is None:
         backend = "pallas" if kernel else "xla"
@@ -573,7 +577,10 @@ def attn_decode_paged(cfg: LMConfig, p, x1, k_blocks, v_blocks, tables, pos,
         posb = pos[:, None]
         q = rope.apply_rope(q, posb, cfg.rope_theta)
         k1 = rope.apply_rope(k1, posb, cfg.rope_theta)
-    kb, vb = k_blocks[:, 0], v_blocks[:, 0]      # (num_blocks, bs, Hkv, Dh)
+    if layer is None:
+        kb, vb = k_blocks[:, 0], v_blocks[:, 0]  # (num_blocks, bs, Hkv, Dh)
+    else:
+        kb, vb = k_blocks, v_blocks
     if scales is not None:
         assert backend == "xla", \
             "only the XLA reference covers the int8 kv_quant layout"
@@ -592,7 +599,7 @@ def attn_decode_paged(cfg: LMConfig, p, x1, k_blocks, v_blocks, tables, pos,
                                       window=window,
                                       new_kv=(k1[:, 0], v1[:, 0]),
                                       backend=backend, cascade=cascade,
-                                      interpret=interpret)
+                                      layer=layer, interpret=interpret)
     out = _proj(o.reshape(B, 1, cfg.n_heads * cfg.d_head), p["wo"],
                 p.get("bo"))
     return out, k1[:, 0], v1[:, 0]

@@ -377,7 +377,7 @@ def gather_paged_kv(arena, block_table) -> jax.Array:
 def attend_decode_paged(q, k_arena, v_arena, block_table, cache_len, *,
                         window=0, new_kv=None, scales=None,
                         out_dtype=None, backend=None, cascade=None,
-                        interpret=None) -> jax.Array:
+                        layer=None, interpret=None) -> jax.Array:
     """One-token decode attention against a *paged* cache (single layer).
 
     q: (B, 1, Hq, D); k_arena, v_arena: (num_blocks, bs, Hkv, D);
@@ -409,6 +409,10 @@ def attend_decode_paged(q, k_arena, v_arena, block_table, cache_len, *,
     ``"cascade"`` routes to :func:`attend_decode_cascade` with the group
     metadata in ``cascade``.  The ``"xla"`` body is the reference
     semantics the other two are pinned against.
+
+    ``layer`` (``"pallas"`` only): the arenas are the whole layer-leading
+    ``(L, num_blocks, 1, bs, Hkv, D)`` arrays and the kernel reads this
+    layer of them (see ``kernels.paged_attn.paged_decode_attention``).
     """
     if backend == "pallas":
         from repro.kernels.paged_attn import paged_decode_attention
@@ -416,8 +420,9 @@ def attend_decode_paged(q, k_arena, v_arena, block_table, cache_len, *,
         nk = None if new_kv is None else (new_kv[0], new_kv[1])
         out = paged_decode_attention(q[:, 0], k_arena, v_arena, block_table,
                                      cache_len, window=window, new_kv=nk,
-                                     interpret=interpret)
+                                     layer=layer, interpret=interpret)
         return out[:, None]
+    assert layer is None, "only the pallas backend reads a whole arena"
     if backend == "cascade":
         assert cascade is not None, "cascade backend needs group metadata"
         return attend_decode_cascade(q, k_arena, v_arena, cascade, cache_len,

@@ -96,6 +96,12 @@ def init_paged_arena(cfg: LMConfig, num_blocks: int, block_size: int,
     over per-layer ``(num_blocks, 1, bs, ...)`` slices — and so the arena
     shards over a mesh with the same leading-axes PartitionSpec shape as
     ``cache_specs`` gives the dense layout (the next ROADMAP item).
+
+    The paged adapter pins the arena's HBM format row-major where a Pallas
+    kernel touches it (``kvcache.paged.arena_formats``): head dims 80 and
+    64 are not multiples of the TPU's 128 lanes, so the compiler's default
+    layout puts the block axis minor-most, and every tick would relayout
+    the whole arena around the kernels, which read and write it row-major.
     """
     blk = init_cache(cfg, 1, block_size, abstract=True)
     mk = (lambda s, d: jax.ShapeDtypeStruct(s, d)) if abstract else \
@@ -790,8 +796,9 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
 
     Maintenance note: the per-family layer bodies below deliberately
     mirror :func:`decode_step` (only the cache plumbing differs — scan xs
-    are arena slices instead of per-layer dense caches, and the write is a
-    row instead of a buffer).  Any numeric change to a family's decode
+    are arena slices instead of per-layer dense caches, or none where the
+    Pallas kernel reads the whole arena at the layer index, and the write
+    is a row instead of a buffer).  Any numeric change to a family's decode
     body must land in BOTH functions; the bitwise parity suite exists to
     catch exactly that drift, so a paged-parity failure after touching
     :func:`decode_step` means this copy is stale, not that paging broke.
@@ -834,33 +841,45 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
     new_cache = dict(cache)
     new_cache["len"] = pos + 1
 
-    def attn(lp, z, kb, vb, window=0, scales=None):
-        """Returns (out, *rows): rows are the sequence-axis writes this
-        layer owes the arena — (k1, v1) plain, + (k1_scale, v1_scale)
-        under the int8 kv_quant layout."""
+    # the Pallas kernel reads the whole layer-leading arena at a layer
+    # index, so the layer scan hands it no slice: a per-layer slice of the
+    # arena is materialized whole, every block live or not, once per layer
+    # and tick.  The XLA reads take per-layer slices as scan inputs.
+    whole = backend == "pallas"
+
+    def attn(lp, z, kv, layer, window=0):
+        """``kv``: this layer's arena slices (k, v[, k_scale, v_scale]),
+        empty when the kernel reads the whole arena at ``layer``.  Returns
+        (out, *rows): rows are the sequence-axis writes this layer owes
+        the arena — (k1, v1) plain, + (k1_scale, v1_scale) under the int8
+        kv_quant layout."""
+        if whole:
+            kb, vb, sc = arena["k"], arena["v"], None
+        else:
+            kb, vb, sc, layer = kv[0], kv[1], (kv[2:] or None), None
         out = lm.attn_decode_paged(cfg, lp, z, kb, vb, tables, pos,
                                    window=window, backend=backend,
                                    cascade=cascade, interpret=interpret,
-                                   scales=scales)
+                                   scales=sc, layer=layer)
         return out[0], out[1:]
 
     def layer_arenas(sl):
+        if whole:
+            return ()
         out = (arena["k"][sl], arena["v"][sl])
         if quant:
             out += (arena["k_scale"][sl], arena["v_scale"][sl])
         return out
 
-    def split_sc(arenas):
-        return (arenas[:2], arenas[2:] if quant else None)
-
     if fam in ("decoder", "moe"):
-        L = cfg.n_layers - (1 if fam == "moe" else 0)
+        first = 1 if fam == "moe" else 0    # moe's layer 0 is dense0
+        L = cfg.n_layers - first
 
         def body(x, inp):
             lp, idx = inp[0], inp[-1]
-            (kb, vb), sc = split_sc(inp[1:-1])
             h, rows = attn(lp["attn"], _norm_apply(cfg, lp["ln1"], x),
-                           kb, vb, window=layer_window(cfg, idx), scales=sc)
+                           inp[1:-1], idx + first,
+                           window=layer_window(cfg, idx))
             x = x + h
             z = _norm_apply(cfg, lp["ln2"], x)
             if fam == "moe":
@@ -875,14 +894,12 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
 
         if fam == "moe":
             p0 = jax.tree.map(lambda a: a[0], params["dense0"])
-            (kb0, vb0), sc0 = split_sc(layer_arenas(0))
             h, rows0 = attn(p0["attn"], _norm_apply(cfg, p0["ln1"], x),
-                            kb0, vb0, scales=sc0)
+                            layer_arenas(0), 0)
             x = x + h
             x = x + _mlp_apply(cfg, p0["mlp"], _norm_apply(cfg, p0["ln2"], x))
-        off = slice(1, None) if fam == "moe" else slice(None)
         x, rows = jax.lax.scan(
-            body, x, (params["blocks"],) + layer_arenas(off)
+            body, x, (params["blocks"],) + layer_arenas(slice(first, None))
             + (jnp.arange(L, dtype=jnp.int32),))
         if fam == "moe":
             rows = tuple(jnp.concatenate([r0[None], r], 0)
@@ -891,10 +908,9 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
     elif fam == "hybrid":
         def body(x, inp):
             lp, conv_st, ssm_st, idx = inp[0], inp[-3], inp[-2], inp[-1]
-            (kb, vb), sc = split_sc(inp[1:-3])
             z = _norm_apply(cfg, lp["ln1"], x)
-            att, kv_rows = attn(lp["attn"], z, kb, vb,
-                                window=layer_window(cfg, idx), scales=sc)
+            att, kv_rows = attn(lp["attn"], z, inp[1:-3], idx,
+                                window=layer_window(cfg, idx))
             xz = _proj(z, lp["in_proj"])
             xm, gate = jnp.split(xz, 2, axis=-1)
             xm, conv_st = _causal_conv(xm, lp["conv_w"], conv_st)
@@ -932,9 +948,9 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
 
     elif fam == "encdec":
         def body(x, inp):
-            lp, kb, vb, xk, xv = inp
+            lp, xk, xv, idx = inp[0], inp[-3], inp[-2], inp[-1]
             h, rows = attn(lp["attn"], _norm_apply(cfg, lp["ln1"], x),
-                           kb, vb)
+                           inp[1:-3], idx)
             x = x + h
             q = _proj(_norm_apply(cfg, lp["ln_x"], x), lp["xattn"]["wq"],
                       lp["xattn"].get("bq")).reshape(
@@ -949,9 +965,10 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
             return x, rows
 
         x, rows = jax.lax.scan(
-            body, x, (params["dec_blocks"], arena["k"], arena["v"],
-                      jnp.moveaxis(cache["xk"], 1, 0)[:, :, 0],
-                      jnp.moveaxis(cache["xv"], 1, 0)[:, :, 0]))
+            body, x, (params["dec_blocks"],) + layer_arenas(slice(None))
+            + (jnp.moveaxis(cache["xk"], 1, 0)[:, :, 0],
+               jnp.moveaxis(cache["xv"], 1, 0)[:, :, 0],
+               jnp.arange(cfg.n_layers, dtype=jnp.int32)))
 
     elif fam == "vlm":
         k_ = cfg.cross_every
@@ -965,14 +982,15 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
             def inner(x, sinp):
                 lp, kb, vb = sinp
                 h, rows_i = attn(lp["attn"], _norm_apply(cfg, lp["ln1"], x),
-                                 kb, vb)
+                                 (kb, vb), None)
                 x = x + h
                 x = x + _mlp_apply(cfg, lp["mlp"],
                                    _norm_apply(cfg, lp["ln2"], x))
                 return x, rows_i
             x, self_rows = jax.lax.scan(inner, x, (self_p, kb_g, vb_g))
             h, x_rows = attn(cross_p["attn"],
-                             _norm_apply(cfg, cross_p["ln1"], x), kxb, vxb)
+                             _norm_apply(cfg, cross_p["ln1"], x), (kxb, vxb),
+                             None)
             x = x + h
             q = _proj(_norm_apply(cfg, cross_p["ln_x"], x),
                       cross_p["xattn"]["wq"]).reshape(

@@ -34,6 +34,12 @@ Decode tick (one jitted call, fixed shapes)
     f32 scale row per layer, and the attention read dequantizes the
     gathered view — bitwise against the gather-tick oracle.
 
+    Under the Pallas backend the arena lies in HBM in the layout the
+    kernels read (row-major, :func:`arena_formats`), every program that
+    takes it declares that format, and the kernels get the whole
+    layer-leading arena plus a layer index: the compiled tick holds no
+    relayout and no per-layer slice of the arena.
+
 Sharing / copy-on-write
     Admission walks the pool's radix index: full prompt blocks that match an
     earlier request's chain are referenced instead of written (their prefill
@@ -72,12 +78,16 @@ Admission control
 """
 from __future__ import annotations
 
+import contextlib
+import math
 from collections import OrderedDict
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
+from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from repro.models.lm import LMConfig
 from repro.serve import engine
@@ -112,6 +122,117 @@ def chunk_fold_fn(cfg: LMConfig) -> Callable:
         fn = jax.jit(prefill_chunk, static_argnums=(3,))
         _CHUNK_FOLDS[cfg] = fn
     return fn
+
+
+def arena_formats(arena, backend: str, shardings: dict) -> dict | None:
+    """The HBM format every program that takes the arena declares for it,
+    per key, or None for the compiler's default layout.
+
+    On the TPU the default layout of an array whose minor dim is not a
+    multiple of the 128 lanes (head dims 80 and 64) puts the block axis
+    minor-most, sparing the head dim its padding.  The Pallas kernels read
+    and write the arena row-major, so under that default the compiled tick
+    relayouts the whole arena into row-major and back around them, and each
+    layer's slice once more, every tick.  Where a Pallas kernel touches the
+    arena (``backend="pallas"``) it is therefore held row-major — the head
+    dim padded to 128 lanes — by every program that takes it; the XLA reads
+    of the other backends suit the compact default and keep it.  On the CPU
+    the default layout is row-major already.  ``arena`` holds arrays or
+    ShapeDtypeStructs; ``shardings`` maps each key to its placement."""
+    if backend != "pallas":
+        return None
+    return {key: Format(Layout(tuple(range(a.ndim))), shardings[key])
+            for key, a in arena.items()}
+
+
+@contextlib.contextmanager
+def _not_persisted():
+    """Nothing compiled inside enters JAX's persistent compile cache."""
+    was = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", math.inf)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
+
+
+def _unpersisted(fn):
+    """``fn``, a jitted program that returns the pinned arena, with every
+    compile it makes kept out of JAX's persistent compile cache.  An
+    executable loaded from that cache labels its outputs with the default
+    layout whatever layout it wrote them in (jax 0.9.0): a warm process
+    would misread the arena, or see the next program that declares its
+    format refuse it.  Such programs compile afresh in every process (the
+    full-width tick in about 2 s).  jit compiles inside the call that
+    misses its cache, so the call is the compile's scope, and so is
+    ``lower(...).compile()``.  Drop this once jax keeps the layout."""
+    def call(*args, **kw):
+        with _not_persisted():
+            out = fn(*args, **kw)
+        if isinstance(out, jax.stages.Lowered):
+            out.compile = _unpersisted(out.compile)
+        return out
+    if hasattr(fn, "lower"):
+        call.lower, call._cache_size = _unpersisted(fn.lower), fn._cache_size
+    return call
+
+
+def jit_arena(fn, fmt: dict | None, n_args: int, *, arena_arg: int = 0,
+              out=None, **jit_kw):
+    """``jax.jit(fn, **jit_kw)`` with the arena argument (position
+    ``arena_arg`` of ``n_args``) and, through ``out(fmt)``, the arena in
+    the result held to ``fmt`` (:func:`arena_formats`).  A donated arena
+    then aliases in place with no change of layout, and an arena in any
+    other layout is refused, not relayouted.  A program that returns the
+    arena is :func:`_unpersisted`.  ``fmt=None`` declares nothing."""
+    if fmt is None:
+        return jax.jit(fn, **jit_kw)
+    jit_kw["in_shardings"] = tuple(fmt if i == arena_arg else None
+                                   for i in range(n_args))
+    if out is None:
+        return jax.jit(fn, **jit_kw)
+    jit_kw["out_shardings"] = out(fmt)
+    return _unpersisted(jax.jit(fn, **jit_kw))
+
+
+def decode_tick(cfg: LMConfig, max_len: int, backend: str,
+                fmt: dict | None = None, interpret: bool | None = None):
+    """The adapter's gather-free decode tick under ``backend`` ("xla",
+    "pallas" or "cascade"), jitted with the arena and slot state donated
+    and the arena held to ``fmt``: ``(params, arena, dense, tables, tokens,
+    mask, wbids[, cascade]) -> (arena, dense, logits)``.
+
+    :func:`engine.decode_step_paged` reads K/V through the block tables
+    inside every attention layer and writes back one row per layer — no
+    dense per-slot cache, no block rescatter.  Non-sequence state is masked
+    exactly like the gather tick, so inactive lanes keep the state
+    ``clear`` left them.  Under "cascade", ``cascade`` is the host-built
+    group metadata of :meth:`PagedKVSlotAdapter._cascade_meta`: every
+    attention layer reads each shared radix chain once per group and
+    merges the partial softmax states by log-sum-exp
+    (:func:`nn.attention.attend_decode_cascade`); the write epilogue is the
+    flat tick's."""
+    def _tick_inplace_impl(p, arena, dense, tables, tokens, mask, wbids,
+                           cascade=None):
+        # a lane whose len escaped the table (at capacity / accounting
+        # drift) must write the trash block, never a real — possibly
+        # shared — one (the gather tick's defense too)
+        wbids = jnp.where(dense["len"] >= max_len, TRASH_BLOCK, wbids)
+        new_arena, new_cache, logits = engine.decode_step_paged(
+            cfg, p, dense, tokens, tables=tables, lens=dense["len"],
+            arena=arena, wbids=wbids, backend=backend, cascade=cascade,
+            interpret=interpret)
+        sel = lambda new, old: jnp.where(
+            mask.reshape((-1,) + (1,) * (new.ndim - 1)), new, old)
+        new_dense = {key: sel(new_cache[key], dense[key]) for key in dense}
+        return new_arena, new_dense, logits
+    # each tick keeps its own program name in traces
+    if backend == "cascade":
+        _tick_inplace_impl.__name__ = "_tick_cascade_impl"
+        _tick_inplace_impl.__qualname__ = "_tick_cascade_impl"
+    return jit_arena(_tick_inplace_impl, fmt,
+                     8 if backend == "cascade" else 7, arena_arg=1,
+                     out=lambda f: (f, None, None), donate_argnums=(1, 2))
 
 
 class PagedKVSlotAdapter:
@@ -170,7 +291,42 @@ class PagedKVSlotAdapter:
             # dense-equivalent capacity + the reserved trash block
             num_blocks = n_slots * self.nb_max + 1
         self.pool = BlockPool(num_blocks, block_size)
-        self.arena = engine.init_paged_arena(cfg, num_blocks, block_size)
+        # mesh-partitioned placement (serve/shard/): commit the arena to
+        # the slice mesh with engine.arena_specs (KV heads over "model"
+        # when divisible — the same rule cache_specs applies to the dense
+        # layout) and replicate params + the slot-stacked state across the
+        # slice's devices.  Every jit below then compiles *sharded* —
+        # GSPMD partitions the tick/fold over the slice — while a
+        # single-device slice runs the exact unsharded executable (the
+        # bitwise-parity contract tests/test_sharded.py pins).  A pinned
+        # arena format (arena_formats) commits the arena to the default
+        # device.  Whatever is committed, the slot-stacked state and the
+        # cold fold's inputs are committed beside it (``self.placement``):
+        # a program fed committed arrays on one call and uncommitted ones
+        # on the next compiles twice.  The arena is made by one program
+        # straight in its placement and format, so the device never holds
+        # it twice.
+        self.mesh = mesh
+        shape = engine.init_paged_arena(cfg, num_blocks, block_size,
+                                        abstract=True)
+        if mesh is not None:
+            from jax.sharding import PartitionSpec as P
+            from repro.dist.sharding import mesh_shape_dict
+            specs = engine.arena_specs(cfg, mesh_shape_dict(mesh))
+            where = {key: NamedSharding(mesh, specs[key]) for key in shape}
+            self.placement = NamedSharding(mesh, P())
+        else:
+            self.placement = SingleDeviceSharding(jax.devices()[0])
+            where = dict.fromkeys(shape, self.placement)
+        self.arena_format = arena_formats(shape, self.backend, where)
+        if mesh is None and self.arena_format is None:
+            self.placement = None
+        make = lambda: engine.init_paged_arena(cfg, num_blocks, block_size)
+        if self.placement is None:
+            self.arena = make()
+        else:
+            self.arena = _unpersisted(jax.jit(
+                make, out_shardings=self.arena_format or where))()
         self.seq_keys = tuple(self.arena)
         self._bax = {key: engine.arena_block_axis(a)
                      for key, a in self.arena.items()}
@@ -196,9 +352,9 @@ class PagedKVSlotAdapter:
 
         # densely slot-stacked non-sequence state (incl. the scalar "len")
         cache0 = engine.init_cache(cfg, 1, self.max_len)
-        self.cache = {
+        self.cache = self._place({
             key: jnp.zeros((n_slots,) + np.shape(a), jnp.asarray(a).dtype)
-            for key, a in cache0.items() if key not in self.arena}
+            for key, a in cache0.items() if key not in self.arena})
 
         # host-side paging state
         self.tables = np.zeros((n_slots, self.nb_max), np.int32)
@@ -217,26 +373,8 @@ class PagedKVSlotAdapter:
         self.peak_blocks_in_use = 0
         self.peak_bytes_saved = 0
 
-        # mesh-partitioned placement (serve/shard/): commit the arena to
-        # the slice mesh with engine.arena_specs (KV heads over "model"
-        # when divisible — the same rule cache_specs applies to the dense
-        # layout) and replicate params + the slot-stacked state across the
-        # slice's devices.  Every jit below then compiles *sharded* —
-        # GSPMD partitions the tick/fold over the slice — while a
-        # single-device slice runs the exact unsharded executable (the
-        # bitwise-parity contract tests/test_sharded.py pins).
-        self.mesh = mesh
         if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            from repro.dist.sharding import mesh_shape_dict
-            specs = engine.arena_specs(cfg, mesh_shape_dict(mesh))
-            self.arena = {
-                key: jax.device_put(a, NamedSharding(mesh, specs[key]))
-                for key, a in self.arena.items()}
-            rep = NamedSharding(mesh, P())
-            self.cache = {key: jax.device_put(a, rep)
-                          for key, a in self.cache.items()}
-            self.params = jax.device_put(params, rep)
+            self.params = self._place(params)
 
         def prefill(p, b):
             return engine.prefill(cfg, p, b)
@@ -248,7 +386,8 @@ class PagedKVSlotAdapter:
         # shared *process-wide* across every adapter of this config
         # (chunk_fold_fn), so a second gateway slice pays zero retraces
         self._chunk_fn = chunk_fold_fn(cfg)
-        self._gather_prefix = jax.jit(self._gather_prefix_impl)
+        fmt = self.arena_format
+        self._gather_prefix = jit_arena(self._gather_prefix_impl, fmt, 2)
         if cfg.family == "encdec":
             self._encode = jax.jit(lambda p, e: engine.encode_cross(cfg, p, e))
         # donate the arena (and dense cache) through every call that rebinds
@@ -256,20 +395,32 @@ class PagedKVSlotAdapter:
         # second full arena copy — the whole point of the fixed byte budget.
         # Every backend donates, the CPU included, so the CPU tests see the
         # chip's semantics: a stale reference to a donated arena is deleted.
-        self._scatter = jax.jit(self._scatter_impl, donate_argnums=(0,))
-        self._copy = jax.jit(self._copy_impl, donate_argnums=(0,))
-        self._write_block = jax.jit(self._write_block_impl,
-                                    donate_argnums=(0,))
-        tick = self._tick_inplace_impl if self.inplace else self._tick_impl
-        self._decode = jax.jit(tick, donate_argnums=(1, 2))
+        same = lambda f: f
+        self._scatter = jit_arena(self._scatter_impl, fmt, 3, out=same,
+                                  donate_argnums=(0,))
+        self._copy = jit_arena(self._copy_impl, fmt, 3, out=same,
+                               donate_argnums=(0,))
+        self._write_block = jit_arena(self._write_block_impl, fmt, 3,
+                                      out=same, donate_argnums=(0,))
+        if self.inplace:
+            self._decode = decode_tick(cfg, self.max_len, "pallas"
+                                       if self.kernel else "xla", fmt)
+        else:
+            self._decode = jax.jit(self._tick_impl, donate_argnums=(1, 2))
         # the cascade tick sits NEXT TO the flat one, not instead of it:
         # a tick on which no chain is shared by >= 2 lanes degrades to
         # self._decode — the *same* executable, hence bitwise.  jit
         # specializes per metadata bucket shape (next-pow-2 padded group /
         # chain / lane / suffix counts), a fixed set in the steady state.
         if self.backend == "cascade":
-            self._decode_cascade = jax.jit(
-                self._tick_cascade_impl, donate_argnums=(1, 2))
+            self._decode_cascade = decode_tick(cfg, self.max_len, "cascade",
+                                               fmt)
+
+    def _place(self, tree):
+        """Commit ``tree`` to ``self.placement`` (left as is where None)."""
+        if self.placement is None:
+            return tree
+        return jax.device_put(tree, self.placement)
 
     # -- jitted bodies ------------------------------------------------------
 
@@ -332,17 +483,18 @@ class PagedKVSlotAdapter:
                                              jnp.asarray(bids, jnp.int32)))
         else:
             empty = engine.init_cache(self.cfg, 1, 0, abstract=True)
-            cache = {key: jnp.zeros(empty[key].shape, empty[key].dtype)
-                     for key in self.seq_keys if key in empty}
+            cache = self._place({
+                key: jnp.zeros(empty[key].shape, empty[key].dtype)
+                for key in self.seq_keys if key in empty})
         cache["len"] = jnp.int32(q0)
         if self.cfg.family == "hybrid":
             if state is None:
                 L = self.cfg.n_layers
-                state = {
+                state = self._place({
                     "conv": jnp.zeros((L, 1, self.cfg.conv_k - 1,
                                        self.cfg.inner), self.cfg.dtype),
                     "ssm": jnp.zeros((L, 1, self.cfg.inner,
-                                      self.cfg.ssm_state), jnp.float32)}
+                                      self.cfg.ssm_state), jnp.float32)})
             cache.update(state)
         if self.cfg.family == "encdec":
             batch = self.extras() if self.extras is not None else {}
@@ -414,44 +566,6 @@ class PagedKVSlotAdapter:
             idx = (slice(None),) * ax + (wbids,)
             new_arena[key] = arena[key].at[idx].set(blk)
         return new_arena, new_dense, logits[:, 0]
-
-    def _tick_inplace_impl(self, p, arena, dense, tables, tokens, mask,
-                           wbids):
-        """The gather-free tick: :func:`engine.decode_step_paged` reads K/V
-        through the block tables inside every attention layer and writes
-        back one row per layer — no dense per-slot cache, no block
-        rescatter.  Non-sequence state is masked exactly like the gather
-        tick, so inactive lanes keep the state ``clear`` left them."""
-        # same out-of-range defense as the gather tick: a lane whose len
-        # escaped the table (at capacity / accounting drift) must write the
-        # trash block, never a real — possibly shared — one
-        wbids = jnp.where(dense["len"] >= self.max_len, TRASH_BLOCK, wbids)
-        new_arena, new_cache, logits = engine.decode_step_paged(
-            self.cfg, p, dense, tokens, tables=tables, lens=dense["len"],
-            arena=arena, wbids=wbids,
-            backend="pallas" if self.kernel else "xla")
-        sel = lambda new, old: jnp.where(
-            mask.reshape((-1,) + (1,) * (new.ndim - 1)), new, old)
-        new_dense = {key: sel(new_cache[key], dense[key]) for key in dense}
-        return new_arena, new_dense, logits
-
-    def _tick_cascade_impl(self, p, arena, dense, tables, tokens, mask,
-                           wbids, cascade):
-        """The in-place tick with shared-prefix cascade attention: every
-        attention layer reads each shared radix chain *once per group*
-        (multi-query pass, prefix KV gathered once), each divergent suffix
-        per lane, and merges the partial softmax states by log-sum-exp
-        (:func:`nn.attention.attend_decode_cascade`).  ``cascade`` is the
-        host-built group metadata from :meth:`_cascade_meta`; the write
-        epilogue is identical to the flat tick."""
-        wbids = jnp.where(dense["len"] >= self.max_len, TRASH_BLOCK, wbids)
-        new_arena, new_cache, logits = engine.decode_step_paged(
-            self.cfg, p, dense, tokens, tables=tables, lens=dense["len"],
-            arena=arena, wbids=wbids, backend="cascade", cascade=cascade)
-        sel = lambda new, old: jnp.where(
-            mask.reshape((-1,) + (1,) * (new.ndim - 1)), new, old)
-        new_dense = {key: sel(new_cache[key], dense[key]) for key in dense}
-        return new_arena, new_dense, logits
 
     # -- admission ----------------------------------------------------------
 

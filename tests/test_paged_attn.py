@@ -127,6 +127,39 @@ def test_paged_kernel_new_kv_splice_matches_insert():
     np.testing.assert_array_equal(np.asarray(kern), np.asarray(kern_want))
 
 
+@pytest.mark.parametrize("window", [None, 5, 13])
+def test_whole_arena_layer_call_matches_per_layer_reference(window):
+    """The serving tick hands the kernel the whole layer-leading arena
+    ``(L, num_blocks, 1, bs, Hkv, D)`` and a layer index instead of a
+    per-layer slice: for every layer, the kernel matches
+    attend_decode_paged on that layer's slice and is bitwise its own
+    single-layer call — over trash-padded tables, with and without sliding
+    windows."""
+    rng = np.random.default_rng(17 + (window or 0))
+    L, B, nb, bs, Hq, Hkv, D = 3, 3, 4, 8, 4, 2, 32
+    num_blocks = B * nb + 1
+    q, _, _, tables, lens = _make_case(rng, B, nb, bs, Hq, Hkv, D,
+                                       num_blocks, jnp.float32)
+    shape = (L, num_blocks, 1, bs, Hkv, D)
+    ka = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    va = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    k1 = jnp.asarray(rng.normal(size=(B, Hkv, D)), jnp.float32)
+    v1 = jnp.asarray(rng.normal(size=(B, Hkv, D)), jnp.float32)
+    tol = dict(rtol=2e-5, atol=2e-5)
+    for layer in range(L):
+        ks, vs = ka[layer, :, 0], va[layer, :, 0]
+        want = attention.attend_decode_paged(
+            q[:, None], ks, vs, tables, lens, window=window or 0,
+            new_kv=(k1, v1))[:, 0]
+        got = paged_decode_attention(q, ka, va, tables, lens, window=window,
+                                     new_kv=(k1, v1), layer=jnp.int32(layer),
+                                     interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), **tol)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(
+            paged_decode_attention(q, ks, vs, tables, lens, window=window,
+                                   new_kv=(k1, v1), interpret=True)))
+
+
 def test_default_interpret_env_override(monkeypatch):
     """REPRO_KERNELS_INTERPRET forces the mode either way; unset falls
     back to the backend probe — what the CI kernels-interpret leg relies

@@ -324,8 +324,7 @@ def test_full_chain_gather_gone_from_inplace_tick():
             jnp.zeros((2,), jnp.int32))
     full_chain = (2 * cfg.n_layers * ad.nb_max * ad.bs
                   * cfg.n_kv_heads * cfg.d_head)
-    new = _gather_out_sizes(jax.make_jaxpr(ad._tick_inplace_impl)(*args)
-                            .jaxpr, [])
+    new = _gather_out_sizes(jax.make_jaxpr(ad._decode)(*args).jaxpr, [])
     assert new and max(new) < full_chain
     # guard the pin itself: the legacy tick DOES contain that gather
     old = _gather_out_sizes(jax.make_jaxpr(ad._tick_impl)(*args).jaxpr, [])
@@ -365,3 +364,158 @@ def test_kernel_tick_matches_reference(arch):
         np.testing.assert_allclose(np.asarray(ker.last_logits),
                                    np.asarray(ref.last_logits),
                                    rtol=2e-4, atol=2e-4)
+
+
+# ==========================================================================
+# The arena's HBM format (kvcache/paged.py arena_formats): row-major where
+# a Pallas kernel touches the arena, declared by every program taking it.
+# ==========================================================================
+
+@pytest.mark.parametrize("backend,sliced", [
+    pytest.param("pallas", False, id="pallas"),
+    pytest.param("xla", False, id="xla"),
+    pytest.param("pallas", True, id="pallas-sliced")])
+def test_arena_format_held_through_insert_cow_tick_and_migration(backend,
+                                                                 sliced):
+    """backend="pallas" pins the arena row-major: insert (``_scatter``),
+    copy-on-write (``_copy``), the tick and a migration landing
+    (``_write_block``) all hand it back in that format, every donated
+    reference is deleted, and every program that takes the arena refuses
+    one laid out otherwise instead of relayouting it.  backend="xla" declares
+    no format and takes the arena as it comes.  ``sliced`` places the two
+    adapters on one-device slice meshes of the sharded gateway, so the
+    format rides a NamedSharding and the migration crosses slices.  The
+    tokens follow the gather-tick oracle throughout (the logits bitwise
+    under "xla", within the kernel's tolerance under "pallas")."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import Mesh, NamedSharding
+    from repro.serve.shard.migrate import migrate_slot
+    cfg, params, _ = _setup("stablelm_3b")
+    rng = np.random.default_rng(13)
+    prompt = rng.integers(0, cfg.vocab, size=6).astype(np.int32)
+    devs = jax.devices()
+    mesh = lambda i: Mesh(np.asarray([devs[i % len(devs)]]), ("model",)) \
+        if sliced else None
+    mk = lambda b, i=0: make_adapter(cfg, params, n_slots=2, max_len=24,
+                                     paged=True, block_size=BS,
+                                     chunked=False, backend=b, mesh=mesh(i))
+    ad, oracle, dst = mk(backend), mk("gather"), mk(backend, 1)
+    row_major = {key: tuple(range(a.ndim)) for key, a in ad.arena.items()}
+    if backend == "pallas":
+        assert {key: f.layout.major_to_minor for key, f in
+                ad.arena_format.items()} == row_major
+    else:
+        assert ad.arena_format is None
+
+    def held(a, old):
+        for arr in a.arena.values():
+            assert arr.format.layout.major_to_minor == tuple(range(arr.ndim))
+            assert isinstance(arr.sharding, NamedSharding) == sliced
+            if sliced:
+                assert arr.sharding.mesh == a.mesh
+        assert all(arr.is_deleted() for arr in old.values())
+
+    for slot in range(2):           # the second shares the partial block
+        old = ad.arena
+        assert ad.insert(slot, prompt, max_new=8) == \
+            oracle.insert(slot, prompt, max_new=8)
+        held(ad, old)
+    assert ad.slot_stats(1)["prefix_hit_blocks"] == 2
+    active = np.ones(2, bool)
+    for toks in ([3, 7], [11, 2], [5, 5]):
+        toks = np.asarray(toks, np.int32)
+        old = ad.arena
+        np.testing.assert_array_equal(ad.decode(toks, active),
+                                      oracle.decode(toks, active))
+        held(ad, old)
+        got, want = (np.asarray(a.last_logits) for a in (ad, oracle))
+        if backend == "xla":
+            np.testing.assert_array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    assert ad.pool.cow_copies >= 1
+    # the slot state is committed beside the arena: one tick executable
+    assert ad._decode._cache_size() == 1
+
+    live = -(-int(ad.lens[0]) // BS)
+    src_blocks = {k: v for k, v in _chain_blocks(ad, 0).items()
+                  if k[1] < live}
+    old = dst.arena
+    migrate_slot(ad, 0, dst, 0, prompt)
+    held(dst, old)
+    for (key, j), blk in src_blocks.items():
+        np.testing.assert_array_equal(
+            np.asarray(dst.arena_block(key, dst.slot_bids[0][j])), blk)
+
+    # the TPU's default for a head dim off the 128 lanes: blocks minor-most
+    def foreign():
+        return {key: jax.device_put(np.asarray(a), Format(
+            Layout((0,) + tuple(range(2, a.ndim)) + (1,)), a.sharding))
+            for key, a in dst.arena.items()}
+    one = jnp.int32(1)
+    padded = {key: jnp.zeros(a.shape[:1] + (1, dst.max_len) + a.shape[-2:],
+                             a.dtype) for key, a in dst.arena.items()}
+    dense = lambda: {key: jnp.array(a) for key, a in dst.cache.items()}
+    programs = {
+        "scatter": lambda ar: dst._scatter(
+            ar, padded, jnp.zeros(dst.nb_max, jnp.int32)),
+        "copy": lambda ar: dst._copy(ar, one, jnp.int32(2)),
+        "write_block": lambda ar: dst._write_block(
+            ar, one, {key: a[:, 2] for key, a in ar.items()}),
+        "gather_prefix": lambda ar: dst._gather_prefix(
+            ar, jnp.asarray([1], jnp.int32)),
+        "decode": lambda ar: dst._decode(
+            dst.params, ar, dense(), jnp.asarray(dst.tables),
+            jnp.zeros((2, 1), jnp.int32), jnp.zeros(2, bool),
+            jnp.zeros(2, jnp.int32)),
+    }
+    for name, call in programs.items():
+        if backend == "pallas":
+            with pytest.raises(ValueError, match="[Ll]ayout"):
+                call(foreign())
+        else:
+            call(foreign())
+
+
+def test_pinned_arena_survives_the_persistent_compile_cache(tmp_path,
+                                                            monkeypatch):
+    """A warm run loads the adapter's programs from JAX's persistent
+    compile cache, and an executable loaded from there labels its outputs
+    with the default layout whatever layout it wrote (jax 0.9.0): the
+    arena reached the next program in the default layout and was refused.
+    The programs that return the arena stay out of that cache, and the
+    warm run serves as the cold one did.  On the CPU the row-major pin is
+    the default layout, so a block-minor pin stands in."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.experimental.layout import Format, Layout
+    from repro.serve.kvcache import paged
+    minor = lambda nd: (0,) + tuple(range(2, nd)) + (1,)
+    monkeypatch.setattr(
+        paged, "arena_formats", lambda arena, backend, where:
+        None if backend != "pallas" else
+        {key: Format(Layout(minor(a.ndim)), where[key])
+         for key, a in arena.items()})
+    cfg, params, _ = _setup("stablelm_3b")
+    prompt = np.random.default_rng(21).integers(
+        0, cfg.vocab, size=5).astype(np.int32)
+    was = (jax.config.jax_compilation_cache_dir,
+           jax.config.jax_persistent_cache_min_compile_time_secs)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    cc.reset_cache()
+    try:
+        for run in ("cold", "warm"):
+            jax.clear_caches()
+            ad = make_adapter(cfg, params, n_slots=1, max_len=16,
+                              paged=True, block_size=BS, backend="pallas")
+            ad.insert(0, prompt, max_new=4)
+            ad.decode(np.asarray([3], np.int32), np.ones(1, bool))
+            for a in ad.arena.values():
+                assert a.format.layout.major_to_minor == minor(a.ndim), run
+        assert any(tmp_path.iterdir())
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          was[1])
+        cc.reset_cache()
+

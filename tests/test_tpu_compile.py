@@ -8,19 +8,27 @@ imported: only one process at a time may load the TPU library, and every
 test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
 from repro.kernels import ops
 from repro.kernels import paged_attn as pk
+from repro.models import lm
+from repro.serve import engine
+from repro.serve.kvcache import paged
 
 # (Hq, Hkv, D): stablelm-3b (MHA) and hymba-1.5b (GQA, Hkv=5)
 WIDTHS = {"stablelm-3b": (32, 32, 80), "hymba-1.5b": (25, 5, 64)}
 LANES, NB, BS, BLOCKS = 8, 32, 16, 257     # 8 lanes x 512 tokens, block 16
 LENET_PATCHES, LENET_K, LENET_O = 32 * 784, 25, 32   # LeNet-5 conv1
+# the served tick of bench/configs/stablelm-3b.json: 16 lanes x 64 table
+# entries of 16-token blocks, 448 blocks in the arena
+TICK_LANES, TICK_NB, TICK_BLOCKS, TICK_MAXLEN = 16, 64, 448, 1024
 
 
 @pytest.fixture(scope="module")
@@ -121,3 +129,62 @@ def test_sng_pack_compiles(one_chip):
         lambda lv, codes: ops.sng_pack(lv, codes, N, interpret=False),
         ((LENET_PATCHES * LENET_K,), jnp.int32), ((N,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def _arena_sized_ops(text, arena_shape):
+    """The copies and slicing fusions of a compiled HLO ``text`` whose
+    result has the shape of the whole arena or of one layer of it (unit
+    dims ignored): the relayouts and per-layer slices the served tick must
+    not hold."""
+    drop = lambda dims: tuple(d for d in dims if d != 1)
+    whole = drop(arena_shape)
+    banned = {whole, whole[1:]}
+    found = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z-]+)\(", text,
+                         re.M):
+        name, result, op = m.groups()
+        if op not in ("copy", "copy-start", "fusion", "dynamic-slice") or \
+                (op == "fusion" and "slice" not in name
+                 and "copy" not in name):
+            continue
+        for dims in re.findall(r"\[([\d,]+)\]", result):
+            if drop(tuple(int(d) for d in dims.split(","))) in banned:
+                found.append(f"%{name} = {result}")
+    return found
+
+
+@pytest.mark.parametrize("arch", ["stablelm_3b", "hymba_1_5b"])
+def test_served_tick_reads_the_arena_where_it_lies(one_chip, arch):
+    """The full-width Pallas tick, built as the adapter builds it
+    (``paged.arena_formats`` + ``paged.decode_tick``) from abstract
+    params: with the arena held row-major and handed whole to the
+    kernels, no copy or slice of the whole arena or of one layer of it
+    remains (head dims 80 and 64 both sit off the 128 lanes), and the
+    compiler's temporaries stay far below an arena (3.82e9 B for
+    stablelm-3b with the default layout and per-layer slices)."""
+    cfg = configs.config(arch)
+    sds = lambda a, where=one_chip: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=where)
+    params = jax.tree.map(sds, lm.init(jax.random.key(0), cfg, {},
+                                       abstract=True)[0])
+    shape = engine.init_paged_arena(cfg, TICK_BLOCKS, BS, abstract=True)
+    fmt = paged.arena_formats(shape, "pallas",
+                              {key: one_chip for key in shape})
+    assert paged.arena_formats(shape, "xla", {}) is None
+    arena = {key: sds(a, fmt[key]) for key, a in shape.items()}
+    dense = {key: jax.ShapeDtypeStruct((TICK_LANES,) + a.shape, a.dtype,
+                                       sharding=one_chip)
+             for key, a in engine.init_cache(cfg, 1, TICK_MAXLEN,
+                                             abstract=True).items()
+             if key not in shape}
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    tick = paged.decode_tick(cfg, TICK_MAXLEN, "pallas", fmt,
+                             interpret=False).lower(
+        params, arena, dense, i32(TICK_LANES, TICK_NB), i32(TICK_LANES, 1),
+        jax.ShapeDtypeStruct((TICK_LANES,), bool, sharding=one_chip),
+        i32(TICK_LANES)).compile()
+    text = tick.as_text()
+    assert "tpu_custom_call" in text
+    assert _arena_sized_ops(text, shape["k"].shape) == []
+    assert tick.memory_analysis().temp_size_in_bytes < 0.5e9
+
