@@ -28,6 +28,13 @@ shape/dtype/table/window permutations (tests/test_paged_attn.py), and
 wired into the serving tick by ``engine.decode_step_paged`` (the
 ``backend="pallas"`` path of the paged slot adapter).
 
+Latent attention (MLA, Moonlight-16B-A3B) caches one row of latent + RoPE
+key per token and layer, no K or V: ``latent_decode_attention`` reads it
+as one key head whose first ``dv`` lanes are also the values (each live
+row fetched once, several table entries per grid step, entries past a
+lane's live blocks neither fetched nor computed), and
+``scatter_latent_rows`` lands the tick's one row per layer and lane.
+
 The cascade extension (``backend="cascade"``) splits decode attention over
 a shared radix prefix and per-lane divergent suffixes and merges the
 partial online-softmax states by log-sum-exp: ``cascade_prefix_attention``
@@ -247,6 +254,160 @@ def paged_decode_attention(q, k_arena, v_arena, tables, lens, *,
         interpret=interpret,
         name="paged_decode_attention",
     )(*operands)
+
+
+# table entries one grid step of the latent sweep reads
+LATENT_TILES = 8
+
+
+def _latent_kernel(tables_ref, lens_ref, layer_ref, q_ref, *rest, bs: int,
+                   tiles: int, steps: int, scale: float, dv: int):
+    lat_refs, row_ref = rest[:tiles], rest[tiles]
+    o_ref, m_scr, l_scr, acc_scr = rest[tiles + 1:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    n = lens_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    q = q_ref[0].astype(jnp.float32)              # (Hq, W)
+    new = row_ref[0].astype(jnp.float32)          # (1, W)
+    for t, lat_ref in enumerate(lat_refs):
+        base = (j * tiles + t) * bs
+
+        @pl.when(base < n)
+        def _tile():
+            # the new token's row is overlaid at its position: it reaches
+            # the arena after the layer scan
+            row = base + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+            lat = jnp.where(row == n - 1, new,
+                            lat_ref[0, 0, 0].astype(jnp.float32))  # (bs, W)
+            s = jax.lax.dot_general(q, lat, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+            s = jnp.where(pos < n, s * scale, NEG_INF)          # (Hq, bs)
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[:, None])
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
+            m_scr[...] = m_new
+            o = jax.lax.dot_general(p, lat[:, :dv], (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            acc_scr[...] = acc_scr[...] * corr[:, None] + o
+
+    @pl.when(j == steps - 1)
+    def _finish():
+        o_ref[0] = (acc_scr[...] /
+                    jnp.maximum(l_scr[...], 1e-30)[:, None]
+                    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "dv", "interpret"))
+def latent_decode_attention(q, lat_arena, tables, lens, *, new_row, scale,
+                            dv, layer, interpret: bool | None = None):
+    """Latent (MLA) decode attention read in place from the paged arena:
+    one key head of width W whose first ``dv`` lanes are also the values,
+    so each live row is read once.
+
+    q: (B, Hq, W) absorbed queries; lat_arena: the whole
+    ``(L, num_blocks, 1, bs, W)`` latent arena, read at ``layer`` (may be
+    traced) as it lies in HBM; tables (B, nb);
+    lens (B,) valid rows including the new one, whose row ``new_row``
+    (B, W) is overlaid at ``lens - 1``; ``scale`` the softmax scale.
+    Returns (B, Hq, dv) in the arena's dtype.
+
+    Grid (B, ceil(nb / LATENT_TILES)): each step reads ``LATENT_TILES``
+    table entries of a lane.  An entry past the lane's last live block is
+    mapped to that block again, which the pipeline does not fetch twice,
+    and its compute is skipped: a lane costs its live blocks, not
+    ``max_len``.
+    """
+    from repro.kernels.ops import resolve_interpret
+    interpret = resolve_interpret(interpret)
+    B, Hq, W = q.shape
+    bs = lat_arena.shape[3]
+    g = LATENT_TILES
+    steps = -(-tables.shape[1] // g)
+    tables = jnp.pad(jnp.asarray(tables, jnp.int32),
+                     ((0, 0), (0, steps * g - tables.shape[1])))
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def tile(t):
+        def index(b, j, tb, ln, y):
+            last = (ln[b] - 1) // bs
+            return (y[0], tb[b, jnp.minimum(j * g + t, last)], 0, 0, 0)
+        return pl.BlockSpec((1, 1, 1, bs, W), index)
+    per_lane = lambda *shape: pl.BlockSpec(
+        (1,) + shape, lambda b, j, tb, ln, y: (b, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, bs=bs, tiles=g, steps=steps,
+                          scale=scale, dv=dv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, steps),
+            in_specs=[per_lane(Hq, W)] + [tile(t) for t in range(g)]
+            + [per_lane(1, W)],
+            out_specs=per_lane(Hq, dv),
+            scratch_shapes=[
+                pltpu.VMEM((Hq,), jnp.float32),      # running max
+                pltpu.VMEM((Hq,), jnp.float32),      # running sum
+                pltpu.VMEM((Hq, dv), jnp.float32),   # output accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, dv), lat_arena.dtype),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(tables, jnp.asarray(lens, jnp.int32), lyr, q,
+      *([lat_arena] * g), new_row[:, None])
+
+
+def _scatter_latent_kernel(wbids_ref, offs_ref, r_ref, a_ref, o_ref, *,
+                           bs: int):
+    # grid (L, S): layer l writes lane b's latent row at row offs[b] of
+    # arena block wbids[b]; the aliased output keeps every other block's
+    # bytes, and the visited block's other rows come from its input
+    b = pl.program_id(1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+    o_ref[0, 0, 0] = jnp.where(row == offs_ref[b], r_ref[0, 0],
+                               a_ref[0, 0, 0])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def scatter_latent_rows(arena, rows, wbids, offs, *,
+                        interpret: bool | None = None):
+    """Land the decode tick's latent rows in the arena **in place**: one
+    row per layer and lane, where :func:`scatter_kv_rows` lands a K/V pair.
+
+    arena: (L, num_blocks, 1, bs, W); rows: (L, S, W); wbids, offs: (S,)
+    int32 block and row within it per lane (masked lanes go to the trash
+    block).  Equal to ``arena.at[:, wbids, 0, offs].set(rows)`` wherever
+    the (block, row) targets are unique."""
+    from repro.kernels.ops import resolve_interpret
+    interpret = resolve_interpret(interpret)
+    L, _, _, bs, W = arena.shape
+    S = wbids.shape[0]
+    blk = pl.BlockSpec((1, 1, 1, bs, W),
+                       lambda l, b, w, o: (l, w[b], 0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_scatter_latent_kernel, bs=bs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(L, S),
+            in_specs=[pl.BlockSpec((1, 1, 1, W),
+                                   lambda l, b, w, o: (l, b, 0, 0)), blk],
+            out_specs=blk,
+        ),
+        out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+        input_output_aliases={3: 0},
+        interpret=interpret,
+        name="scatter_latent_rows",
+    )(jnp.asarray(wbids, jnp.int32), jnp.asarray(offs, jnp.int32),
+      rows[:, :, None], arena)
 
 
 def _paged_state_kernel(tables_ref, lens_ref, win_ref, q0_ref, q_ref, k_ref,

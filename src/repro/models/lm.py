@@ -3,7 +3,9 @@ ten assigned architectures.
 
 Families
   decoder : llama3-405b, starcoder2-15b, deepseek-67b, stablelm-3b
-  moe     : deepseek-moe-16b, moonshot-v1-16b-a3b (dense layer 0 + MoE rest)
+  moe     : deepseek-moe-16b (dense layer 0 + MoE rest; MHA, softmax router),
+            moonshot-v1-16b-a3b = Moonlight-16B-A3B (dense layer 0 + MoE rest;
+            latent attention (MLA), biased sigmoid router)
   rwkv    : rwkv6-7b (attention-free; time-mix + channel-mix)
   hybrid  : hymba-1.5b (parallel GQA + Mamba heads, sliding window + globals)
   encdec  : whisper-medium (frame-embedding encoder + causal/cross decoder)
@@ -20,8 +22,10 @@ Conventions
     replication when a dim is not divisible by the mesh axis.
   - Modality frontends (whisper audio conv, VLM image tower) are STUBS per
     the assignment: batches carry precomputed frame/patch embeddings.
-  - Serve caches: attention K/V are (L, B, Smax, Hkv, Dh); RWKV/Mamba carry
-    O(1) recurrent state — which is why only those families run long_500k.
+  - Serve caches: attention K/V are (L, B, Smax, Hkv, Dh); latent attention
+    caches one (L, B, Smax, kv_lora_rank + qk_rope_head_dim) row per token;
+    RWKV/Mamba carry O(1) recurrent state — which is why only those families
+    run long_500k.
 """
 from __future__ import annotations
 
@@ -36,7 +40,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import hint
 from repro.nn import attention, mlp as mlp_lib, norms, rope, ssm
-from repro.nn.moe import MoEConfig, moe_ffn
+from repro.nn.moe import MoEConfig, expert_rows, moe_ffn
 
 DATA, MODEL = "data", "model"   # logical mesh axis names (pod handled by batch)
 
@@ -72,6 +76,16 @@ class LMConfig:
     # prefix-cache resumption; off by default so the training forward and
     # the dry-run roofline cells keep GShard capacity semantics
     moe_dropless_prefill: bool = False
+    router_score: str = "softmax"     # "softmax" | "sigmoid" (see nn/moe.py)
+    routed_scaling: float = 1.0       # sigmoid router's output scale
+    # --- latent attention (MLA, DeepSeek-V2/V3 form without a query LoRA);
+    # on when kv_lora_rank > 0.  Queries are n_heads x (nope + rope) wide,
+    # the cache holds the RMS-normed kv_lora_rank latent and one RoPE'd
+    # qk_rope_head_dim key part shared by every head.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- vlm ---
     cross_every: int = 0              # a cross-attn layer every k layers
     n_vision_tokens: int = 1024
@@ -109,7 +123,18 @@ class LMConfig:
             return None
         return MoEConfig(self.n_experts, self.top_k, self.d_expert,
                          self.n_shared, self.capacity_factor,
-                         self.moe_group_size, impl=self.moe_impl)
+                         self.moe_group_size, impl=self.moe_impl,
+                         score=self.router_score,
+                         routed_scaling=self.routed_scaling)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Width of one cached latent row (MLA)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def vocab_padded(self) -> int:
@@ -170,10 +195,11 @@ class _Builder:
         return (scale * jax.random.truncated_normal(
             self._split(), -2, 2, shape, jnp.float32)).astype(self.cfg.dtype)
 
-    def fill(self, shape, value):
+    def fill(self, shape, value, dtype=None):
+        dtype = dtype or self.cfg.dtype
         if self.abstract:
-            return jax.ShapeDtypeStruct(shape, self.cfg.dtype)
-        return jnp.full(shape, value, self.cfg.dtype)
+            return jax.ShapeDtypeStruct(shape, dtype)
+        return jnp.full(shape, value, dtype)
 
     def fn(self, shape, f):
         if self.abstract:
@@ -214,6 +240,27 @@ def _attn_params(b: _Builder, L, d, hq, hkv, dh, bias):
     return p, s
 
 
+def _mla_params(b: _Builder, L, d):
+    """Latent attention: ``wq`` (d -> H x (nope + rope)), ``w_kv_a``
+    (d -> latent + rope key), the latent's RMSNorm, ``w_kv_b`` (latent ->
+    H x (nope key + value)) and ``wo`` (H x value -> d)."""
+    cfg = b.cfg
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dq = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    lead, llog = (L,), (None,)
+    p, s = {}, {}
+    for nm, shape, logical in (
+            ("wq", (d, H * dq), ("fsdp", "tp")),
+            ("w_kv_a", (d, cfg.latent_width), ("fsdp", None)),
+            ("w_kv_b", (r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+             (None, "tp")),
+            ("wo", (H * cfg.v_head_dim, d), ("tp", "fsdp"))):
+        p[nm] = b.dense(lead + shape)
+        s[nm] = b.spec(lead + shape, llog + logical)
+    p["kv_norm"], s["kv_norm"] = _norm_params(b, L, r)
+    return p, s
+
+
 def _mlp_params(b: _Builder, L, d, f, kind, bias):
     lead = () if L is None else (L,)
     llog = () if L is None else (None,)
@@ -240,6 +287,9 @@ def _moe_params(b: _Builder, L, d):
     p, s = {}, {}
     p["w_router"] = b.dense((L, d, m.n_experts))
     s["w_router"] = P(None, None, None)
+    if m.score == "sigmoid":
+        p["router_bias"] = b.fill((L, m.n_experts), 0.0, jnp.float32)
+        s["router_bias"] = P(None, None)
     for nm, shape, logical in (
             ("w_gate", (L, m.n_experts, d, f), (None, "tp", "fsdp", None)),
             ("w_in", (L, m.n_experts, d, f), (None, "tp", "fsdp", None)),
@@ -271,9 +321,12 @@ def _decoder_block_params(b: _Builder, L, *, moe_layer):
     cfg = b.cfg
     p, s = {}, {}
     p["ln1"], s["ln1"] = _norm_params(b, L, cfg.d_model, cfg.use_bias)
-    p["attn"], s["attn"] = _attn_params(b, L, cfg.d_model, cfg.n_heads,
-                                        cfg.n_kv_heads, cfg.d_head,
-                                        cfg.use_bias)
+    if cfg.mla:
+        p["attn"], s["attn"] = _mla_params(b, L, cfg.d_model)
+    else:
+        p["attn"], s["attn"] = _attn_params(b, L, cfg.d_model, cfg.n_heads,
+                                            cfg.n_kv_heads, cfg.d_head,
+                                            cfg.use_bias)
     p["ln2"], s["ln2"] = _norm_params(b, L, cfg.d_model, cfg.use_bias)
     if moe_layer:
         p["moe"], s["moe"] = _moe_params(b, L, cfg.d_model)
@@ -524,6 +577,104 @@ def _attn_apply(cfg: LMConfig, p, x, positions, *, causal=True, window=0,
     return out, (k, v)
 
 
+def mla_project(cfg: LMConfig, p, x, positions):
+    """Latent attention's per-token projections of ``x`` (B, S, d) at
+    ``positions`` (B, S).  Returns ``(q, rows)``: q (B, S, H, nope + rope)
+    with RoPE on its last ``qk_rope_head_dim`` lanes, and the cache rows
+    (B, S, kv_lora_rank + qk_rope_head_dim) — the RMS-normed latent beside
+    the RoPE'd key part that every head shares."""
+    B, S, _ = x.shape
+    dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = _proj(x, p["wq"]).reshape(B, S, cfg.n_heads,
+                                  dn + cfg.qk_rope_head_dim)
+    q = jnp.concatenate(
+        [q[..., :dn], rope.apply_rope(q[..., dn:], positions, cfg.rope_theta)],
+        axis=-1)
+    kv = _proj(x, p["w_kv_a"])
+    c = norms.rmsnorm(kv[..., :r], p["kv_norm"]["scale"], cfg.norm_eps)
+    k_pe = rope.apply_rope(kv[..., None, r:], positions, cfg.rope_theta)
+    return q, jnp.concatenate([c, k_pe[..., 0, :]], axis=-1)
+
+
+def mla_scale(cfg: LMConfig) -> float:
+    """Softmax scale of latent attention: the query/key head width."""
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def _mla_apply(cfg: LMConfig, p, x, positions, *, causal=True, window=0,
+               q_offset=0, kv_prefix=None):
+    """Latent attention over a whole sequence (train / prefill) in its
+    expanded form: the latent rows of prefix + chunk are projected up by
+    ``w_kv_b`` to per-head keys (beside the shared RoPE part) and values.
+    ``kv_prefix``: the latent rows (B, q_offset, W) of an already-computed
+    prefix.  Returns (out, rows) with rows covering prefix + chunk — the
+    cache this layer owes."""
+    assert isinstance(window, int) and window == 0, \
+        "latent attention has no sliding window"
+    B, S, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    assert dv <= dn + dr, (dv, dn, dr)
+    q, rows = mla_project(cfg, p, x, positions)
+    if kv_prefix is not None:
+        assert kv_prefix.shape[1] == q_offset, (kv_prefix.shape, q_offset)
+        rows = jnp.concatenate([kv_prefix.astype(rows.dtype), rows], axis=1)
+    T = rows.shape[1]
+    kv = _proj(rows[..., :r], p["w_kv_b"]).reshape(B, T, H, dn + dv)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(rows[:, :, None, r:], (B, T, H, dr))],
+        axis=-1)
+    # attend_chunked takes one head width for q, k and v: the values ride
+    # zero-padded to the query width, and the padding is cut off after
+    v = jnp.pad(kv[..., dn:], ((0, 0), (0, 0), (0, 0), (0, dn + dr - dv)))
+    o = attention.attend_chunked(q, k, v, causal=causal, q_offset=q_offset,
+                                 q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
+    out = _proj(o[..., :dv].reshape(B, S, H * dv), p["wo"])
+    return out, rows
+
+
+def mla_absorb(cfg: LMConfig, p, q):
+    """Fold ``w_kv_b``'s key half into decode queries: q (B, H, nope + rope)
+    -> (B, H, kv_lora_rank + rope), whose dot with a cached latent row is
+    the expanded form's score (before the softmax scale)."""
+    H, r, dn = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    wkb = p["w_kv_b"].reshape(r, H, dn + cfg.v_head_dim)[..., :dn]
+    q_lat = jnp.einsum("bhn,rhn->bhr", q[..., :dn], wkb,
+                       preferred_element_type=jnp.float32)
+    return jnp.concatenate([q_lat.astype(q.dtype), q[..., dn:]], axis=-1)
+
+
+def mla_unabsorb(cfg: LMConfig, p, o_lat):
+    """Decode attention output over the latent, (B, H, kv_lora_rank), through
+    ``w_kv_b``'s value half and ``wo``: the layer's output (B, 1, d)."""
+    B, H, r = o_lat.shape
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    wvb = p["w_kv_b"].reshape(r, H, dn + dv)[..., dn:]
+    o = jnp.einsum("bhr,rhv->bhv", o_lat, wvb)
+    return _proj(o.reshape(B, 1, H * dv), p["wo"])
+
+
+def attn_decode_paged_mla(cfg: LMConfig, p, x1, lat_blocks, tables, pos, *,
+                          backend=None, layer=None, interpret=None):
+    """One-token latent attention for a batch of slots in its absorbed
+    form, reading the latent rows in place from the paged arena.
+
+    x1: (S, 1, d) normed activations; lat_blocks: one layer's slice
+    (num_blocks, 1, bs, W) of the latent arena, or with ``layer``
+    (``"pallas"`` only) the whole (L, num_blocks, 1, bs, W) arena read at
+    that layer; tables (S, nb); pos (S,) the new token's row index.  The
+    new row is overlaid at ``pos`` by the read (it reaches the arena after
+    the layer scan).  Returns (out (S, 1, d), row (S, W))."""
+    q, row = mla_project(cfg, p, x1, pos[:, None])
+    if layer is None:
+        lat_blocks = lat_blocks[:, 0]           # (num_blocks, bs, W)
+    o = attention.attend_decode_latent(
+        mla_absorb(cfg, p, q[:, 0]), lat_blocks, tables, pos + 1,
+        new_row=row[:, 0], scale=mla_scale(cfg), dv=cfg.kv_lora_rank,
+        backend=backend, layer=layer, interpret=interpret)
+    return mla_unabsorb(cfg, p, o), row[:, 0]
+
+
 def attn_decode_paged(cfg: LMConfig, p, x1, k_blocks, v_blocks, tables, pos,
                       *, window=0, kernel=None, interpret=None,
                       scales=None, backend=None, cascade=None, layer=None):
@@ -615,7 +766,8 @@ def _mlp_apply(cfg: LMConfig, p, x, kind=None):
 
 def decoder_block(cfg: LMConfig, p, x, positions, *, window=0, moe_layer=False,
                   q_offset=0, causal=True, kv_prefix=None, moe_dropless=False):
-    """Pre-norm transformer block.  Returns (x, kv, aux).
+    """Pre-norm transformer block.  Returns (x, kv, aux); ``kv`` is the
+    (k, v) pair, or the latent rows under latent attention (``cfg.mla``).
 
     ``kv_prefix`` + ``q_offset`` resume from an existing KV prefix
     (suffix-only chunked prefill); ``kv`` then spans prefix + suffix.
@@ -625,9 +777,10 @@ def decoder_block(cfg: LMConfig, p, x, positions, *, window=0, moe_layer=False,
     resumed from a cached prefix — see ``cfg.moe_dropless_prefill``).
     """
     x = hint(x, "batch", None, None)
-    h, kv = _attn_apply(cfg, p["attn"], _norm_apply(cfg, p["ln1"], x),
-                        positions, causal=causal, window=window,
-                        q_offset=q_offset, kv_prefix=kv_prefix)
+    attend = _mla_apply if cfg.mla else _attn_apply
+    h, kv = attend(cfg, p["attn"], _norm_apply(cfg, p["ln1"], x),
+                   positions, causal=causal, window=window,
+                   q_offset=q_offset, kv_prefix=kv_prefix)
     x = x + h
     z = _norm_apply(cfg, p["ln2"], x)
     if moe_layer:
@@ -975,6 +1128,13 @@ def moe_ffn_decode(cfg: LMConfig, moe_params, z):
     m = dataclasses.replace(cfg.moe, group_size=B)
     return moe_ffn(z.reshape(1, B, -1), moe_params, m)[0].reshape(B, 1, -1), \
         jnp.float32(0.0)
+
+
+def moe_ffn_decode_rows(cfg: LMConfig, tokens: int) -> int:
+    """Rows the expert FFN of :func:`moe_ffn_decode` computes for a
+    ``(tokens, 1, d)`` activation: one dispatch group of ``tokens``."""
+    return expert_rows(dataclasses.replace(cfg.moe, group_size=tokens),
+                       tokens)
 
 
 def chunked_xent(cfg: LMConfig, x, head, labels):

@@ -462,6 +462,54 @@ def attend_decode_paged(q, k_arena, v_arena, block_table, cache_len, *,
     return out.reshape(B, 1, Hq, D).astype(v.dtype)
 
 
+def attend_latent(q, lat, cache_len, *, scale: float, dv: int) -> jax.Array:
+    """One-token latent attention (MLA's absorbed decode form) against
+    dense latent rows: a single key head whose first ``dv`` lanes are also
+    the values.
+
+    q: (B, Hq, W) absorbed queries; lat: (B, Smax, W) latent rows;
+    cache_len: (B,) int32 valid rows per lane.  Returns (B, Hq, dv) in
+    lat.dtype.
+    """
+    s = jnp.einsum("bhw,bsw->bhs", q, lat,
+                   preferred_element_type=jnp.float32) * scale
+    pos = jnp.arange(lat.shape[1])
+    s = jnp.where(pos[None, None, :] < cache_len[:, None, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhs,bsv->bhv", p.astype(lat.dtype), lat[..., :dv],
+                     preferred_element_type=jnp.float32)
+    return out.astype(lat.dtype)
+
+
+def attend_decode_latent(q, lat_arena, block_table, cache_len, *, new_row,
+                         scale: float, dv: int, backend=None, layer=None,
+                         interpret=None) -> jax.Array:
+    """One-token latent attention against a *paged* latent cache.
+
+    q: (B, Hq, W) absorbed queries; lat_arena: (num_blocks, bs, W) under
+    ``"xla"``, the whole (L, num_blocks, 1, bs, W) arena read at ``layer``
+    under ``"pallas"``; block_table (B, nb); cache_len (B,) valid rows
+    including the new one, whose row ``new_row`` (B, W) is overlaid at
+    ``cache_len - 1``.
+    ``"xla"`` gathers each chain into the dense layout and runs
+    :func:`attend_latent` — the reference the ``"pallas"`` kernel
+    (``kernels.paged_attn.latent_decode_attention``) is pinned against.
+    Returns (B, Hq, dv).
+    """
+    if backend == "pallas":
+        from repro.kernels.paged_attn import latent_decode_attention
+        return latent_decode_attention(q, lat_arena, block_table, cache_len,
+                                       new_row=new_row, scale=scale, dv=dv,
+                                       layer=layer, interpret=interpret)
+    assert backend in (None, "xla"), \
+        f"latent attention: no backend {backend!r}"
+    assert layer is None, "only the pallas backend reads a whole arena"
+    lat = gather_paged_kv(lat_arena, block_table)    # (B, S, W)
+    lat = lat.at[jnp.arange(q.shape[0]), cache_len - 1].set(
+        new_row.astype(lat.dtype), mode="drop")
+    return attend_latent(q, lat, cache_len, scale=scale, dv=dv)
+
+
 def merge_softmax_states(acc1, m1, l1, acc2, m2, l2):
     """Log-sum-exp merge of two partial online-softmax states.
 

@@ -10,6 +10,13 @@ expected all-to-all pattern under pjit.  An alternative sort-based dispatch
 
 Capacity overflow drops tokens (standard GShard semantics); an auxiliary
 load-balance loss is returned for training.
+
+Two routers: ``score="softmax"`` (DeepSeekMoE: softmax over the experts,
+top-k of the probabilities, renormalised) and ``score="sigmoid"``
+(DeepSeek-V3's ``noaux_tc``, Moonlight: sigmoid scores in float32, the top-k
+of the scores *plus* a per-expert correction bias selects, the unbiased
+scores of the selected experts weight, renormalised and multiplied by
+``routed_scaling``).
 """
 from __future__ import annotations
 
@@ -36,6 +43,8 @@ class MoEConfig:
     # other prompts share its dispatch group — the prerequisite for resuming
     # a prompt from a cached prefix); training keeps GShard drop semantics.
     dropless: bool = False
+    score: str = "softmax"           # "softmax" | "sigmoid" (biased top-k)
+    routed_scaling: float = 1.0      # sigmoid router's output scale
 
 
 def capacity(cfg: MoEConfig, group_tokens: int) -> int:
@@ -45,19 +54,41 @@ def capacity(cfg: MoEConfig, group_tokens: int) -> int:
     return max(cfg.top_k, -(-c // 4) * 4)   # round up to 4 for layout
 
 
-def router(x, w_router, cfg: MoEConfig):
-    """x: (G, T, d) -> (weights (G,T,k), experts (G,T,k) int32, aux loss)."""
+def expert_rows(cfg: MoEConfig, group_tokens: int) -> int:
+    """Rows the expert FFN computes for one dispatch group of
+    ``group_tokens`` tokens: every expert's capacity buffer, filled or not
+    (the ``(E, C)`` buffers both dispatches build)."""
+    return cfg.n_experts * capacity(cfg, group_tokens)
+
+
+def router(x, w_router, cfg: MoEConfig, bias=None):
+    """x: (G, T, d) -> (weights (G,T,k), experts (G,T,k) int32, aux loss).
+
+    ``bias`` (E,): the sigmoid router's correction bias, added to the
+    scores for the selection only."""
     logits = jnp.einsum("gtd,de->gte", x.astype(jnp.float32),
                         w_router.astype(jnp.float32))
+    if cfg.score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_e = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                 cfg.top_k)
+        top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+        top_w = top_w / (jnp.sum(top_w, -1, keepdims=True) + 1e-20)
+        top_w = top_w * cfg.routed_scaling
+        probs = scores / jnp.sum(scores, -1, keepdims=True)
+        return top_w, top_e, _balance_loss(probs, top_e, cfg)
     probs = jax.nn.softmax(logits, axis=-1)
     top_w, top_e = jax.lax.top_k(probs, cfg.top_k)
     top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    return top_w, top_e, _balance_loss(probs, top_e, cfg)
+
+
+def _balance_loss(probs, top_e, cfg: MoEConfig):
     # load-balance aux loss (Switch-style): E * mean(density * mean_prob)
     density = jnp.mean(
         jax.nn.one_hot(top_e, cfg.n_experts, dtype=jnp.float32), axis=(1, 2))
     mean_prob = jnp.mean(probs, axis=1)
-    aux = cfg.n_experts * jnp.mean(jnp.sum(density * mean_prob, axis=-1))
-    return top_w, top_e, aux
+    return cfg.n_experts * jnp.mean(jnp.sum(density * mean_prob, axis=-1))
 
 
 def _dispatch_einsum(x, top_w, top_e, cfg: MoEConfig, params):
@@ -133,7 +164,8 @@ def moe_ffn(x, params, cfg: MoEConfig):
     """x: (B, S, d).  Returns (out (B,S,d), aux_loss scalar).
 
     params: {w_router (d,E), w_gate/w_in (E,d,f), w_out (E,f,d),
-             shared_gate/shared_in (d, n_shared*f), shared_out (n_shared*f, d)}
+             shared_gate/shared_in (d, n_shared*f), shared_out (n_shared*f, d),
+             router_bias (E,) f32 for the sigmoid router}
     """
     B, S, d = x.shape
     tokens = B * S
@@ -141,7 +173,8 @@ def moe_ffn(x, params, cfg: MoEConfig):
     G = tokens // gs
     assert G * gs == tokens, f"group_size {gs} must divide tokens {tokens}"
     xg = hint(x.reshape(G, gs, d), "batch", None, None)
-    top_w, top_e, aux = router(xg, params["w_router"], cfg)
+    top_w, top_e, aux = router(xg, params["w_router"], cfg,
+                               params.get("router_bias"))
     impl = {"einsum": _dispatch_einsum, "sort": _dispatch_sort}[cfg.impl]
     routed = impl(xg, top_w, top_e, cfg, params).reshape(B, S, d)
     if cfg.n_shared > 0:

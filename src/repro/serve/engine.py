@@ -1,7 +1,8 @@
 """Serving: batched prefill + single-token decode with family-specific caches.
 
 Cache layouts (leading axis = layers, consumed/produced by lax.scan):
-  attention : k/v (L, B, Smax, Hkv, Dh) + scalar "len"
+  attention : k/v (L, B, Smax, Hkv, Dh) + scalar "len"; latent attention
+              (MLA) instead one "latent" (L, B, Smax, kv_lora_rank + rope)
   rwkv      : wkv state (L, B, H, Dh, Dh) f32 + token-shift states (L, B, d)
   hybrid    : k/v + mamba conv state (L, B, K-1, di) + ssm state (L, B, di, N)
   encdec/vlm: self k/v + precomputed cross K/V from encoder/vision tokens
@@ -44,7 +45,11 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, abstract: bool = False,
     fam = cfg.family
     kv_dtype = jnp.int8 if cfg.kv_quant else cfg.dtype
     cache: dict = {"len": mk((), jnp.int32)}
-    if fam in ("decoder", "moe", "hybrid"):
+    if cfg.mla:
+        assert fam in ("decoder", "moe") and not cfg.kv_quant, \
+            "latent attention: decoder/moe families, no int8 cache"
+        cache["latent"] = mk((L, B, max_len, cfg.latent_width), cfg.dtype)
+    elif fam in ("decoder", "moe", "hybrid"):
         cache["k"] = mk((L, B, max_len, Hkv, Dh), kv_dtype)
         cache["v"] = mk((L, B, max_len, Hkv, Dh), kv_dtype)
         if cfg.kv_quant:
@@ -76,10 +81,19 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, abstract: bool = False,
     return cache
 
 
-# Cache keys whose axis -3 is the (paged) sequence axis.  Everything else —
-# rwkv/ssm states, conv taps, encoder/vision cross K/V — is O(1) per slot and
-# stays densely slot-stacked even under the paged layout.
-PAGED_SEQ_KEYS = ("k", "v", "k_scale", "v_scale", "kx_self", "vx_self")
+# Cache keys with a (paged) sequence axis.  Everything else — rwkv/ssm
+# states, conv taps, encoder/vision cross K/V — is O(1) per slot and stays
+# densely slot-stacked even under the paged layout.
+PAGED_SEQ_KEYS = ("k", "v", "k_scale", "v_scale", "kx_self", "vx_self",
+                  "latent")
+
+
+def seq_axis(key: str) -> int:
+    """Sequence axis of a cache key, from the end: ``(..., B, S, heads,
+    feat)`` for K/V-like keys, ``(..., B, S, width)`` for the MLA latent,
+    which has no head axis (a size-1 one would be padded to a whole tile
+    in the TPU's HBM layout)."""
+    return -2 if key == "latent" else -3
 
 
 def init_paged_arena(cfg: LMConfig, num_blocks: int, block_size: int,
@@ -111,19 +125,20 @@ def init_paged_arena(cfg: LMConfig, num_blocks: int, block_size: int,
         if key not in blk:
             continue
         s = blk[key].shape                       # (*layers, 1, bs, *post)
-        ax = len(s) - 4                          # just before the B axis
+        ax = len(s) + seq_axis(key) - 1          # just before the B axis
         out[key] = mk(s[:ax] + (num_blocks,) + s[ax:], blk[key].dtype)
     return out
 
 
-def arena_block_axis(a) -> int:
-    """Block-id axis of an :func:`init_paged_arena` array.
+def arena_block_axis(a, key: str) -> int:
+    """Block-id axis of an :func:`init_paged_arena` array of ``key``.
 
-    Every paged key's block shape ends ``(B=1, bs, heads-ish, feat)`` with
-    the block axis spliced in just before B, so it always sits 5 axes from
-    the end whatever the leading layer axes look like (one for decoder
-    k/v, two for the vlm grouped layout)."""
-    return a.ndim - 5
+    Every paged key's block shape ends ``(B=1, bs, *post)`` with the block
+    axis spliced in just before B, so it sits two axes before the
+    sequence axis whatever the leading layer axes look like (one for
+    decoder k/v, two for the vlm grouped layout): 5 from the end for K/V,
+    4 for the latent."""
+    return a.ndim + seq_axis(key) - 2
 
 
 def arena_specs(cfg: LMConfig, mesh_shape: dict[str, int]):
@@ -143,7 +158,7 @@ def arena_specs(cfg: LMConfig, mesh_shape: dict[str, int]):
         if key not in dense:
             continue
         sp = tuple(dense[key])
-        ax = len(sp) - 4                         # just before the B axis
+        ax = len(sp) + seq_axis(key) - 1         # just before the B axis
         out[key] = P(*sp[:ax], None, *sp[ax:])
     return out
 
@@ -156,7 +171,9 @@ def cache_specs(cfg: LMConfig, mesh_shape: dict[str, int], batch: int):
     fam = cfg.family
     specs: dict = {"len": P()}
     kv = P(None, b, seq, kv_heads, None)
-    if fam in ("decoder", "moe", "hybrid"):
+    if cfg.mla:
+        specs["latent"] = P(None, b, None, None)
+    elif fam in ("decoder", "moe", "hybrid"):
         specs["k"] = kv
         specs["v"] = kv
         if cfg.kv_quant:
@@ -210,11 +227,9 @@ def prefill(cfg: LMConfig, params, batch):
         L = cfg.n_layers - (1 if fam == "moe" else 0)
         x, kvs = lm._stack_scan(cfg, params["blocks"], body, x,
                                 jnp.arange(L, dtype=jnp.int32))
-        k, v = kvs
         if fam == "moe":
-            k = jnp.concatenate([kv0[0][None], k], 0)
-            v = jnp.concatenate([kv0[1][None], v], 0)
-        cache["k"], cache["v"] = k, v
+            kvs = _prepend_layer(kv0, kvs)
+        cache.update(_attn_cache(cfg, kvs))
 
     elif fam == "rwkv":
         def body(lp, x, _):
@@ -337,6 +352,21 @@ def prefill(cfg: LMConfig, params, batch):
     return cache, logits[:, 0]
 
 
+def _prepend_layer(first, rest):
+    """Per-layer cache arrays of a dense first layer (moe's ``dense0``)
+    put before the scanned layers' stack."""
+    return jax.tree.map(lambda a0, a: jnp.concatenate([a0[None], a], 0),
+                        first, rest)
+
+
+def _attn_cache(cfg: LMConfig, kvs) -> dict:
+    """The attention layers' stacked outputs as cache keys: (k, v), or the
+    latent rows under latent attention."""
+    if cfg.mla:
+        return {"latent": kvs}
+    return {"k": kvs[0], "v": kvs[1]}
+
+
 # ==========================================================================
 # Chunked (suffix-only) prefill: resume from an existing KV prefix.
 # ==========================================================================
@@ -400,35 +430,38 @@ def prefill_chunked(cfg: LMConfig, params, batch, cache, q_offset: int):
     fam = cfg.family
     new_cache = {"len": jnp.int32(q_offset + S)}
     assert fam in ("decoder", "moe", "hybrid", "encdec"), fam
-    pk_all, pv_all = cache["k"], cache["v"]     # (L, B, q_offset, Hkv, Dh)
-    assert pk_all.shape[-3] == q_offset, (pk_all.shape, q_offset)
+    if cfg.mla:
+        prefix = cache["latent"]                # (L, B, q_offset, W)
+        assert prefix.shape[-2] == q_offset, (prefix.shape, q_offset)
+    else:
+        pk_all, pv_all = cache["k"], cache["v"]  # (L, B, q_offset, Hkv, Dh)
+        assert pk_all.shape[-3] == q_offset, (pk_all.shape, q_offset)
+        prefix = (pk_all, pv_all)
 
     if fam in ("decoder", "moe"):
         if fam == "moe":
             p0 = jax.tree.map(lambda a: a[0], params["dense0"])
-            x, kv0, _ = lm.decoder_block(cfg, p0, x, positions,
-                                         q_offset=q_offset,
-                                         kv_prefix=(pk_all[0], pv_all[0]))
+            x, kv0, _ = lm.decoder_block(
+                cfg, p0, x, positions, q_offset=q_offset,
+                kv_prefix=jax.tree.map(lambda a: a[0], prefix))
 
         def body(lp, x, inp):
-            pk, pv, idx = inp
+            pre, idx = inp
             x, kv, _ = lm.decoder_block(cfg, lp, x, positions,
                                         window=layer_window(cfg, idx),
                                         moe_layer=(fam == "moe"),
                                         moe_dropless=cfg.moe_dropless_prefill,
                                         q_offset=q_offset,
-                                        kv_prefix=(pk, pv))
+                                        kv_prefix=pre)
             return x, kv
         L = cfg.n_layers - (1 if fam == "moe" else 0)
         off = 1 if fam == "moe" else 0
         x, kvs = lm._stack_scan(cfg, params["blocks"], body, x,
-                                (pk_all[off:], pv_all[off:],
+                                (jax.tree.map(lambda a: a[off:], prefix),
                                  jnp.arange(L, dtype=jnp.int32)))
-        k, v = kvs
         if fam == "moe":
-            k = jnp.concatenate([kv0[0][None], k], 0)
-            v = jnp.concatenate([kv0[1][None], v], 0)
-        new_cache["k"], new_cache["v"] = k, v
+            kvs = _prepend_layer(kv0, kvs)
+        new_cache.update(_attn_cache(cfg, kvs))
 
     elif fam == "hybrid":
         if lm.hybrid_grouped(cfg):
@@ -552,6 +585,20 @@ def _decode_attn(cfg, p, x1, cache_k, cache_v, pos, *, window=0,
     return out, cache_k, cache_v, None
 
 
+def _decode_attn_mla(cfg, p, x1, cache_lat, pos):
+    """Latent attention's :func:`_decode_attn`: x1 (B,1,d); writes the new
+    latent row at ``pos`` and attends in the absorbed form.  Returns
+    (out, new cache_lat)."""
+    B = x1.shape[0]
+    q, row = lm.mla_project(cfg, p, x1, jnp.broadcast_to(pos[None], (B, 1)))
+    cache_lat = jax.lax.dynamic_update_slice_in_dim(cache_lat, row, pos,
+                                                    axis=1)
+    o = attention.attend_latent(lm.mla_absorb(cfg, p, q[:, 0]), cache_lat,
+                                jnp.broadcast_to(pos + 1, (B,)),
+                                scale=lm.mla_scale(cfg), dv=cfg.kv_lora_rank)
+    return lm.mla_unabsorb(cfg, p, o), cache_lat
+
+
 def decode_step(cfg: LMConfig, params, cache, tokens):
     """tokens: (B, 1).  Returns (new_cache, logits (B, vocab_padded))."""
     B = tokens.shape[0]
@@ -570,54 +617,45 @@ def decode_step(cfg: LMConfig, params, cache, tokens):
         blocks = params["blocks"]
         L = cfg.n_layers - (1 if fam == "moe" else 0)
         quant = cfg.kv_quant
+        keys = ("latent",) if cfg.mla else \
+            ("k", "v") + (("k_scale", "v_scale") if quant else ())
 
-        def layer_caches(sl):
-            out = [cache["k"][sl], cache["v"][sl]]
-            if quant:
-                out += [cache["k_scale"][sl], cache["v_scale"][sl]]
-            return out
+        def attend(lp, x, caches, window=0):
+            """Returns (out, this layer's updated caches, in ``keys``
+            order)."""
+            z = _norm_apply(cfg, lp["ln1"], x)
+            if cfg.mla:
+                h, lat = _decode_attn_mla(cfg, lp["attn"], z, caches[0], pos)
+                return h, (lat,)
+            h, ck, cv, sc = _decode_attn(
+                cfg, lp["attn"], z, caches[0], caches[1], pos, window=window,
+                scales=tuple(caches[2:]) if quant else None)
+            return h, (ck, cv) + (sc if quant else ())
 
         if fam == "moe":
             p0 = jax.tree.map(lambda a: a[0], params["dense0"])
-            c0 = layer_caches(0)
-            h, k0, v0, sc0 = _decode_attn(
-                cfg, p0["attn"], _norm_apply(cfg, p0["ln1"], x),
-                c0[0], c0[1], pos,
-                scales=tuple(c0[2:]) if quant else None)
+            h, outs0 = attend(p0, x, [cache[key][0] for key in keys])
             x = x + h
             x = x + _mlp_apply(cfg, p0["mlp"], _norm_apply(cfg, p0["ln2"], x))
 
         def body(x, inp):
             lp, caches, idx = inp
-            ck, cv = caches[0], caches[1]
-            h, ck, cv, sc = _decode_attn(
-                cfg, lp["attn"], _norm_apply(cfg, lp["ln1"], x), ck, cv,
-                pos, window=layer_window(cfg, idx),
-                scales=(caches[2], caches[3]) if quant else None)
+            h, outc = attend(lp, x, caches, window=layer_window(cfg, idx))
             x = x + h
             z = _norm_apply(cfg, lp["ln2"], x)
             if fam == "moe":
                 y, _ = lm.moe_ffn_decode(cfg, lp["moe"], z)
             else:
                 y = _mlp_apply(cfg, lp["mlp"], z)
-            outc = (ck, cv) + (sc if quant else ())
             return x + y, outc
 
         off = slice(1, None) if fam == "moe" else slice(None)
         x, outs = jax.lax.scan(
-            body, x, (blocks, tuple(layer_caches(off)),
+            body, x, (blocks, tuple(cache[key][off] for key in keys),
                       jnp.arange(L, dtype=jnp.int32)))
-        ks, vs = outs[0], outs[1]
         if fam == "moe":
-            ks = jnp.concatenate([k0[None], ks], 0)
-            vs = jnp.concatenate([v0[None], vs], 0)
-        new_cache["k"], new_cache["v"] = ks, vs
-        if quant:
-            kss, vss = outs[2], outs[3]
-            if fam == "moe":
-                kss = jnp.concatenate([sc0[0][None], kss], 0)
-                vss = jnp.concatenate([sc0[1][None], vss], 0)
-            new_cache["k_scale"], new_cache["v_scale"] = kss, vss
+            outs = _prepend_layer(outs0, outs)
+        new_cache.update(zip(keys, outs))
 
     elif fam == "rwkv":
         def body(x, inp):
@@ -761,6 +799,17 @@ def decode_step(cfg: LMConfig, params, cache, tokens):
 # Decode (one token), in place against the paged block arena.
 # ==========================================================================
 
+def decode_moe_work(cfg: LMConfig, lanes: int, active: int) -> dict:
+    """MoE work of one :func:`decode_step_paged` tick of ``lanes`` lanes,
+    counted from shapes: ``moe_rows``, the rows the expert FFN computes
+    (every lane, active or not, is a dispatch group of one token, in every
+    MoE layer), and ``moe_picks``, the router picks the ``active`` lanes
+    need."""
+    layers = cfg.n_layers - 1               # layer 0 is dense
+    return {"moe_rows": lanes * layers * lm.moe_ffn_decode_rows(cfg, 1),
+            "moe_picks": active * cfg.top_k * layers}
+
+
 def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
                       arena, wbids=None, kernel=None, interpret=None,
                       backend=None, cascade=None):
@@ -823,7 +872,7 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
         "in-place paged decode: only the XLA reference covers the vlm " \
         "grouped layout"
     S = tokens.shape[0]
-    bs = arena["k"].shape[-3]
+    bs = arena["latent"].shape[-2] if cfg.mla else arena["k"].shape[-3]
     nb = tables.shape[1]
     pos = jnp.asarray(lens, jnp.int32)
     offs = pos % bs
@@ -852,7 +901,13 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
         empty when the kernel reads the whole arena at ``layer``.  Returns
         (out, *rows): rows are the sequence-axis writes this layer owes
         the arena — (k1, v1) plain, + (k1_scale, v1_scale) under the int8
-        kv_quant layout."""
+        kv_quant layout, or the one latent row under latent attention."""
+        if cfg.mla:
+            out = lm.attn_decode_paged_mla(
+                cfg, lp, z, arena["latent"] if whole else kv[0], tables, pos,
+                backend=backend, layer=layer if whole else None,
+                interpret=interpret)
+            return out[0], out[1:]
         if whole:
             kb, vb, sc = arena["k"], arena["v"], None
         else:
@@ -866,6 +921,8 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
     def layer_arenas(sl):
         if whole:
             return ()
+        if cfg.mla:
+            return (arena["latent"][sl],)
         out = (arena["k"][sl], arena["v"][sl])
         if quant:
             out += (arena["k_scale"][sl], arena["v_scale"][sl])
@@ -885,7 +942,8 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
             if fam == "moe":
                 # per-lane dispatch groups of one token, exactly the
                 # vmapped dense tick's routing (a lane's output must not
-                # depend on which other lanes share its decode batch)
+                # depend on which other lanes share its decode batch);
+                # decode_moe_work counts the rows this dispatch computes
                 y = jax.vmap(lambda zi: lm.moe_ffn_decode(
                     cfg, lp["moe"], zi[None])[0][0])(z)
             else:
@@ -1023,9 +1081,15 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
     new_arena = dict(arena)
     if fam == "vlm":
         row_keys = ("k", "v", "kx_self", "vx_self")
+    elif cfg.mla:
+        row_keys = ("latent",)
     else:
         row_keys = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
-    if backend == "pallas":
+    if backend == "pallas" and cfg.mla:
+        from repro.kernels.paged_attn import scatter_latent_rows
+        new_arena["latent"] = scatter_latent_rows(
+            arena["latent"], rows[0], wbids, offs, interpret=interpret)
+    elif backend == "pallas":
         from repro.kernels.paged_attn import scatter_kv_rows
         new_arena["k"], new_arena["v"] = scatter_kv_rows(
             arena["k"], arena["v"], rows[0], rows[1], wbids, offs,
@@ -1034,9 +1098,10 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
         for key, r in zip(row_keys, rows):
             # leading layer axes vary per key (one for decoder k/v, two
             # for vlm's grouped self k/v, one for its cross-layer self
-            # k/v); the (block, B=1, offset) triple always sits 5 axes
-            # from the end — see arena_block_axis
-            idx = (slice(None),) * (arena[key].ndim - 5) + (wbids, 0, offs)
+            # k/v); the (block, B=1, offset) triple sits just before the
+            # row's trailing axes — see arena_block_axis
+            idx = (slice(None),) * arena_block_axis(arena[key], key) + \
+                (wbids, 0, offs)
             new_arena[key] = arena[key].at[idx].set(r)
 
     x = _norm_apply(cfg, params["final_norm"], x)

@@ -152,9 +152,9 @@ class KVSlotAdapter:
     compilation, any mix of slot positions.
     """
 
-    # cache keys whose axis -3 is the sequence axis (padded to max_len);
-    # cross-attention keys (xk/xv) are fixed-length and never padded.
-    SEQ_KEYS = ("k", "v", "k_scale", "v_scale", "kx_self", "vx_self")
+    # cache keys with a sequence axis (padded to max_len); cross-attention
+    # keys (xk/xv) are fixed-length and never padded.
+    SEQ_KEYS = engine.PAGED_SEQ_KEYS
 
     def __init__(self, cfg: LMConfig, params, n_slots: int, max_len: int,
                  extras: Callable[[], dict] | None = None):
@@ -190,9 +190,9 @@ class KVSlotAdapter:
         cache1 = dict(cache1)
         for key in self.SEQ_KEYS:
             if key in cache1:
-                a = cache1[key]
+                a, ax = cache1[key], engine.seq_axis(key)
                 pad = [(0, 0)] * a.ndim
-                pad[-3] = (0, self.max_len - a.shape[-3])
+                pad[ax] = (0, self.max_len - a.shape[ax])
                 cache1[key] = jnp.pad(a, pad)
         self.cache = jax.tree.map(lambda sl, c1: sl.at[slot].set(c1),
                                   self.cache, cache1)
@@ -448,7 +448,8 @@ class ContinuousBatcher:
                     self.adapter.clear(slot)
                     self.last_token[slot] = 0
         if sp is not None:
-            sp.set(active=self.last_active, finished=len(finished))
+            sp.set(active=self.last_active, finished=len(finished),
+                   **(getattr(self.adapter, "last_moe", None) or {}))
         return finished
 
     def _admit(self, finished: list[Request]) -> tuple[int, int]:

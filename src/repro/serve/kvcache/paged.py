@@ -96,10 +96,11 @@ from repro.serve.kvcache.pool import (TRASH_BLOCK, BlockPool, PoolExhausted)
 from repro.serve.obs.tracer import NO_SPAN
 
 
-def _pad_seq(a: jax.Array, target: int) -> jax.Array:
-    """Zero-pad a cache array's sequence axis (-3) to ``target``."""
+def _pad_seq(a: jax.Array, target: int, key: str) -> jax.Array:
+    """Zero-pad the sequence axis of cache key ``key`` to ``target``."""
+    ax = engine.seq_axis(key)
     pad = [(0, 0)] * a.ndim
-    pad[-3] = (0, target - a.shape[-3])
+    pad[ax] = (0, target - a.shape[ax])
     return jnp.pad(a, pad)
 
 
@@ -173,7 +174,13 @@ def _unpersisted(fn):
             out.compile = _unpersisted(out.compile)
         return out
     if hasattr(fn, "lower"):
-        call.lower, call._cache_size = _unpersisted(fn.lower), fn._cache_size
+        # through a Python closure, never the jitted function's own bound
+        # ``_cache_size``: that binding hides ``fn`` from the garbage
+        # collector, so the cycle adapter -> call -> fn -> bound method ->
+        # adapter was never collected and every Pallas adapter kept its
+        # params and arena on the device for the life of the process
+        call.lower = _unpersisted(fn.lower)
+        call._cache_size = lambda: fn._cache_size()
     return call
 
 
@@ -277,9 +284,11 @@ class PagedKVSlotAdapter:
         explicit = backend is not None or kernel
         self.backend = resolve_backend(backend, inplace=inplace,
                                        kernel=kernel, warn=True)
-        if self.backend in ("pallas", "cascade") and \
-                (cfg.kv_quant or cfg.family == "vlm"):
-            layout = "int8 kv_quant" if cfg.kv_quant else "vlm grouped"
+        if (self.backend in ("pallas", "cascade") and
+                (cfg.kv_quant or cfg.family == "vlm")) or \
+                (self.backend == "cascade" and cfg.mla):
+            layout = "int8 kv_quant" if cfg.kv_quant else \
+                "vlm grouped" if cfg.family == "vlm" else "latent"
             if explicit:
                 raise ValueError(
                     f"backend={self.backend!r} does not support the "
@@ -328,7 +337,7 @@ class PagedKVSlotAdapter:
             self.arena = _unpersisted(jax.jit(
                 make, out_shardings=self.arena_format or where))()
         self.seq_keys = tuple(self.arena)
-        self._bax = {key: engine.arena_block_axis(a)
+        self._bax = {key: engine.arena_block_axis(a, key)
                      for key, a in self.arena.items()}
         # hybrid: recurrent (conv/ssm) state at each indexed block boundary,
         # keyed by the boundary's chain key — what lets an SSM stream resume
@@ -344,6 +353,12 @@ class PagedKVSlotAdapter:
         # compute-skip telemetry (prefill_tokens_* in pool_stats)
         self.prefill_tokens_total = 0
         self.prefill_tokens_skipped_total = 0
+        # MoE decode work of each tick as ``engine.decode_moe_work``
+        # counts it from shapes: per tick in ``last_moe`` (the tick spans'
+        # args), and running totals
+        self.moe_rows_total = 0
+        self.moe_picks_total = 0
+        self.last_moe = None
         # obs span recorder, wired by the prompt gateways for a run's
         # duration; every use is guarded so a bare adapter makes zero
         # obs calls.  The batcher points the tracer's lane context at the
@@ -431,7 +446,7 @@ class PagedKVSlotAdapter:
         out = {}
         for key in self.seq_keys:
             a = padded[key]
-            ax = a.ndim - 3                     # the sequence axis
+            ax = a.ndim + engine.seq_axis(key)  # the sequence axis
             b = a.reshape(a.shape[:ax] + (self.nb_max, self.bs)
                           + a.shape[ax + 1:])
             b = jnp.moveaxis(b, ax, ax - 1)     # block axis just before B
@@ -466,9 +481,11 @@ class PagedKVSlotAdapter:
         out = {}
         for key in self.seq_keys:
             g = jnp.take(arena[key], bids, axis=self._bax[key])
-            g = jnp.moveaxis(g, self._bax[key], g.ndim - 4)  # behind B
-            out[key] = g.reshape(g.shape[:g.ndim - 4]
-                                 + (bids.shape[0] * self.bs,) + g.shape[-2:])
+            seq = g.ndim + engine.seq_axis(key)  # (..., 1, n, bs, *post)
+            g = jnp.moveaxis(g, self._bax[key], seq - 1)     # behind B
+            out[key] = g.reshape(g.shape[:seq - 1]
+                                 + (bids.shape[0] * self.bs,)
+                                 + g.shape[seq + 1:])
         return out
 
     # -- the chunked-prefill fold -------------------------------------------
@@ -538,9 +555,10 @@ class PagedKVSlotAdapter:
             g = jnp.take(arena[key], tables, axis=ax)
             g = jnp.moveaxis(g, ax, 0)          # slot lanes leading
             g = jnp.moveaxis(g, ax + 1, ax + 2)  # block axis behind B
+            seq = g.ndim + engine.seq_axis(key)
             cache[key] = g.reshape(
-                g.shape[:g.ndim - 4] + (self.nb_max * self.bs,)
-                + g.shape[-2:])
+                g.shape[:seq - 1] + (self.nb_max * self.bs,)
+                + g.shape[seq + 1:])
         new_cache, logits = jax.vmap(
             lambda c, t: engine.decode_step(self.cfg, p, c, t),
             in_axes=(0, 0))(cache, tokens[:, None])
@@ -560,8 +578,9 @@ class PagedKVSlotAdapter:
         for key in self.seq_keys:
             ax = self._bax[key]
             blk = jax.vmap(
-                lambda a, s: jax.lax.dynamic_slice_in_dim(
-                    a, s, self.bs, axis=a.ndim - 3))(new_cache[key], start)
+                lambda a, s, ax=engine.seq_axis(key):
+                jax.lax.dynamic_slice_in_dim(a, s, self.bs, axis=a.ndim + ax)
+            )(new_cache[key], start)
             blk = jnp.moveaxis(blk, 0, ax)
             idx = (slice(None),) * ax + (wbids,)
             new_arena[key] = arena[key].at[idx].set(blk)
@@ -711,7 +730,7 @@ class PagedKVSlotAdapter:
         cache, logits, snapshots = self._fold_prefill(prompt, q0, cache,
                                                       keys)
         cache = dict(cache)
-        padded = {key: _pad_seq(cache.pop(key), self.max_len)
+        padded = {key: _pad_seq(cache.pop(key), self.max_len, key)
                   for key in self.seq_keys}
         wbids = np.zeros(self.nb_max, np.int32)
         for j, key, b in fresh:
@@ -804,7 +823,7 @@ class PagedKVSlotAdapter:
             batch.update(self.extras())
         cache1, logits = self._prefill(self.params, batch)
         cache1 = dict(cache1)
-        padded = {key: _pad_seq(cache1.pop(key), self.max_len)
+        padded = {key: _pad_seq(cache1.pop(key), self.max_len, key)
                   for key in self.seq_keys}
         wbids = np.zeros(self.nb_max, np.int32)
         for j, key, b in fresh:
@@ -976,9 +995,15 @@ class PagedKVSlotAdapter:
             args = (jnp.asarray(self.tables),
                     jnp.asarray(tokens, jnp.int32)[:, None],
                     jnp.asarray(active, bool), jnp.asarray(wbids))
+            if self.cfg.moe is not None:
+                self.last_moe = engine.decode_moe_work(
+                    self.cfg, self.n_slots, int(active.sum()))
+                self.moe_rows_total += self.last_moe["moe_rows"]
+                self.moe_picks_total += self.last_moe["moe_picks"]
             if sp is not None:
                 sp.set(active=int(active.sum()),
-                       live_tokens=int(self.lens[active].sum()))
+                       live_tokens=int(self.lens[active].sum()),
+                       **(self.last_moe or {}))
         with (tr.span("tick.dispatch") if tr is not None else NO_SPAN):
             if meta is None:
                 # no chain shared by >= 2 lanes: degrade to the flat

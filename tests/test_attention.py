@@ -134,3 +134,33 @@ def test_decode_consistent_with_chunked_last_row():
     dec = attention.attend_decode(q[:, -1:], k, v, jnp.int32(S))
     np.testing.assert_allclose(np.asarray(dec[:, 0]),
                                np.asarray(full[:, -1]), rtol=2e-4, atol=2e-4)
+
+
+def test_absorbed_latent_decode_matches_expanded_form():
+    """Latent attention's decode form (``w_kv_b``'s key half folded into
+    the query, its value half applied after attending over the cached
+    latent rows) equals the expanded prefill form (the latent projected up
+    to per-head keys and values) at a small size, in float32; and decode
+    writes the row prefill computes."""
+    import dataclasses
+    from repro import configs
+    from repro.models import lm
+    from repro.serve import engine
+    cfg = dataclasses.replace(configs.smoke_config("moonshot_v1_16b_a3b"),
+                              param_dtype="float32")
+    params, _ = lm.init(jax.random.key(3), cfg, {})
+    p = jax.tree.map(lambda a: a[0], params["blocks"]["attn"])
+    S = 10
+    x = jnp.asarray(np.random.default_rng(4).normal(0, 1,
+                                                    (1, S, cfg.d_model)),
+                    jnp.float32)
+    full, rows = lm._mla_apply(cfg, p, x, jnp.arange(S)[None])
+    cache = jnp.zeros((1, 16, cfg.latent_width)).at[:, :S - 1].set(
+        rows[:, :S - 1])
+    out, cache = engine._decode_attn_mla(cfg, p, x[:, S - 1:], cache,
+                                         jnp.int32(S - 1))
+    np.testing.assert_allclose(np.asarray(out[:, 0]),
+                               np.asarray(full[:, S - 1]), rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(cache[:, :S]), np.asarray(rows),
+                               rtol=1e-6, atol=1e-6)

@@ -21,9 +21,11 @@ FAMILY_ARCH = {
     "moe": "deepseek_moe_16b",
     "hybrid": "hymba_1_5b",
     "encdec": "whisper_medium",
+    "mla": "moonshot_v1_16b_a3b",
 }
 
 BS = 4
+SEQ = ("k", "v", "latent")       # the attention cache keys a fold extends
 
 
 def _setup(arch, seed=0):
@@ -46,7 +48,7 @@ def _setup(arch, seed=0):
 def _empty_prefix(cfg, params, extras):
     empty = engine.init_cache(cfg, 1, 0, abstract=True)
     cache = {key: jnp.zeros(empty[key].shape, empty[key].dtype)
-             for key in ("k", "v") if key in empty}
+             for key in SEQ if key in empty}
     cache["len"] = jnp.int32(0)
     if cfg.family == "hybrid":
         cache["conv"] = jnp.zeros((cfg.n_layers, 1, cfg.conv_k - 1,
@@ -84,9 +86,12 @@ def test_engine_fold_resume_bitwise(family):
                                     _empty_prefix(cfg, params, extras), 0)
     for H in (0, 1, 2):
         q0 = H * BS
-        warm = {"len": jnp.int32(q0),
-                "k": cold_cache["k"][..., :q0, :, :],
-                "v": cold_cache["v"][..., :q0, :, :]}
+        warm = {"len": jnp.int32(q0)}
+        for key in SEQ:
+            if key in cold_cache:
+                a = cold_cache[key]
+                warm[key] = jax.lax.slice_in_dim(
+                    a, 0, q0, axis=a.ndim + engine.seq_axis(key))
         if family == "hybrid":
             # the recurrent boundary state comes from folding the prefix —
             # exactly what the adapter snapshots during a cold admission
@@ -99,10 +104,11 @@ def test_engine_fold_resume_bitwise(family):
         warm_cache, warm_logits = _fold(cfg, params, prompt, warm, q0)
         np.testing.assert_array_equal(np.asarray(cold_logits),
                                       np.asarray(warm_logits), err_msg=family)
-        for key in ("k", "v"):
-            np.testing.assert_array_equal(np.asarray(cold_cache[key]),
-                                          np.asarray(warm_cache[key]),
-                                          err_msg=(family, key, H))
+        for key in SEQ:
+            if key in cold_cache:
+                np.testing.assert_array_equal(np.asarray(cold_cache[key]),
+                                              np.asarray(warm_cache[key]),
+                                              err_msg=(family, key, H))
 
 
 def test_engine_fold_resume_bitwise_sliced_window():

@@ -96,3 +96,65 @@ def test_shared_experts_added():
     out0, _ = moe_lib.moe_ffn(x, p, cfg0)
     out2, _ = moe_lib.moe_ffn(x, p, cfg2)
     assert not np.allclose(np.asarray(out0), np.asarray(out2))
+
+
+# ==========================================================================
+# The sigmoid router (DeepSeek-V3 noaux_tc, Moonlight) and the softmax one.
+# ==========================================================================
+
+def test_sigmoid_router_matches_hand_computation():
+    """Scores sigmoid(x @ w); the top-k of scores + bias select, the
+    unbiased scores weight, renormalised, times ``routed_scaling``.  The
+    bias changes which experts are picked but never their weights."""
+    E, k = 4, 2
+    cfg = MoEConfig(E, k, 8, score="sigmoid", routed_scaling=2.5)
+    logits = np.asarray([2.0, 1.0, 0.5, -1.0], np.float32)
+    x = jnp.asarray(logits)[None, None]                 # (G=1, T=1, d=E)
+    w = jnp.eye(E, dtype=jnp.float32)                   # logits = x
+    s = 1.0 / (1.0 + np.exp(-logits.astype(np.float64)))
+    bias = jnp.zeros((E,), jnp.float32)
+    top_w, top_e, _ = moe_lib.router(x, w, cfg, bias)
+    assert top_e[0, 0].tolist() == [0, 1]
+    np.testing.assert_allclose(np.asarray(top_w[0, 0]),
+                               2.5 * s[[0, 1]] / s[[0, 1]].sum(), rtol=1e-6)
+    # a bias large enough lifts expert 3 over expert 1 in the selection;
+    # its weight is still its own unbiased score
+    bias = jnp.asarray([0.0, 0.0, 0.0, 0.6], jnp.float32)
+    top_w, top_e, _ = moe_lib.router(x, w, cfg, bias)
+    assert top_e[0, 0].tolist() == [0, 3]
+    np.testing.assert_allclose(np.asarray(top_w[0, 0]),
+                               2.5 * s[[0, 3]] / s[[0, 3]].sum(), rtol=1e-6)
+    np.testing.assert_allclose(float(jnp.sum(top_w)), 2.5, rtol=1e-6)
+
+
+def _softmax_router_before_sigmoid(x, w_router, cfg):
+    """The softmax router as it stood before the sigmoid one joined it."""
+    logits = jnp.einsum("gtd,de->gte", x.astype(jnp.float32),
+                        w_router.astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_e = jax.lax.top_k(probs, cfg.top_k)
+    top_w = top_w / jnp.maximum(jnp.sum(top_w, -1, keepdims=True), 1e-9)
+    density = jnp.mean(
+        jax.nn.one_hot(top_e, cfg.n_experts, dtype=jnp.float32), axis=(1, 2))
+    mean_prob = jnp.mean(probs, axis=1)
+    aux = cfg.n_experts * jnp.mean(jnp.sum(density * mean_prob, axis=-1))
+    return top_w, top_e, aux
+
+
+def test_softmax_router_bitwise_unchanged():
+    """deepseek-moe-16b's softmax path computes exactly what it did before
+    the sigmoid router was added: the same weights, experts and aux loss,
+    bit for bit."""
+    from repro import configs
+    cfg = configs.smoke_config("deepseek_moe_16b")
+    mcfg = cfg.moe
+    assert mcfg.score == "softmax"
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.normal(0, 1, (2, 32, cfg.d_model)), jnp.bfloat16)
+    p = _params(rng, mcfg.n_experts, cfg.d_model, mcfg.d_expert,
+                mcfg.n_shared)
+    got = jax.jit(lambda a, w: moe_lib.router(a, w, mcfg))(x, p["w_router"])
+    want = jax.jit(lambda a, w: _softmax_router_before_sigmoid(a, w, mcfg))(
+        x, p["w_router"])
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -539,6 +539,32 @@ def test_tick_spans_on_the_profiler_host_plane(tmp_path):
     b.tracer = ad.tracer = None
 
 
+def test_moe_tick_spans_count_expert_rows_and_picks():
+    """An MoE adapter's ``tick`` and ``tick.prepare`` spans carry the rows
+    its expert FFN computes in the tick (every lane, one-token groups:
+    2 lanes x 8 experts x capacity 2 x 2 MoE layers) and the router picks
+    the active lanes need (1 lane x top-2 x 2 layers), counted on the host,
+    and the adapter keeps their running totals."""
+    cfg, params = _setup("moonshot_v1_16b_a3b")
+    ad = make_adapter(cfg, params, n_slots=2, max_len=16, paged=True,
+                      block_size=BS)
+    b = ContinuousBatcher(ad)
+    tracer = obs.Tracer(clock=obs.WallClock())
+    b.tracer = ad.tracer = tracer
+    b.submit(Request(uid=0, prompt=np.arange(6, dtype=np.int32),
+                     max_new_tokens=3))
+    b.step()
+    b.step()
+    want = {"moe_rows": 64, "moe_picks": 4}
+    for name in ("tick", "tick.prepare"):
+        spans = tracer.spans(name)
+        assert len(spans) == 2
+        for sp in spans:
+            assert {k: sp["args"][k] for k in want} == want, name
+    assert (ad.moe_rows_total, ad.moe_picks_total) == (128, 8)
+    b.tracer = ad.tracer = None
+
+
 def test_queue_wait_runs_from_submit_when_driven_directly():
     cfg, params = _setup()
     ad = make_adapter(cfg, params, n_slots=1, max_len=16, paged=True,

@@ -244,3 +244,55 @@ def test_scatter_kv_rows_trash_collisions_stay_in_trash():
         np.asarray(nk[:, 2, 0, 3]), np.asarray(kr[:, 2]))
     np.testing.assert_array_equal(
         np.asarray(nv[:, 2, 0, 3]), np.asarray(vr[:, 2]))
+
+
+# ==========================================================================
+# The latent (MLA) decode read and its row write.
+# ==========================================================================
+
+@pytest.mark.parametrize("nb,dtype", [(3, jnp.float32), (20, jnp.float32),
+                                      (17, jnp.bfloat16),
+                                      (300, jnp.float32)])
+def test_latent_kernel_matches_gather_reference(nb, dtype):
+    """The latent kernel against ``attend_decode_latent``'s XLA gather, on
+    the whole layer-leading arena at each layer:
+    tables shorter than, equal to and longer than one step's 8 entries, and
+    of 300 entries (positions past 1024, as the Moonlight cell's 136
+    entries of 16 reach), lanes ending anywhere in a step (entries past a lane's last live block
+    are read as that block again and skipped), the new row overlaid at
+    ``lens - 1``; the arena's other rows are noise the lengths mask."""
+    from repro.kernels.paged_attn import latent_decode_attention
+    rng = np.random.default_rng(nb)
+    B, Hq, W, dv, bs, L = 5, 4, 80, 64, 4, 2
+    num_blocks = B * nb + 1
+    arena = jnp.asarray(rng.normal(0, 1, (L, num_blocks, 1, bs, W)), dtype)
+    tables = jnp.asarray(rng.permutation(np.arange(1, num_blocks))
+                         .reshape(B, nb), jnp.int32)
+    lens = jnp.asarray([1, bs, bs + 1, nb * bs - 3, nb * bs], jnp.int32)
+    q = jnp.asarray(rng.normal(0, 1, (B, Hq, W)), dtype)
+    row = jnp.asarray(rng.normal(0, 1, (B, W)), dtype)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    for layer in range(L):
+        want = attention.attend_decode_latent(
+            q, arena[layer, :, 0], tables, lens, new_row=row, scale=0.1,
+            dv=dv)
+        got = latent_decode_attention(q, arena, tables, lens, new_row=row,
+                                      scale=0.1, dv=dv,
+                                      layer=jnp.int32(layer), interpret=True)
+        assert got.shape == (B, Hq, dv) and got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=tol, atol=tol)
+
+
+def test_scatter_latent_rows_matches_at_set():
+    from repro.kernels.paged_attn import scatter_latent_rows
+    rng = np.random.default_rng(3)
+    L, nblk, bs, W = 3, 9, 4, 80
+    arena = jnp.asarray(rng.normal(0, 1, (L, nblk, 1, bs, W)), jnp.float32)
+    rows = jnp.asarray(rng.normal(0, 1, (L, 4, W)), jnp.float32)
+    wbids = jnp.asarray([3, 7, 5, 8], jnp.int32)
+    offs = jnp.asarray([0, 3, 1, 2], jnp.int32)
+    want = arena.at[:, wbids, 0, offs].set(rows)
+    got = scatter_latent_rows(arena, rows, wbids, offs, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

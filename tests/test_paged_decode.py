@@ -19,6 +19,7 @@ FAMILY_ARCH = {                      # one arch per attention family
     "moe": "deepseek_moe_16b",       # causal + routed FFN
     "hybrid": "hymba_1_5b",          # sliding windows + GQA + SSM state
     "encdec": "whisper_medium",      # causal self + cross attention
+    "mla": "moonshot_v1_16b_a3b",    # latent attention + sigmoid-routed FFN
 }
 BS = 4
 
@@ -475,6 +476,81 @@ def test_arena_format_held_through_insert_cow_tick_and_migration(backend,
                 call(foreign())
         else:
             call(foreign())
+
+
+@pytest.mark.parametrize("backend,sliced", [
+    pytest.param("pallas", False, id="pallas"),
+    pytest.param("xla", False, id="xla"),
+    pytest.param("pallas", True, id="pallas-sliced")])
+def test_latent_arena_through_insert_cow_tick_and_migration(backend, sliced):
+    """The latent-attention arena, one row per token and layer with no head
+    axis, through every path a K/V arena takes: held row-major under
+    "pallas"; a second insert shares the first's prefix blocks (the full
+    one and the partial one, which copy-on-write then splits); ticks
+    follow the gather-tick oracle (bitwise under "xla", within the latent
+    kernel's tolerance under "pallas"); and a migration to a second
+    adapter, on a one-device slice mesh with ``sliced``, lands the chain's
+    rows unchanged."""
+    from jax.sharding import Mesh
+    from repro.serve.shard.migrate import migrate_slot
+    cfg, params, _ = _setup("moonshot_v1_16b_a3b")
+    rng = np.random.default_rng(17)
+    prompt = rng.integers(0, cfg.vocab, size=6).astype(np.int32)
+    devs = jax.devices()
+    mesh = lambda i: Mesh(np.asarray([devs[i % len(devs)]]), ("model",)) \
+        if sliced else None
+    mk = lambda b, i=0: make_adapter(cfg, params, n_slots=2, max_len=24,
+                                     paged=True, block_size=BS,
+                                     chunked=False, backend=b, mesh=mesh(i))
+    ad, oracle, dst = mk(backend), mk("gather"), mk(backend, 1)
+    assert ad.seq_keys == ("latent",)
+    assert ad.arena["latent"].shape[-3:] == (1, BS, cfg.latent_width)
+    for a in (ad, dst):
+        for arr in a.arena.values():
+            layout = arr.format.layout.major_to_minor
+            assert backend == "xla" or layout == tuple(range(arr.ndim))
+    for slot in range(2):           # the second shares the partial block
+        assert ad.insert(slot, prompt, max_new=8) == \
+            oracle.insert(slot, prompt, max_new=8)
+    assert ad.slot_stats(1)["prefix_hit_blocks"] == 2
+    active = np.ones(2, bool)
+    for toks in ([3, 7], [11, 2], [5, 5]):
+        toks = np.asarray(toks, np.int32)
+        np.testing.assert_array_equal(ad.decode(toks, active),
+                                      oracle.decode(toks, active))
+        got, want = (np.asarray(a.last_logits) for a in (ad, oracle))
+        if backend == "xla":
+            np.testing.assert_array_equal(got, want)
+        else:
+            np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    assert ad.pool.cow_copies >= 1
+    live = -(-int(ad.lens[0]) // BS)
+    src_blocks = {k: v for k, v in _chain_blocks(ad, 0).items()
+                  if k[1] < live}
+    migrate_slot(ad, 0, dst, 0, prompt)
+    for (key, j), blk in src_blocks.items():
+        np.testing.assert_array_equal(
+            np.asarray(dst.arena_block(key, dst.slot_bids[0][j])), blk)
+
+
+@pytest.mark.parametrize("backend", ["pallas", "xla"])
+def test_dropped_adapter_frees_its_arena(backend):
+    """An adapter nobody holds is collected with its arena: a process that
+    builds a second gateway (a run after a run, on the chip) must find the
+    first one's device memory free.  Under "pallas" a wrapper of the jitted
+    programs once hid a reference cycle from the collector."""
+    import gc
+    import weakref
+    cfg, params, _ = _setup("stablelm_3b")
+    ad = make_adapter(cfg, params, n_slots=2, max_len=16, paged=True,
+                      block_size=BS, backend=backend)
+    ad.insert(0, np.arange(5, dtype=np.int32), max_new=4)
+    ad.decode(np.zeros(2, np.int32), np.asarray([True, False]))
+    alive, shape = weakref.ref(ad), ad.arena["k"].shape
+    del ad
+    gc.collect()
+    assert alive() is None
+    assert not any(a.shape == shape for a in jax.live_arrays())
 
 
 def test_pinned_arena_survives_the_persistent_compile_cache(tmp_path,

@@ -32,6 +32,7 @@ FAMILY_ARCH = {                      # one arch per attention family
     "moe": "deepseek_moe_16b",
     "hybrid": "hymba_1_5b",
     "encdec": "whisper_medium",
+    "mla": "moonshot_v1_16b_a3b",
 }
 BS = 4
 
@@ -111,12 +112,13 @@ def test_slice_placement_bitwise(family):
 def test_arena_specs_match_layout():
     """engine.arena_specs must produce one spec per paged key with the
     arena's exact rank, for every family layout (incl. vlm's grouped axes
-    and the int8 quant scales), and shard KV heads over "model" exactly
-    when cache_specs would."""
+    the int8 quant scales and the latent arena), and shard KV heads over
+    "model" exactly when cache_specs would."""
     ms = {"data": 2, "model": 2}
     for arch, quant in [("stablelm_3b", False), ("stablelm_3b", True),
                         ("hymba_1_5b", False), ("whisper_medium", False),
-                        ("llama32_vision_90b", False)]:
+                        ("llama32_vision_90b", False),
+                        ("moonshot_v1_16b_a3b", False)]:
         cfg = configs.smoke_config(arch)
         if quant:
             cfg = dataclasses.replace(cfg, kv_quant=True)
@@ -126,7 +128,7 @@ def test_arena_specs_match_layout():
         for key, a in arena.items():
             sp = tuple(specs[key])
             assert len(sp) == a.ndim, (arch, key, sp, a.shape)
-            assert sp[engine.arena_block_axis(a)] is None, \
+            assert sp[engine.arena_block_axis(a, key)] is None, \
                 "the block axis never shards"
             want = "model" if cfg.n_kv_heads % ms["model"] == 0 else None
             if key in ("k", "v"):

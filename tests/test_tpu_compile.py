@@ -7,6 +7,7 @@ The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
 test worker imports this file.
 """
+import dataclasses
 import os
 import re
 
@@ -28,7 +29,12 @@ LANES, NB, BS, BLOCKS = 8, 32, 16, 257     # 8 lanes x 512 tokens, block 16
 LENET_PATCHES, LENET_K, LENET_O = 32 * 784, 25, 32   # LeNet-5 conv1
 # the served tick of bench/configs/stablelm-3b.json: 16 lanes x 64 table
 # entries of 16-token blocks, 448 blocks in the arena
-TICK_LANES, TICK_NB, TICK_BLOCKS, TICK_MAXLEN = 16, 64, 448, 1024
+TICK_LANES, TICK_NB, TICK_BLOCKS = 16, 64, 448
+# Moonlight-16B-A3B's latent rows (kv_lora_rank 512 + rope 64) read by 16
+# query heads, and the served tick of bench/configs/moonlight-16b-a3b.json:
+# 9 of its 27 layers, 64 lanes x 136 entries, 8704 blocks
+LATENT_HQ, LATENT_W, LATENT_DV = 16, 576, 512
+MLA_LAYERS, MLA_LANES, MLA_NB, MLA_BLOCKS = 9, 64, 136, 8704
 
 
 @pytest.fixture(scope="module")
@@ -153,6 +159,70 @@ def _arena_sized_ops(text, arena_shape):
     return found
 
 
+def test_latent_decode_kernel_compiles(one_chip):
+    """The latent read at Moonlight's widths over the whole layer-leading
+    arena, and its row write."""
+    bf, i32 = jnp.bfloat16, jnp.int32
+    arena = ((MLA_LAYERS, BLOCKS, 1, BS, LATENT_W), bf)
+    text = _compile(
+        one_chip,
+        lambda q, a, t, ln, row, y: pk.latent_decode_attention(
+            q, a, t, ln, new_row=row, scale=192 ** -0.5, dv=LATENT_DV,
+            layer=y, interpret=False),
+        ((LANES, LATENT_HQ, LATENT_W), bf), arena, ((LANES, NB), i32),
+        ((LANES,), i32), ((LANES, LATENT_W), bf), ((), i32))
+    assert "tpu_custom_call" in text
+    text = _compile(
+        one_chip,
+        lambda a, r, w, o: pk.scatter_latent_rows(a, r, w, o,
+                                                  interpret=False),
+        arena, ((MLA_LAYERS, LANES, LATENT_W), bf), ((LANES,), i32),
+        ((LANES,), i32), donate_argnums=(0,))
+    assert "tpu_custom_call" in text
+
+
+def _served_tick(one_chip, cfg, lanes, nb, blocks):
+    """The adapter's Pallas tick for ``cfg`` compiled from abstract params
+    and the arena held row-major: (executable, arena shapes)."""
+    sds = lambda a, where=one_chip: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=where)
+    params = jax.tree.map(sds, lm.init(jax.random.key(0), cfg, {},
+                                       abstract=True)[0])
+    shape = engine.init_paged_arena(cfg, blocks, BS, abstract=True)
+    fmt = paged.arena_formats(shape, "pallas",
+                              {key: one_chip for key in shape})
+    assert paged.arena_formats(shape, "xla", {}) is None
+    arena = {key: sds(a, fmt[key]) for key, a in shape.items()}
+    dense = {key: jax.ShapeDtypeStruct((lanes,) + a.shape, a.dtype,
+                                       sharding=one_chip)
+             for key, a in engine.init_cache(cfg, 1, nb * BS,
+                                             abstract=True).items()
+             if key not in shape}
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    tick = paged.decode_tick(cfg, nb * BS, "pallas", fmt,
+                             interpret=False).lower(
+        params, arena, dense, i32(lanes, nb), i32(lanes, 1),
+        jax.ShapeDtypeStruct((lanes,), bool, sharding=one_chip),
+        i32(lanes)).compile()
+    return tick, shape
+
+
+def test_served_latent_tick_reads_the_arena_where_it_lies(one_chip):
+    """Moonlight's served tick at the benchmark's cut (9 layers, 64 lanes,
+    8704 blocks): the latent arena is held row-major and read and written
+    by the kernels where it lies, with no copy or slice of the whole arena
+    or of one layer of it and no arena-sized temporary (the arena is
+    1.44e9 B)."""
+    cfg = dataclasses.replace(configs.config("moonshot_v1_16b_a3b"),
+                              n_layers=MLA_LAYERS)
+    tick, shape = _served_tick(one_chip, cfg, MLA_LANES, MLA_NB, MLA_BLOCKS)
+    text = tick.as_text()
+    assert "latent_decode_attention" in text
+    assert "scatter_latent_rows" in text
+    assert _arena_sized_ops(text, shape["latent"].shape) == []
+    assert tick.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 @pytest.mark.parametrize("arch", ["stablelm_3b", "hymba_1_5b"])
 def test_served_tick_reads_the_arena_where_it_lies(one_chip, arch):
     """The full-width Pallas tick, built as the adapter builds it
@@ -162,27 +232,8 @@ def test_served_tick_reads_the_arena_where_it_lies(one_chip, arch):
     remains (head dims 80 and 64 both sit off the 128 lanes), and the
     compiler's temporaries stay far below an arena (3.82e9 B for
     stablelm-3b with the default layout and per-layer slices)."""
-    cfg = configs.config(arch)
-    sds = lambda a, where=one_chip: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=where)
-    params = jax.tree.map(sds, lm.init(jax.random.key(0), cfg, {},
-                                       abstract=True)[0])
-    shape = engine.init_paged_arena(cfg, TICK_BLOCKS, BS, abstract=True)
-    fmt = paged.arena_formats(shape, "pallas",
-                              {key: one_chip for key in shape})
-    assert paged.arena_formats(shape, "xla", {}) is None
-    arena = {key: sds(a, fmt[key]) for key, a in shape.items()}
-    dense = {key: jax.ShapeDtypeStruct((TICK_LANES,) + a.shape, a.dtype,
-                                       sharding=one_chip)
-             for key, a in engine.init_cache(cfg, 1, TICK_MAXLEN,
-                                             abstract=True).items()
-             if key not in shape}
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
-    tick = paged.decode_tick(cfg, TICK_MAXLEN, "pallas", fmt,
-                             interpret=False).lower(
-        params, arena, dense, i32(TICK_LANES, TICK_NB), i32(TICK_LANES, 1),
-        jax.ShapeDtypeStruct((TICK_LANES,), bool, sharding=one_chip),
-        i32(TICK_LANES)).compile()
+    tick, shape = _served_tick(one_chip, configs.config(arch), TICK_LANES,
+                               TICK_NB, TICK_BLOCKS)
     text = tick.as_text()
     assert "tpu_custom_call" in text
     assert _arena_sized_ops(text, shape["k"].shape) == []
