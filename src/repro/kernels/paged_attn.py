@@ -4,17 +4,24 @@ The XLA paged path (``nn.attention.attend_decode_paged``) gathers each
 request's block chain into a dense (B, S, Hkv, D) array before attending —
 at production sizes that materializes the whole cache in HBM every decode
 tick.  This kernel reads K/V *directly out of the block arena*: the block
-table rides in as a scalar-prefetch operand, so the BlockSpec index map can
-route grid step (b, j) at the arena block ``table[b, j]`` and the DMA
-engine streams exactly the blocks each request owns — HBM traffic is one
-read of the live blocks and nothing else, and the trash block (id 0) that
-pads short chains is masked out by ``lens`` like any overlong position.
+table rides in as a scalar-prefetch operand, so the BlockSpec index maps
+route each grid step at arena blocks ``table[b, e]``.  The sweep is bounded
+to each lane's live entries ``[lo, last]`` (``last`` holds position
+``lens - 1``, ``lo`` the sliding window's first position): an entry outside
+is mapped onto a live one its tile has fetched already, so the pipeline
+issues no DMA for it, and a step holding no live entry skips its compute.
+HBM traffic is about one read of the live blocks, and the trash block
+(id 0) that pads short chains is never read for a lane that holds a
+position.  The host counts the entries computed against the blocks live
+(``engine.decode_attn_work``).
 
-Grid (B, nb): the trailing dim iterates a request's chain sequentially, so
-the online-softmax state (m, l, acc) lives in VMEM scratch across the sweep
-— the same structure as ``flash_attn.py`` with the block table supplying
-the indirection.  GQA is handled in-kernel: q (Hq, D) is viewed as
-(Hkv, n_rep, D) and batched against the block's (Hkv, bs, D) K tile.
+Grid (B, ceil(nb / T)): each step reads T table entries (``paged_tiles``,
+sized to the v5e's scoped VMEM) and computes them as one run of T * bs
+rows, so the online-softmax state (m, l, acc) in VMEM scratch takes one
+update a step.  The rows are scored in the arena's own (rows, Hkv, D)
+layout, a float32 multiply-reduce over D per KV head with no relayout of
+the tiles; GQA runs it once per query head of a KV head (q is read as
+(n_rep, Hkv, D)).
 
 Sliding windows ride in as a third scalar-prefetch operand: positions
 outside ``[lens - window, lens)`` are masked to NEG_INF exactly like
@@ -39,14 +46,13 @@ The cascade extension (``backend="cascade"``) splits decode attention over
 a shared radix prefix and per-lane divergent suffixes and merges the
 partial online-softmax states by log-sum-exp: ``cascade_prefix_attention``
 runs one multi-query pass per shared chain (prefix KV streamed once per
-*group*, not once per lane), ``paged_decode_attention_with_state`` is this
-file's flat sweep restarted at an absolute position offset ``q0`` and
-returning its *unnormalized* (acc, m, l) state, and ``merge_attn_states``
-fuses the two states and normalizes.  Unlike the flat kernel, the state
+*group*, not once per lane), ``paged_decode_attention_with_state`` is a
+one-entry-a-step sweep over every table entry, restarted at an absolute
+position offset ``q0`` and returning its *unnormalized* (acc, m, l) state,
+and ``merge_attn_states`` fuses the two states and normalizes.  The state
 kernels zero masked probabilities (``p *= valid``) so an all-masked sweep
-yields the empty state (m = NEG_INF, l = 0) that the merge drops exactly —
-the flat kernel can leave garbage in fully-masked lanes because it
-normalizes in place and its callers mask those lanes out.
+yields the empty state (m = NEG_INF, l = 0) that the merge drops exactly;
+the flat kernel computes no step without a live position.
 """
 from __future__ import annotations
 
@@ -63,15 +69,56 @@ NEG_INF = -1e30
 NO_WINDOW = 1 << 30
 
 
-def _paged_kernel(tables_ref, lens_ref, win_ref, layer_ref, q_ref, k_ref,
-                  v_ref, *rest, bs: int, nb: int, n_rep: int, scale: float,
+# K+V bytes (double-buffered, as laid out in VMEM) the table entries of one
+# grid step of the dense sweep may take: the v5e's scoped VMEM is 16 MiB,
+# and the step's float32 temporaries need the rest
+PAGED_STEP_BYTES = 4 << 20
+
+
+def paged_tiles(nb: int, bs: int, Hkv: int, D: int, itemsize: int) -> int:
+    """Table entries one grid step of :func:`paged_decode_attention` reads:
+    the most, up to 16 and a power of two, whose K and V tiles fit
+    ``PAGED_STEP_BYTES`` double-buffered, and never more than ``nb``."""
+    sub = 8 * (4 // itemsize)                   # sublanes of a packed tile
+    tile = bs * (-(-Hkv // sub) * sub) * (-(-D // 128) * 128) * itemsize
+    t = 16
+    while t > 1 and 4 * t * tile > PAGED_STEP_BYTES:
+        t //= 2
+    while t > 1 and t // 2 >= nb:
+        t //= 2
+    return t
+
+
+def live_entries(n, win, bs: int):
+    """First and last table entries holding a position in ``[n - win, n)``
+    (last = -1 for an empty lane); ``n`` and ``win`` may be scalars of a
+    kernel, traced or host arrays."""
+    return (n - win).clip(0) // bs, (n - 1) // bs
+
+
+def _tree(op, xs):
+    """``op`` folded over ``xs`` pairwise: a reduction over the leading,
+    untiled axis as plain vector ops, with no masking of padded lanes."""
+    while len(xs) > 1:
+        xs = [op(*xs[i:i + 2]) if i + 1 < len(xs) else xs[i]
+              for i in range(0, len(xs), 2)]
+    return xs[0]
+
+
+def _paged_kernel(tables_ref, lens_ref, win_ref, layer_ref, q_ref, *rest,
+                  bs: int, tiles: int, steps: int, n_rep: int, scale: float,
                   splice: bool):
+    k_refs, v_refs = rest[:tiles], rest[tiles:2 * tiles]
+    rest = rest[2 * tiles:]
     if splice:
-        k1_ref, v1_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        k1_ref, v1_ref, o_ref, m_scr, l_scr, acc_scr, keep_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     j = pl.program_id(1)
+    n = lens_ref[b]
+    lo, last = live_entries(n, win_ref[0], bs)
+    rows = tiles * bs
 
     @pl.when(j == 0)
     def _init():
@@ -79,47 +126,62 @@ def _paged_kernel(tables_ref, lens_ref, win_ref, layer_ref, q_ref, k_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)              # (Hq, D)
-    k = k_ref[0, 0, 0].astype(jnp.float32)        # (bs, Hkv, D)
-    v = v_ref[0, 0, 0].astype(jnp.float32)        # (bs, Hkv, D)
-    Hq, D = q.shape
-    Hkv = k.shape[1]
-    pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+    # a step whose entries all lie outside [lo, last] was mapped onto an
+    # entry already fetched and computes nothing
+    live = (j * tiles <= last) & ((j + 1) * tiles > lo)
+    off = n - 1 - j * rows
+
+    def overlay(back: bool):
+        # the current token's K/V row, overlaid at its position in the VMEM
+        # copy of the tile that holds it instead of pre-written into the
+        # arena (the arena write stays one post-scan row per layer).  The
+        # copy's own row is put back after the step: a later step that
+        # maps the same block reads the copy again, unfetched.
+        for t in range(tiles):
+            @pl.when(live & (off >= 0) & (off // bs == t) & (off < rows))
+            def _():
+                for i, (ref, row) in enumerate(((k_refs[t], k1_ref),
+                                                (v_refs[t], v1_ref))):
+                    if back:
+                        ref[0, 0, 0, off % bs] = keep_scr[i]
+                    else:
+                        keep_scr[i] = ref[0, 0, 0, off % bs]
+                        ref[0, 0, 0, off % bs] = row[0]
+
     if splice:
-        # the current token's K/V row, overlaid at its position instead of
-        # pre-written into the arena: the sweep reads live blocks only and
-        # the arena write stays a single post-scan row per layer.  The
-        # mask is built as a (bs, 1, 1) column directly: Mosaic cannot
-        # reshape the (1, bs) position row into one
-        row = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1, 1), 0)
-        here = row == lens_ref[b] - 1
-        k = jnp.where(here, k1_ref[0].astype(jnp.float32)[None], k)
-        v = jnp.where(here, v1_ref[0].astype(jnp.float32)[None], v)
-    kt = jnp.swapaxes(k, 0, 1)                    # (Hkv, bs, D)
-    qh = q.reshape(Hkv, n_rep, D)
-    s = jax.lax.dot_general(qh, kt, (((2,), (2,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32) * scale
-    s = s.reshape(Hq, bs)
-    valid = (pos < lens_ref[b]) & (pos >= lens_ref[b] - win_ref[0])
-    s = jnp.where(valid, s, NEG_INF)
+        overlay(back=False)
 
-    m_prev = m_scr[...]
-    l_prev = l_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])               # (Hq, bs)
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1)
-    m_scr[...] = m_new
-    vt = jnp.swapaxes(v, 0, 1)                    # (Hkv, bs, D)
-    ph = p.reshape(Hkv, n_rep, bs)
-    o = jax.lax.dot_general(ph, vt, (((2,), (1,)), ((0,), (0,))),
-                            preferred_element_type=jnp.float32)
-    acc_scr[...] = acc_scr[...] * corr[:, None] + o.reshape(Hq, D)
+    @pl.when(live)
+    def _step():
+        # the step's entries as one run of tiles * bs rows, scored in the
+        # arena's own (rows, Hkv, D) layout: a multiply-reduce over D per
+        # KV head, no relayout of the tiles.  Entries clamped onto a
+        # neighbour keep their own positions, so the mask drops them.  The
+        # softmax state is held replicated across D, so the probabilities
+        # multiply V with no lane broadcast.
+        k = jnp.concatenate([r[0, 0, 0] for r in k_refs]).astype(jnp.float32)
+        v = jnp.concatenate([r[0, 0, 0] for r in v_refs]).astype(jnp.float32)
+        row = j * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1, 1), 0)
+        valid = (row < n) & (row >= n - win_ref[0])
+        for r in range(n_rep):              # query heads r, r + n_rep, ...
+            q = q_ref[0, r].astype(jnp.float32) * scale      # (Hkv, D)
+            s = k * q[None]
+            s = jnp.broadcast_to(jnp.sum(s, axis=-1, keepdims=True), s.shape)
+            s = jnp.where(valid, s, NEG_INF)                 # (rows, Hkv, D)
+            m_prev = m_scr[r]
+            m_new = jnp.maximum(m_prev, _tree(jnp.maximum, list(s)))
+            p = jnp.exp(s - m_new[None])
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[r] = l_scr[r] * corr + _tree(jnp.add, list(p))
+            m_scr[r] = m_new
+            acc_scr[r] = acc_scr[r] * corr + _tree(jnp.add, list(p * v))
 
-    @pl.when(j == nb - 1)
+    if splice:
+        overlay(back=True)
+
+    @pl.when(j == steps - 1)
     def _finish():
-        o_ref[0] = (acc_scr[...] /
-                    jnp.maximum(l_scr[...], 1e-30)[:, None]
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
                     ).astype(o_ref.dtype)
 
 
@@ -187,9 +249,10 @@ def scatter_kv_rows(k_arena, v_arena, k_rows, v_rows, wbids, offs, *,
       k_rows, v_rows, k_arena, v_arena)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
 def paged_decode_attention(q, k_arena, v_arena, tables, lens, *,
                            window=None, new_kv=None, layer=None,
+                           tiles: int | None = None,
                            interpret: bool | None = None):
     """q: (B, Hq, D); k_arena, v_arena: (num_blocks, bs, Hkv, D), or with
     ``layer`` (may be traced) the whole ``(L, num_blocks, 1, bs, Hkv, D)``
@@ -206,8 +269,19 @@ def paged_decode_attention(q, k_arena, v_arena, tables, lens, *,
     tick never has to pre-write the row into the arena (a functional
     arena-slice update per layer would copy every block, live or not —
     exactly the traffic this kernel exists to avoid).
-    Returns (B, Hq, D) in v_arena.dtype.
+    ``tiles``: table entries a grid step reads (default
+    :func:`paged_tiles`).
+    Returns (B, Hq, D) in v_arena.dtype.  A lane with ``lens == 0``
+    returns zeros.
     ``interpret=None`` auto-detects the backend (Mosaic on TPU only).
+
+    Grid (B, ceil(nb / tiles)): each step reads ``tiles`` table entries
+    of a lane and computes them as one run of rows.  An entry outside the
+    lane's live entries ``[lo, last]`` (before the window's first block,
+    or past the block holding ``lens - 1``) is mapped onto a live entry
+    its tile has fetched already, so the pipeline issues no DMA for it,
+    and a step holding no live entry skips its compute: a lane costs its
+    live blocks, not ``max_len``.
     """
     from repro.kernels.ops import resolve_interpret
     interpret = resolve_interpret(interpret)
@@ -219,41 +293,58 @@ def paged_decode_attention(q, k_arena, v_arena, tables, lens, *,
     bs, Hkv = k_arena.shape[3:5]
     nb = tables.shape[1]
     n_rep = Hq // Hkv
-    scale = D ** -0.5
+    g = tiles or paged_tiles(nb, bs, Hkv, D, k_arena.dtype.itemsize)
+    steps = -(-nb // g)
+    tables = jnp.pad(jnp.asarray(tables, jnp.int32),
+                     ((0, 0), (0, steps * g - nb)))
     if window is None:
         window = NO_WINDOW
     win = jnp.where(jnp.asarray(window, jnp.int32) == 0, NO_WINDOW,
                     jnp.asarray(window, jnp.int32)).reshape(1)
-    row = pl.BlockSpec((1, Hq, D), lambda b, j, t, ln, w, y: (b, 0, 0))
-    blk = pl.BlockSpec((1, 1, 1, bs, Hkv, D),
-                       lambda b, j, t, ln, w, y: (y[0], t[b, j], 0, 0, 0, 0))
-    kv_row = pl.BlockSpec((1, Hkv, D), lambda b, j, t, ln, w, y: (b, 0, 0))
+    # query head h = kv_head * n_rep + r is read as [r, kv_head]
+    qr = q.reshape(B, Hkv, n_rep, D).swapaxes(1, 2)
+
+    def tile(t):
+        # a step outside the lane's live steps reads what its neighbour
+        # inside them reads, and an entry of a live step outside [lo, last]
+        # what this tile read a step earlier (or entry last, or lo): the
+        # pipeline fetches a block only when a tile's block changes
+        def index(b, j, tb, ln, w, y):
+            lo, last = live_entries(ln[b], w[0], bs)
+            last = jnp.maximum(last, 0)
+            js = jnp.minimum(jnp.maximum(j, lo // g), last // g)
+            e = js * g + t
+            e = jnp.where(e > last, jnp.where(js > lo // g, e - g, last), e)
+            return (y[0], tb[b, jnp.maximum(e, lo)], 0, 0, 0, 0)
+        return pl.BlockSpec((1, 1, 1, bs, Hkv, D), index)
+    per_lane = lambda *shape: pl.BlockSpec(
+        (1,) + shape, lambda b, j, tb, ln, w, y: (b,) + (0,) * len(shape))
     splice = new_kv is not None
-    operands = (jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32),
-                win, lyr, q, k_arena, v_arena)
-    in_specs = [row, blk, blk]
+    operands = (tables, jnp.asarray(lens, jnp.int32), win, lyr, qr) \
+        + (k_arena,) * g + (v_arena,) * g
+    in_specs = [per_lane(n_rep, Hkv, D)] + [tile(t) for t in range(g)] * 2
     if splice:
         operands += tuple(new_kv)
-        in_specs += [kv_row, kv_row]
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, bs=bs, nb=nb, n_rep=n_rep,
-                          scale=scale, splice=splice),
+        in_specs += [per_lane(Hkv, D)] * 2
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, bs=bs, tiles=g, steps=steps,
+                          n_rep=n_rep, scale=D ** -0.5, splice=splice),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(B, nb),
+            grid=(B, steps),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, Hq, D),
-                                   lambda b, j, t, ln, w, y: (b, 0, 0)),
+            out_specs=per_lane(n_rep, Hkv, D),
             scratch_shapes=[
-                pltpu.VMEM((Hq,), jnp.float32),      # running max
-                pltpu.VMEM((Hq,), jnp.float32),      # running sum
-                pltpu.VMEM((Hq, D), jnp.float32),    # output accumulator
-            ],
+                pltpu.VMEM((n_rep, Hkv, D), jnp.float32),   # running max
+                pltpu.VMEM((n_rep, Hkv, D), jnp.float32),   # running sum
+                pltpu.VMEM((n_rep, Hkv, D), jnp.float32),   # accumulator
+            ] + splice * [pltpu.VMEM((2, Hkv, D), k_arena.dtype)],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), v_arena.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, n_rep, Hkv, D), v_arena.dtype),
         interpret=interpret,
         name="paged_decode_attention",
     )(*operands)
+    return out.swapaxes(1, 2).reshape(B, Hq, D)
 
 
 # table entries one grid step of the latent sweep reads

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import (axis_if_divisible, batch_spec_axis)
@@ -808,6 +809,34 @@ def decode_moe_work(cfg: LMConfig, lanes: int, active: int) -> dict:
     layers = cfg.n_layers - 1               # layer 0 is dense
     return {"moe_rows": lanes * layers * lm.moe_ffn_decode_rows(cfg, 1),
             "moe_picks": active * cfg.top_k * layers}
+
+
+def decode_attn_work(cfg: LMConfig, lens, bs: int, nb: int,
+                     tiles: int | None = None) -> dict:
+    """Dense paged attention work of one :func:`decode_step_paged` tick
+    under ``backend="pallas"``, counted from shapes and summed over layers:
+    ``attn_blocks_read``, the table entries
+    ``kernels.paged_attn.paged_decode_attention`` computes (whole grid
+    steps of ``tiles`` entries that hold a live one), and
+    ``attn_blocks_live``, the blocks holding a position a lane attends.
+    ``lens``: the tick's (S,) cache lengths, every lane as the kernel sees
+    it (a lane attends ``lens + 1`` rows, inside each layer's window);
+    ``nb``: table entries a lane; ``tiles`` defaults to the kernel's."""
+    from repro.kernels.paged_attn import NO_WINDOW, live_entries, paged_tiles
+    if tiles is None:
+        tiles = paged_tiles(nb, bs, cfg.n_kv_heads, cfg.d_head,
+                            jnp.dtype(cfg.dtype).itemsize)
+    n = np.asarray(lens, np.int64) + 1
+    windows = np.full(cfg.n_layers, NO_WINDOW)
+    if cfg.window:
+        glob = np.asarray(cfg.is_global_layer(np.arange(cfg.n_layers)))
+        windows[~glob] = cfg.window
+    read = live = 0
+    for w, layers in zip(*np.unique(windows, return_counts=True)):
+        lo, last = live_entries(n, w, bs)
+        live += int(layers * np.sum(last - lo + 1))
+        read += int(layers * tiles * np.sum(last // tiles - lo // tiles + 1))
+    return {"attn_blocks_read": read, "attn_blocks_live": live}
 
 
 def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,

@@ -449,7 +449,7 @@ class ContinuousBatcher:
                     self.last_token[slot] = 0
         if sp is not None:
             sp.set(active=self.last_active, finished=len(finished),
-                   **(getattr(self.adapter, "last_moe", None) or {}))
+                   **getattr(self.adapter, "last_work", {}))
         return finished
 
     def _admit(self, finished: list[Request]) -> tuple[int, int]:

@@ -353,12 +353,15 @@ class PagedKVSlotAdapter:
         # compute-skip telemetry (prefill_tokens_* in pool_stats)
         self.prefill_tokens_total = 0
         self.prefill_tokens_skipped_total = 0
-        # MoE decode work of each tick as ``engine.decode_moe_work``
-        # counts it from shapes: per tick in ``last_moe`` (the tick spans'
-        # args), and running totals
+        # decode work of each tick as ``engine.decode_moe_work`` (MoE) and
+        # ``engine.decode_attn_work`` (the dense Pallas attention) count it
+        # from shapes: per tick in ``last_work`` (the tick spans' args),
+        # and running totals
         self.moe_rows_total = 0
         self.moe_picks_total = 0
-        self.last_moe = None
+        self.attn_blocks_read_total = 0
+        self.attn_blocks_live_total = 0
+        self.last_work = {}
         # obs span recorder, wired by the prompt gateways for a run's
         # duration; every use is guarded so a bare adapter makes zero
         # obs calls.  The batcher points the tracer's lane context at the
@@ -995,15 +998,20 @@ class PagedKVSlotAdapter:
             args = (jnp.asarray(self.tables),
                     jnp.asarray(tokens, jnp.int32)[:, None],
                     jnp.asarray(active, bool), jnp.asarray(wbids))
+            work = {}
             if self.cfg.moe is not None:
-                self.last_moe = engine.decode_moe_work(
-                    self.cfg, self.n_slots, int(active.sum()))
-                self.moe_rows_total += self.last_moe["moe_rows"]
-                self.moe_picks_total += self.last_moe["moe_picks"]
+                work.update(engine.decode_moe_work(
+                    self.cfg, self.n_slots, int(active.sum())))
+            if self.kernel and not self.cfg.mla:
+                work.update(engine.decode_attn_work(
+                    self.cfg, self.lens, self.bs, self.nb_max))
+            for key, count in work.items():
+                name = f"{key}_total"
+                setattr(self, name, getattr(self, name) + count)
+            self.last_work = work
             if sp is not None:
                 sp.set(active=int(active.sum()),
-                       live_tokens=int(self.lens[active].sum()),
-                       **(self.last_moe or {}))
+                       live_tokens=int(self.lens[active].sum()), **work)
         with (tr.span("tick.dispatch") if tr is not None else NO_SPAN):
             if meta is None:
                 # no chain shared by >= 2 lanes: degrade to the flat

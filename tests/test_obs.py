@@ -565,6 +565,36 @@ def test_moe_tick_spans_count_expert_rows_and_picks():
     b.tracer = ad.tracer = None
 
 
+def test_pallas_tick_spans_count_attention_blocks():
+    """A Pallas adapter's ``tick`` and ``tick.prepare`` spans carry the
+    table entries the dense paged kernel computes and the live blocks the
+    lanes hold, counted on the host by ``engine.decode_attn_work``, and
+    the adapter keeps their running totals.  Smoke stablelm: 4 layers,
+    4 entries of 4 rows, so one grid step of 4 entries a lane; lanes of
+    6 and 0 cached tokens attend 7 and 1 rows (2 and 1 live blocks), then
+    8 and 1."""
+    cfg, params = _setup()
+    assert cfg.n_layers == 4
+    ad = make_adapter(cfg, params, n_slots=2, max_len=16, paged=True,
+                      block_size=BS, backend="pallas")
+    b = ContinuousBatcher(ad)
+    tracer = obs.Tracer(clock=obs.WallClock())
+    b.tracer = ad.tracer = tracer
+    b.submit(Request(uid=0, prompt=np.arange(6, dtype=np.int32),
+                     max_new_tokens=3))
+    b.step()
+    b.step()
+    want = {"attn_blocks_read": 4 * 2 * 4, "attn_blocks_live": 4 * 3}
+    for name in ("tick", "tick.prepare"):
+        spans = tracer.spans(name)
+        assert len(spans) == 2
+        for sp in spans:
+            assert {k: sp["args"][k] for k in want} == want, name
+            assert "moe_rows" not in sp["args"]
+    assert (ad.attn_blocks_read_total, ad.attn_blocks_live_total) == (64, 24)
+    b.tracer = ad.tracer = None
+
+
 def test_queue_wait_runs_from_submit_when_driven_directly():
     cfg, params = _setup()
     ad = make_adapter(cfg, params, n_slots=1, max_len=16, paged=True,

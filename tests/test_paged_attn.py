@@ -160,6 +160,54 @@ def test_whole_arena_layer_call_matches_per_layer_reference(window):
                                    new_kv=(k1, v1), interpret=True)))
 
 
+@pytest.mark.parametrize("tiles", [1, 4, None], ids=["t1", "t4", "tdef"])
+@pytest.mark.parametrize("splice", [False, True], ids=["plain", "new_kv"])
+@pytest.mark.parametrize("window", [None, 6])
+def test_paged_kernel_reads_only_live_entries(window, splice, tiles):
+    """Every block a table entry outside a lane's live entries ``[lo,
+    last]`` points at holds NaN: before the window's first block, past the
+    block of ``lens - 1``, and the trash block.  The sweep must neither
+    compute nor fetch them — a masked NaN tile would still reach ``p @ V``
+    — so every lane with ``lens > 0`` stays finite and equal to the gather
+    reference over a clean arena.  Lanes end on, inside and just past a
+    block, and (at 4 and the default entries a step) mid-step."""
+    rng = np.random.default_rng(29)
+    nb, bs, Hq, Hkv, D = 6, 4, 4, 2, 16
+    lens = np.asarray([0, 1, bs, bs + 1, nb * bs - 1, nb * bs], np.int32)
+    B = len(lens)
+    num_blocks = 1 + 2 * B * nb
+    q = jnp.asarray(rng.normal(size=(B, Hq, D)), jnp.float32)
+    ka = rng.normal(size=(num_blocks, bs, Hkv, D)).astype(np.float32)
+    va = rng.normal(size=(num_blocks, bs, Hkv, D)).astype(np.float32)
+    # live entries take blocks 1 .. B*nb, every other entry a block of its
+    # own past them; only the live ones keep finite contents
+    tables = np.zeros((B, nb), np.int32)
+    dead = np.ones(num_blocks, bool)
+    for b, n in enumerate(lens):
+        lo = max(n - (window or n), 0) // bs
+        for e in range(nb):
+            live = n > 0 and lo <= e <= (n - 1) // bs
+            tables[b, e] = 1 + b * nb + e + (0 if live else B * nb)
+            dead[tables[b, e]] &= not live
+    tables[0, 0] = 0                           # and the trash block
+    poisoned = lambda a: jnp.asarray(np.where(
+        dead[:, None, None, None], np.nan, a))
+    new_kv = None
+    if splice:
+        new_kv = tuple(jnp.asarray(rng.normal(size=(B, Hkv, D)), jnp.float32)
+                       for _ in range(2))
+    got = paged_decode_attention(q, poisoned(ka), poisoned(va),
+                                 jnp.asarray(tables), jnp.asarray(lens),
+                                 window=window, new_kv=new_kv, tiles=tiles,
+                                 interpret=True)
+    want = attention.attend_decode_paged(
+        q[:, None], jnp.asarray(ka), jnp.asarray(va), jnp.asarray(tables),
+        jnp.asarray(lens), window=window or 0, new_kv=new_kv)[:, 0]
+    got, want = np.asarray(got)[lens > 0], np.asarray(want)[lens > 0]
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
 def test_default_interpret_env_override(monkeypatch):
     """REPRO_KERNELS_INTERPRET forces the mode either way; unset falls
     back to the backend probe — what the CI kernels-interpret leg relies

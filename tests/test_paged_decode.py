@@ -595,3 +595,26 @@ def test_pinned_arena_survives_the_persistent_compile_cache(tmp_path,
                           was[1])
         cc.reset_cache()
 
+
+def test_decode_attn_work_counts_read_and_live_entries():
+    """``engine.decode_attn_work`` on hand-built lengths: each lane attends
+    ``lens + 1`` rows; the live blocks are those rows' blocks inside each
+    layer's window, the read entries whole grid steps of ``tiles`` that
+    hold a live one; both summed over layers."""
+    from repro.serve import engine
+    cfg = configs.config("stablelm_3b")             # 32 layers, no window
+    lens = np.asarray([0, 15, 16, 200, 1023])
+    # rows 1, 16, 17, 201, 1024 -> last live entries 0, 0, 1, 12, 63
+    want = {"attn_blocks_read": 32 * 8 * (1 + 1 + 1 + 2 + 8),
+            "attn_blocks_live": 32 * (1 + 1 + 2 + 13 + 64)}
+    assert engine.decode_attn_work(cfg, lens, 16, 64, tiles=8) == want
+    # the default is the kernel's: 8 entries a step at stablelm's widths
+    assert engine.decode_attn_work(cfg, lens, 16, 64) == want
+    # hymba: layers 0 and 16 global, 30 with a 1024-token window
+    cfg = configs.config("hymba_1_5b")
+    assert (cfg.n_layers, cfg.window, cfg.global_every) == (32, 1024, 16)
+    # 1501 rows: global entries 0-93 (steps of 4: 0-23); windowed from
+    # row 477, entries 29-93 (steps 7-23)
+    assert engine.decode_attn_work(cfg, [1500], 16, 128, tiles=4) == {
+        "attn_blocks_read": 2 * 4 * 24 + 30 * 4 * 17,
+        "attn_blocks_live": 2 * 94 + 30 * 65}

@@ -70,6 +70,32 @@ def test_paged_decode_kernel_with_new_row_compiles(one_chip, width):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_paged_decode_kernel_compiles_at_the_served_tick(one_chip, width):
+    """The whole-arena ``layer=`` call at the served tick's shape (32
+    layers, 16 lanes x 64 entries of 16, 448 blocks) over the arena held
+    row-major, as the adapter holds it: the default entries a grid step
+    fit the v5e's VMEM and lower, with no relayout of the arena around
+    the kernel.  Hymba's GQA width runs a traced sliding window."""
+    Hq, Hkv, D = WIDTHS[width]
+    bf, i32 = jnp.bfloat16, jnp.int32
+    shape = jax.ShapeDtypeStruct((32, TICK_BLOCKS, 1, BS, Hkv, D), bf)
+    fmt = paged.arena_formats({"k": shape}, "pallas", {"k": one_chip})["k"]
+    arena = jax.ShapeDtypeStruct(shape.shape, bf, sharding=fmt)
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    window = width == "hymba-1.5b"
+    tick = jax.jit(
+        lambda q, k, v, t, ln, k1, v1, y, w: pk.paged_decode_attention(
+            q, k, v, t, ln, window=w if window else None, new_kv=(k1, v1),
+            layer=y, interpret=False)).lower(
+        sds((TICK_LANES, Hq, D), bf), arena, arena,
+        sds((TICK_LANES, TICK_NB), i32), sds((TICK_LANES,), i32),
+        sds((TICK_LANES, Hkv, D), bf), sds((TICK_LANES, Hkv, D), bf),
+        sds((), i32), sds((), i32)).compile()
+    assert "tpu_custom_call" in tick.as_text()
+    assert tick.memory_analysis().temp_size_in_bytes < 1e6
+
+
 def test_scatter_kv_rows_compiles(one_chip):
     Hq, Hkv, D = WIDTHS["stablelm-3b"]
     arena = ((32, BLOCKS, 1, BS, Hkv, D), jnp.bfloat16)
