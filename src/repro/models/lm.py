@@ -655,7 +655,7 @@ def mla_unabsorb(cfg: LMConfig, p, o_lat):
 
 
 def attn_decode_paged_mla(cfg: LMConfig, p, x1, lat_blocks, tables, pos, *,
-                          backend=None, layer=None, interpret=None):
+                          backend, layer=None, interpret=None):
     """One-token latent attention for a batch of slots in its absorbed
     form, reading the latent rows in place from the paged arena.
 
@@ -675,9 +675,30 @@ def attn_decode_paged_mla(cfg: LMConfig, p, x1, lat_blocks, tables, pos, *,
     return mla_unabsorb(cfg, p, o), row[:, 0]
 
 
+def attn_decode_qkv(cfg: LMConfig, p, x1, pos):
+    """One decode token's attention projections: x1 (B, 1, d) at positions
+    ``pos`` (B,).  Returns q (B, 1, H, Dh) and the post-RoPE k1 beside v1
+    (B, 1, Hkv, Dh), the row the token owes its cache."""
+    B = x1.shape[0]
+    q = _proj(x1, p["wq"], p.get("bq")).reshape(B, 1, cfg.n_heads, cfg.d_head)
+    k1 = _proj(x1, p["wk"]).reshape(B, 1, cfg.n_kv_heads, cfg.d_head)
+    v1 = _proj(x1, p["wv"], p.get("bv")).reshape(B, 1, cfg.n_kv_heads,
+                                                 cfg.d_head)
+    if cfg.pos_embedding == "rope":
+        posb = pos[:, None]
+        q = rope.apply_rope(q, posb, cfg.rope_theta)
+        k1 = rope.apply_rope(k1, posb, cfg.rope_theta)
+    return q, k1, v1
+
+
+def attn_decode_out(p, o):
+    """A decode attention's heads o (B, 1, H, Dh) through ``wo``: (B, 1, d)."""
+    return _proj(o.reshape(o.shape[0], 1, -1), p["wo"], p.get("bo"))
+
+
 def attn_decode_paged(cfg: LMConfig, p, x1, k_blocks, v_blocks, tables, pos,
-                      *, window=0, kernel=None, interpret=None,
-                      scales=None, backend=None, cascade=None, layer=None):
+                      *, backend, window=0, interpret=None, scales=None,
+                      cascade=None, layer=None):
     """One-token decode attention for a batch of slots, reading K/V in
     place from one layer's slice of the paged block arena.
 
@@ -690,70 +711,53 @@ def attn_decode_paged(cfg: LMConfig, p, x1, k_blocks, v_blocks, tables, pos,
     the tick's only persistent sequence-axis write.
 
     The new token's row has not reached the arena yet when attention runs,
-    so both paths overlay it at position ``pos`` functionally: the XLA
+    so every backend overlays it at position ``pos`` functionally: the XLA
     reference (:func:`nn.attention.attend_decode_paged`) splices it into
     the gathered view — bitwise-identical to the dense
     ``engine.decode_step`` attention, which the paged parity suite pins —
-    and ``kernel=True`` hands it to ``kernels.paged_attn`` as a row
-    operand overlaid in VMEM (an arena-slice update here would copy every
-    block of the layer, live or not — the very traffic the kernel's
-    per-block DMA exists to avoid).
+    and ``"pallas"`` hands it to ``kernels.paged_attn`` as a row operand
+    overlaid in VMEM (an arena-slice update here would copy every block of
+    the layer, live or not — the very traffic the kernel's per-block DMA
+    exists to avoid).
 
     ``scales``: optional (k_scale_blocks, v_scale_blocks) — one layer's
     slice of the int8 ``kv_quant`` scale arenas.  The new row is quantized
-    post-RoPE (exactly :func:`engine._decode_attn`'s write) and attention
-    reads the dequantized gathered view with the *dequantized-quantized*
-    row spliced in — what the dense quant tick sees after its write — so
-    in-place quant decode stays bitwise against the gather-tick oracle.
-    Returns (out, k1q, v1q, k1_scale, v1_scale) in that case; the Pallas
-    kernel path does not cover the quant layout (assert).
+    post-RoPE (exactly the dense cache's write) and attention reads the
+    dequantized gathered view with the *dequantized-quantized* row spliced
+    in — what the dense quant tick sees after its write — so in-place
+    quant decode stays bitwise against the gather-tick oracle.  Returns
+    (out, k1q, v1q, k1_scale, v1_scale) in that case; only ``"xla"`` covers
+    the quant layout (assert).
 
     ``backend`` ("xla" | "pallas" | "cascade", plus ``cascade=`` group
     metadata for the last — see :mod:`repro.serve.backend`) is the read-
-    path dispatch forwarded to :func:`nn.attention.attend_decode_paged`;
-    ``kernel=True`` survives as the deprecated alias for "pallas".
+    path dispatch forwarded to :func:`nn.attention.attend_decode_paged`.
 
     ``layer`` (``"pallas"`` only): ``k_blocks``/``v_blocks`` are the whole
     layer-leading ``(L, num_blocks, 1, bs, Hkv, Dh)`` arena, handed to the
     kernel as it lies in HBM, and the kernel reads this layer of it.
     """
-    if backend is None:
-        backend = "pallas" if kernel else "xla"
-    B = x1.shape[0]
-    q = _proj(x1, p["wq"], p.get("bq")).reshape(B, 1, cfg.n_heads, cfg.d_head)
-    k1 = _proj(x1, p["wk"]).reshape(B, 1, cfg.n_kv_heads, cfg.d_head)
-    v1 = _proj(x1, p["wv"], p.get("bv")).reshape(B, 1, cfg.n_kv_heads,
-                                                 cfg.d_head)
-    if cfg.pos_embedding == "rope":
-        posb = pos[:, None]
-        q = rope.apply_rope(q, posb, cfg.rope_theta)
-        k1 = rope.apply_rope(k1, posb, cfg.rope_theta)
+    q, k1, v1 = attn_decode_qkv(cfg, p, x1, pos)
     if layer is None:
         kb, vb = k_blocks[:, 0], v_blocks[:, 0]  # (num_blocks, bs, Hkv, Dh)
     else:
         kb, vb = k_blocks, v_blocks
+    new_kv, scale_rows = (k1[:, 0], v1[:, 0]), ()
     if scales is not None:
         assert backend == "xla", \
             "only the XLA reference covers the int8 kv_quant layout"
         from repro.serve import kvquant
-        k1q, k1s = kvquant.quantize(k1)
-        v1q, v1s = kvquant.quantize(v1)
-        o = attention.attend_decode_paged(
-            q, kb, vb, tables, pos + 1, window=window,
-            new_kv=(kvquant.dequantize(k1q, k1s, cfg.dtype)[:, 0],
-                    kvquant.dequantize(v1q, v1s, cfg.dtype)[:, 0]),
-            scales=(scales[0][:, 0], scales[1][:, 0]), out_dtype=cfg.dtype)
-        out = _proj(o.reshape(B, 1, cfg.n_heads * cfg.d_head), p["wo"],
-                    p.get("bo"))
-        return out, k1q[:, 0], v1q[:, 0], k1s[:, 0], v1s[:, 0]
+        (k1, k1s), (v1, v1s) = kvquant.quantize(k1), kvquant.quantize(v1)
+        new_kv = (kvquant.dequantize(k1, k1s, cfg.dtype)[:, 0],
+                  kvquant.dequantize(v1, v1s, cfg.dtype)[:, 0])
+        scales = (scales[0][:, 0], scales[1][:, 0])
+        scale_rows = (k1s[:, 0], v1s[:, 0])
     o = attention.attend_decode_paged(q, kb, vb, tables, pos + 1,
-                                      window=window,
-                                      new_kv=(k1[:, 0], v1[:, 0]),
+                                      window=window, new_kv=new_kv,
+                                      scales=scales, out_dtype=cfg.dtype,
                                       backend=backend, cascade=cascade,
                                       layer=layer, interpret=interpret)
-    out = _proj(o.reshape(B, 1, cfg.n_heads * cfg.d_head), p["wo"],
-                p.get("bo"))
-    return out, k1[:, 0], v1[:, 0]
+    return (attn_decode_out(p, o), k1[:, 0], v1[:, 0]) + scale_rows
 
 
 def _mlp_apply(cfg: LMConfig, p, x, kind=None):
@@ -1122,19 +1126,18 @@ def forward(cfg: LMConfig, params, batch) -> tuple[jax.Array, dict]:
 
 
 def moe_ffn_decode(cfg: LMConfig, moe_params, z):
-    """MoE FFN for a (B, 1, d) decode activation: a single dispatch group of
-    B tokens (capacity stays tiny at decode batch sizes)."""
-    B = z.shape[0]
-    m = dataclasses.replace(cfg.moe, group_size=B)
-    return moe_ffn(z.reshape(1, B, -1), moe_params, m)[0].reshape(B, 1, -1), \
-        jnp.float32(0.0)
+    """MoE FFN for a (B, 1, d) decode activation.  Every lane's token is a
+    dispatch group of its own, so a lane's output never depends on the
+    other lanes of its batch (the paged tick's parity contract)."""
+    m = dataclasses.replace(cfg.moe, group_size=1)
+    return jax.vmap(lambda zi: moe_ffn(zi[None], moe_params, m)[0][0])(z)
 
 
 def moe_ffn_decode_rows(cfg: LMConfig, tokens: int) -> int:
     """Rows the expert FFN of :func:`moe_ffn_decode` computes for a
-    ``(tokens, 1, d)`` activation: one dispatch group of ``tokens``."""
-    return expert_rows(dataclasses.replace(cfg.moe, group_size=tokens),
-                       tokens)
+    ``(tokens, 1, d)`` activation: ``tokens`` dispatch groups of one."""
+    return tokens * expert_rows(dataclasses.replace(cfg.moe, group_size=1),
+                                1)
 
 
 def chunked_xent(cfg: LMConfig, x, head, labels):

@@ -25,10 +25,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import (axis_if_divisible, batch_spec_axis)
 from repro.models import lm
-from repro.models.lm import (LMConfig, _attn_apply, _causal_conv, _maybe_remat,
-                             _mlp_apply, _norm_apply, _proj, _sinusoidal,
-                             _token_shift, layer_window)
-from repro.nn import attention, rope, ssm
+from repro.models.lm import (LMConfig, _causal_conv, _maybe_remat, _mlp_apply,
+                             _norm_apply, _proj, _sinusoidal, layer_window)
+from repro.nn import attention, ssm
 from repro.serve import kvquant
 
 
@@ -346,11 +345,17 @@ def prefill(cfg: LMConfig, params, batch):
         cache["k"], cache["k_scale"] = kvquant.quantize(cache["k"])
         cache["v"], cache["v_scale"] = kvquant.quantize(cache["v"])
 
-    x = _norm_apply(cfg, params["final_norm"], x[:, -1:])
+    return cache, _logits(cfg, params, x[:, -1:])
+
+
+def _logits(cfg: LMConfig, params, x):
+    """The final norm and the tied or untied head of (B, 1, d) activations:
+    float32 logits (B, vocab_padded)."""
+    x = _norm_apply(cfg, params["final_norm"], x)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", x, head,
                         preferred_element_type=jnp.float32)
-    return cache, logits[:, 0]
+    return logits[:, 0]
 
 
 def _prepend_layer(first, rest):
@@ -535,154 +540,114 @@ def prefill_chunked(cfg: LMConfig, params, batch, cache, q_offset: int):
         new_cache["k"], new_cache["v"] = kvs
         new_cache["xk"], new_cache["xv"] = cache["xk"], cache["xv"]
 
-    x = _norm_apply(cfg, params["final_norm"], x[:, -1:])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head,
-                        preferred_element_type=jnp.float32)
-    return new_cache, logits[:, 0]
+    return new_cache, _logits(cfg, params, x[:, -1:])
 
 
 # ==========================================================================
-# Decode (one token).
+# Decode (one token): one layer stack, two cache plumbings.
 # ==========================================================================
 
-def _decode_attn(cfg, p, x1, cache_k, cache_v, pos, *, window=0,
-                 scales=None):
-    """x1: (B,1,d).  Updates cache at ``pos`` and attends.
-
-    ``scales``: (k_scale, v_scale) when the cache is int8-quantized
-    (cfg.kv_quant) — writes quantize, reads dequantize (fused into the
-    attention einsum's input).  Returns (out, new_k, new_v, new_scales).
-    """
-    B = x1.shape[0]
-    q = _proj(x1, p["wq"], p.get("bq")).reshape(B, 1, cfg.n_heads, cfg.d_head)
-    k1 = _proj(x1, p["wk"]).reshape(B, 1, cfg.n_kv_heads, cfg.d_head)
-    v1 = _proj(x1, p["wv"], p.get("bv")).reshape(B, 1, cfg.n_kv_heads,
-                                                 cfg.d_head)
-    if cfg.pos_embedding == "rope":
-        posb = jnp.broadcast_to(pos[None], (B, 1))
-        q = rope.apply_rope(q, posb, cfg.rope_theta)
-        k1 = rope.apply_rope(k1, posb, cfg.rope_theta)
-    if scales is not None:
-        ks, vs = scales
-        k1q, k1s = kvquant.quantize(k1)
-        v1q, v1s = kvquant.quantize(v1)
-        cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k1q, pos, 1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v1q, pos, 1)
-        ks = jax.lax.dynamic_update_slice_in_dim(ks, k1s, pos, 1)
-        vs = jax.lax.dynamic_update_slice_in_dim(vs, v1s, pos, 1)
-        k_full = kvquant.dequantize(cache_k, ks, cfg.dtype)
-        v_full = kvquant.dequantize(cache_v, vs, cfg.dtype)
-        o = attention.attend_decode(q, k_full, v_full, pos + 1,
-                                    window=window)
-        out = _proj(o.reshape(B, 1, cfg.n_heads * cfg.d_head), p["wo"],
-                    p.get("bo"))
-        return out, cache_k, cache_v, (ks, vs)
-    cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k1, pos, axis=1)
-    cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v1, pos, axis=1)
-    o = attention.attend_decode(q, cache_k, cache_v, pos + 1, window=window)
-    out = _proj(o.reshape(B, 1, cfg.n_heads * cfg.d_head), p["wo"],
-                p.get("bo"))
-    return out, cache_k, cache_v, None
-
-
-def _decode_attn_mla(cfg, p, x1, cache_lat, pos):
-    """Latent attention's :func:`_decode_attn`: x1 (B,1,d); writes the new
-    latent row at ``pos`` and attends in the absorbed form.  Returns
-    (out, new cache_lat)."""
-    B = x1.shape[0]
-    q, row = lm.mla_project(cfg, p, x1, jnp.broadcast_to(pos[None], (B, 1)))
-    cache_lat = jax.lax.dynamic_update_slice_in_dim(cache_lat, row, pos,
-                                                    axis=1)
-    o = attention.attend_latent(lm.mla_absorb(cfg, p, q[:, 0]), cache_lat,
-                                jnp.broadcast_to(pos + 1, (B,)),
-                                scale=lm.mla_scale(cfg), dv=cfg.kv_lora_rank)
-    return lm.mla_unabsorb(cfg, p, o), cache_lat
-
-
-def decode_step(cfg: LMConfig, params, cache, tokens):
-    """tokens: (B, 1).  Returns (new_cache, logits (B, vocab_padded))."""
-    B = tokens.shape[0]
-    pos = cache["len"]
+def _decode_embed(cfg: LMConfig, params, tokens, pos):
+    """tokens (B, 1) at positions ``pos`` (B,): the layer stack's input."""
     x = params["embed"][tokens]
     if cfg.pos_embedding == "sinusoidal":
         i = jnp.arange(0, cfg.d_model, 2, dtype=jnp.float32)
-        ang = pos.astype(jnp.float32) / jnp.power(10000.0, i / cfg.d_model)
-        pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)])[None, None, :]
+        ang = pos[:, None].astype(jnp.float32) / \
+            jnp.power(10000.0, i / cfg.d_model)
+        pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], -1)[:, None, :]
         x = x + pe.astype(x.dtype)
+    return x
+
+
+def _kv_keys(cfg: LMConfig) -> tuple:
+    """The attention cache keys a decode layer reads and writes, in the
+    order :func:`_decode_layers` carries them: the latent; vlm's self k/v
+    and its cross layers' self k/v; else k/v, plus the scale rows under the
+    int8 ``kv_quant`` layout (which encdec ignores)."""
+    if cfg.mla:
+        return ("latent",)
+    if cfg.family == "vlm":
+        return ("k", "v", "kx_self", "vx_self")
+    quant = cfg.kv_quant and cfg.family != "encdec"
+    return ("k", "v") + (("k_scale", "v_scale") if quant else ())
+
+
+# the non-sequence state a family's decode layer reads: hybrid's conv taps
+# and SSM state (which it also writes), encdec/vlm's fixed cross K/V
+_STATE_KEYS = {"hybrid": ("conv", "ssm"), "encdec": ("xk", "xv"),
+               "vlm": ("xk", "xv")}
+
+
+def _decode_layers(cfg: LMConfig, params, x, attend, kv_xs, state_xs):
+    """Every family's decode layer stack for one token a lane, x (B, 1, d).
+
+    The dense and the paged tick run this same stack; they differ only in
+    how a layer reads and writes its attention cache, which ``attend``
+    carries: ``attend(lp, z, kv, layer, window)`` -> ``(out (B, 1, d),
+    rows)`` for attention params ``lp``, normed input ``z``, this layer's
+    slices ``kv`` of ``kv_xs``, its index ``layer`` in the whole stack
+    (None inside vlm's groups) and its window.  ``rows`` is what the layer
+    owes its cache, stacked over layers by the scan.
+
+    kv_xs     the attention caches in :func:`_kv_keys` order, with their
+              leading layer axes, or () when ``attend`` reads a whole arena
+              at ``layer`` itself.
+    state_xs  the :data:`_STATE_KEYS` of the family, layer-leading.
+
+    MoE layers route through :func:`lm.moe_ffn_decode`: every lane is a
+    dispatch group of its own token.
+
+    Returns (x, rows per cache key, hybrid's new (conv, ssm) or ()).
+    """
     fam = cfg.family
-    new_cache = dict(cache)
-    new_cache["len"] = pos + 1
+
+    def mlp(lp, x):
+        return x + _mlp_apply(cfg, lp["mlp"], _norm_apply(cfg, lp["ln2"], x))
+
+    def self_attn(lp, x, kv, layer, window=0):
+        h, rows = attend(lp["attn"], _norm_apply(cfg, lp["ln1"], x), kv,
+                         layer, window)
+        return x + h, rows
+
+    def cross(lp, x, xk, xv):
+        """Gated cross-attention over the fixed encoder or vision K/V,
+        then the MLP."""
+        q = _proj(_norm_apply(cfg, lp["ln_x"], x), lp["xattn"]["wq"],
+                  lp["xattn"].get("bq")).reshape(
+            x.shape[0], 1, cfg.n_heads, cfg.d_head)
+        o = attention.attend_decode(q, xk, xv, xk.shape[1])
+        hx = lm.attn_decode_out(lp["xattn"], o)
+        gate = jnp.tanh(lp["gate_attn"].astype(jnp.float32)).astype(x.dtype)
+        return mlp(lp, x + gate * hx)
 
     if fam in ("decoder", "moe"):
-        blocks = params["blocks"]
-        L = cfg.n_layers - (1 if fam == "moe" else 0)
-        quant = cfg.kv_quant
-        keys = ("latent",) if cfg.mla else \
-            ("k", "v") + (("k_scale", "v_scale") if quant else ())
+        first = 1 if fam == "moe" else 0    # moe's layer 0 is dense0
 
-        def attend(lp, x, caches, window=0):
-            """Returns (out, this layer's updated caches, in ``keys``
-            order)."""
-            z = _norm_apply(cfg, lp["ln1"], x)
-            if cfg.mla:
-                h, lat = _decode_attn_mla(cfg, lp["attn"], z, caches[0], pos)
-                return h, (lat,)
-            h, ck, cv, sc = _decode_attn(
-                cfg, lp["attn"], z, caches[0], caches[1], pos, window=window,
-                scales=tuple(caches[2:]) if quant else None)
-            return h, (ck, cv) + (sc if quant else ())
+        def body(x, inp):
+            lp, kv, idx = inp
+            x, rows = self_attn(lp, x, kv, idx + first,
+                                layer_window(cfg, idx))
+            if fam == "decoder":
+                return mlp(lp, x), rows
+            return x + lm.moe_ffn_decode(
+                cfg, lp["moe"], _norm_apply(cfg, lp["ln2"], x)), rows
 
         if fam == "moe":
             p0 = jax.tree.map(lambda a: a[0], params["dense0"])
-            h, outs0 = attend(p0, x, [cache[key][0] for key in keys])
-            x = x + h
-            x = x + _mlp_apply(cfg, p0["mlp"], _norm_apply(cfg, p0["ln2"], x))
-
-        def body(x, inp):
-            lp, caches, idx = inp
-            h, outc = attend(lp, x, caches, window=layer_window(cfg, idx))
-            x = x + h
-            z = _norm_apply(cfg, lp["ln2"], x)
-            if fam == "moe":
-                y, _ = lm.moe_ffn_decode(cfg, lp["moe"], z)
-            else:
-                y = _mlp_apply(cfg, lp["mlp"], z)
-            return x + y, outc
-
-        off = slice(1, None) if fam == "moe" else slice(None)
-        x, outs = jax.lax.scan(
-            body, x, (blocks, tuple(cache[key][off] for key in keys),
-                      jnp.arange(L, dtype=jnp.int32)))
+            x, rows0 = self_attn(p0, x, tuple(a[0] for a in kv_xs), 0)
+            x = mlp(p0, x)
+        x, rows = jax.lax.scan(
+            body, x, (params["blocks"], tuple(a[first:] for a in kv_xs),
+                      jnp.arange(cfg.n_layers - first, dtype=jnp.int32)))
         if fam == "moe":
-            outs = _prepend_layer(outs0, outs)
-        new_cache.update(zip(keys, outs))
+            rows = _prepend_layer(rows0, rows)
+        return x, rows, ()
 
-    elif fam == "rwkv":
+    if fam == "hybrid":
         def body(x, inp):
-            lp, wkv_st, sh1, sh2 = inp
-            st = {"wkv": wkv_st, "shift1": sh1, "shift2": sh2}
-            x, st = lm.rwkv_block(cfg, lp, x, st)
-            return x, (st["wkv"], st["shift1"], st["shift2"])
-        x, (wkv, s1, s2) = jax.lax.scan(
-            body, x, (params["blocks"], cache["wkv"], cache["shift1"],
-                      cache["shift2"]))
-        new_cache.update(wkv=wkv, shift1=s1, shift2=s2)
-
-    elif fam == "hybrid":
-        quant = cfg.kv_quant
-
-        def body(x, inp):
-            if quant:
-                lp, ck, cv, ks_, vs_, conv_st, ssm_st, idx = inp
-                sc_in = (ks_, vs_)
-            else:
-                lp, ck, cv, conv_st, ssm_st, idx = inp
-                sc_in = None
+            lp, kv, (conv_st, ssm_st), idx = inp
             z = _norm_apply(cfg, lp["ln1"], x)
-            att, ck, cv, sc = _decode_attn(cfg, lp["attn"], z, ck, cv, pos,
-                                           window=layer_window(cfg, idx),
-                                           scales=sc_in)
+            att, rows = attend(lp["attn"], z, kv, idx, layer_window(cfg, idx))
             xz = _proj(z, lp["in_proj"])
             xm, gate = jnp.split(xz, 2, axis=-1)
             xm, conv_st = _causal_conv(xm, lp["conv_w"], conv_st)
@@ -705,95 +670,107 @@ def decode_step(cfg: LMConfig, params, cache, tokens):
                                            ).astype(jnp.float32)
                      + beta[1] * _norm_apply(cfg, lp["norm_ssm"], y
                                              ).astype(jnp.float32)) * 0.5
-            x = x + mixed.astype(x.dtype)
-            x = x + _mlp_apply(cfg, lp["mlp"], _norm_apply(cfg, lp["ln2"], x))
-            outc = (ck, cv) + (sc if quant else ()) + (conv_st, ssm_st)
-            return x, outc
+            x = mlp(lp, x + mixed.astype(x.dtype))
+            return x, (rows, (conv_st, ssm_st))
 
-        xs_in = (params["blocks"], cache["k"], cache["v"])
-        if quant:
-            xs_in += (cache["k_scale"], cache["v_scale"])
-        xs_in += (cache["conv"], cache["ssm"],
-                  jnp.arange(cfg.n_layers, dtype=jnp.int32))
-        x, outs = jax.lax.scan(body, x, xs_in)
-        if quant:
-            ks, vs, kss, vss, conv, ssm_s = outs
-            new_cache.update(k=ks, v=vs, k_scale=kss, v_scale=vss,
-                             conv=conv, ssm=ssm_s)
-        else:
-            ks, vs, conv, ssm_s = outs
-            new_cache.update(k=ks, v=vs, conv=conv, ssm=ssm_s)
+        x, (rows, state) = jax.lax.scan(
+            body, x, (params["blocks"], kv_xs, state_xs,
+                      jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        return x, rows, state
 
-    elif fam == "encdec":
+    if fam == "encdec":
         def body(x, inp):
-            lp, ck, cv, xk, xv = inp
-            h, ck, cv, _ = _decode_attn(cfg, lp["attn"],
-                                        _norm_apply(cfg, lp["ln1"], x),
-                                        ck, cv, pos)
-            x = x + h
-            q = _proj(_norm_apply(cfg, lp["ln_x"], x), lp["xattn"]["wq"],
-                      lp["xattn"].get("bq")).reshape(
-                x.shape[0], 1, cfg.n_heads, cfg.d_head)
-            o = attention.attend_decode(q, xk, xv, xk.shape[1])
-            hx = _proj(o.reshape(x.shape[0], 1, -1), lp["xattn"]["wo"],
-                       lp["xattn"].get("bo"))
-            gate = jnp.tanh(lp["gate_attn"].astype(jnp.float32)).astype(x.dtype)
-            x = x + gate * hx
-            x = x + _mlp_apply(cfg, lp["mlp"], _norm_apply(cfg, lp["ln2"], x))
-            return x, (ck, cv)
-        x, (ks, vs) = jax.lax.scan(
-            body, x, (params["dec_blocks"], cache["k"], cache["v"],
-                      cache["xk"], cache["xv"]))
-        new_cache.update(k=ks, v=vs)
+            lp, kv, (xk, xv), idx = inp
+            x, rows = self_attn(lp, x, kv, idx)
+            return cross(lp, x, xk, xv), rows
 
-    elif fam == "vlm":
-        k_ = cfg.cross_every
-        G = cfg.n_layers // k_
-        self_pp = jax.tree.map(
-            lambda a: a.reshape((G, k_ - 1) + a.shape[1:]), params["blocks"])
+        x, rows = jax.lax.scan(
+            body, x, (params["dec_blocks"], kv_xs, state_xs,
+                      jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        return x, rows, ()
 
-        def group(x, inp):
-            self_p, cross_p, ck, cv, ckx, cvx, xk, xv = inp
+    assert fam == "vlm", fam
+    k_ = cfg.cross_every
+    G = cfg.n_layers // k_
+    self_pp = jax.tree.map(
+        lambda a: a.reshape((G, k_ - 1) + a.shape[1:]), params["blocks"])
 
-            def inner(x, sinp):
-                lp, ck_i, cv_i = sinp
-                h, ck_i, cv_i, _ = _decode_attn(
-                    cfg, lp["attn"], _norm_apply(cfg, lp["ln1"], x),
-                    ck_i, cv_i, pos)
-                x = x + h
-                x = x + _mlp_apply(cfg, lp["mlp"],
-                                   _norm_apply(cfg, lp["ln2"], x))
-                return x, (ck_i, cv_i)
-            x, (ck, cv) = jax.lax.scan(inner, x, (self_p, ck, cv))
-            h, ckx, cvx, _ = _decode_attn(cfg, cross_p["attn"],
-                                          _norm_apply(cfg, cross_p["ln1"], x),
-                                          ckx, cvx, pos)
-            x = x + h
-            q = _proj(_norm_apply(cfg, cross_p["ln_x"], x),
-                      cross_p["xattn"]["wq"]).reshape(
-                x.shape[0], 1, cfg.n_heads, cfg.d_head)
-            o = attention.attend_decode(q, xk, xv, xk.shape[1])
-            hx = _proj(o.reshape(x.shape[0], 1, -1), cross_p["xattn"]["wo"])
-            gate = jnp.tanh(cross_p["gate_attn"].astype(jnp.float32)
-                            ).astype(x.dtype)
-            x = x + gate * hx
-            x = x + _mlp_apply(cfg, cross_p["mlp"],
-                               _norm_apply(cfg, cross_p["ln2"], x))
-            return x, (ck, cv, ckx, cvx)
+    def group(x, inp):
+        """k-1 self layers, then the cross layer."""
+        self_p, cross_p, (k, v, kx, vx), (xk, xv) = inp
+        x, self_rows = jax.lax.scan(
+            lambda x, sinp: self_attn(sinp[0], x, sinp[1:], None), x,
+            (self_p, k, v))
+        x, x_rows = self_attn(cross_p, x, (kx, vx), None)
+        return cross(cross_p, x, xk, xv), self_rows + x_rows
 
-        x, (ks, vs, kxs, vxs) = jax.lax.scan(
-            group, x, (self_pp, params["cross_blocks"], cache["k"],
-                       cache["v"], cache["kx_self"], cache["vx_self"],
-                       cache["xk"], cache["xv"]))
-        new_cache.update(k=ks, v=vs, kx_self=kxs, vx_self=vxs)
+    x, rows = jax.lax.scan(
+        group, x, (self_pp, params["cross_blocks"], kv_xs, state_xs))
+    return x, rows, ()
+
+
+def _dense_attend(cfg: LMConfig, pos):
+    """The dense cache's ``attend`` for :func:`_decode_layers`, at the one
+    position ``pos`` (scalar) every lane writes: the layer's new K/V row
+    (int8 with its scale rows under ``kv_quant``) or latent row lands in
+    its per-layer cache at ``pos``, and the layer attends over the cache.
+    Returns (out, the layer's updated caches)."""
+    def attend(p, z, caches, layer, window=0):
+        lens = jnp.broadcast_to(pos, z.shape[:1])
+        write = lambda c, r: jax.lax.dynamic_update_slice_in_dim(c, r, pos,
+                                                                 axis=1)
+        if cfg.mla:
+            q, row = lm.mla_project(cfg, p, z, lens[:, None])
+            lat = write(caches[0], row)
+            o = attention.attend_latent(lm.mla_absorb(cfg, p, q[:, 0]), lat,
+                                        lens + 1, scale=lm.mla_scale(cfg),
+                                        dv=cfg.kv_lora_rank)
+            return lm.mla_unabsorb(cfg, p, o), (lat,)
+        q, k1, v1 = lm.attn_decode_qkv(cfg, p, z, lens)
+        quant = len(caches) == 4
+        rows = (k1, v1)
+        if quant:
+            (k1, ks), (v1, vs) = kvquant.quantize(k1), kvquant.quantize(v1)
+            rows = (k1, v1, ks, vs)
+        caches = tuple(map(write, caches, rows))
+        k, v = caches[:2]
+        if quant:
+            k = kvquant.dequantize(k, caches[2], cfg.dtype)
+            v = kvquant.dequantize(v, caches[3], cfg.dtype)
+        o = attention.attend_decode(q, k, v, pos + 1, window=window)
+        return lm.attn_decode_out(p, o), caches
+    return attend
+
+
+def decode_step(cfg: LMConfig, params, cache, tokens):
+    """tokens: (B, 1), every lane at the one position ``cache["len"]``.
+    Returns (new_cache, logits (B, vocab_padded))."""
+    pos = cache["len"]
+    x = _decode_embed(cfg, params, tokens,
+                      jnp.broadcast_to(pos, tokens.shape[:1]))
+    fam = cfg.family
+    new_cache = dict(cache)
+    new_cache["len"] = pos + 1
+
+    if fam == "rwkv":       # recurrent state only: no attention cache
+        def body(x, inp):
+            lp, wkv_st, sh1, sh2 = inp
+            st = {"wkv": wkv_st, "shift1": sh1, "shift2": sh2}
+            x, st = lm.rwkv_block(cfg, lp, x, st)
+            return x, (st["wkv"], st["shift1"], st["shift2"])
+        x, (wkv, s1, s2) = jax.lax.scan(
+            body, x, (params["blocks"], cache["wkv"], cache["shift1"],
+                      cache["shift2"]))
+        new_cache.update(wkv=wkv, shift1=s1, shift2=s2)
     else:
-        raise ValueError(fam)
-
-    x = _norm_apply(cfg, params["final_norm"], x)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head,
-                        preferred_element_type=jnp.float32)
-    return new_cache, logits[:, 0]
+        keys, state_keys = _kv_keys(cfg), _STATE_KEYS.get(fam, ())
+        x, rows, state = _decode_layers(
+            cfg, params, x, _dense_attend(cfg, pos),
+            tuple(cache[key] for key in keys),
+            tuple(cache[key] for key in state_keys))
+        new_cache.update(zip(keys, rows))
+        new_cache.update(zip(state_keys, state))
+    return new_cache, _logits(cfg, params, x)
 
 
 # ==========================================================================
@@ -807,7 +784,7 @@ def decode_moe_work(cfg: LMConfig, lanes: int, active: int) -> dict:
     MoE layer), and ``moe_picks``, the router picks the ``active`` lanes
     need."""
     layers = cfg.n_layers - 1               # layer 0 is dense
-    return {"moe_rows": lanes * layers * lm.moe_ffn_decode_rows(cfg, 1),
+    return {"moe_rows": layers * lm.moe_ffn_decode_rows(cfg, lanes),
             "moe_picks": active * cfg.top_k * layers}
 
 
@@ -840,17 +817,19 @@ def decode_attn_work(cfg: LMConfig, lens, bs: int, nb: int,
 
 
 def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
-                      arena, wbids=None, kernel=None, interpret=None,
-                      backend=None, cascade=None):
+                      arena, backend, wbids=None, cascade=None,
+                      interpret=None):
     """One batched decode tick reading K/V **in place** from the block arena.
 
     The gather-free counterpart of ``vmap(decode_step)`` over slot lanes:
-    instead of materializing every lane's chain as a dense ``max_len``
-    cache, each attention layer reads its K/V through the lane's block
-    table (``lm.attn_decode_paged`` → ``attend_decode_paged`` in XLA, or
-    the ``kernels/paged_attn.py`` scalar-prefetch kernel with
-    ``kernel=True``), and the only persistent sequence-axis write is the
-    new token's single row per layer, scattered once after the layer scan.
+    the same layer stack (:func:`_decode_layers`), but instead of
+    materializing every lane's chain as a dense ``max_len`` cache, each
+    attention layer reads its K/V through the lane's block table
+    (``lm.attn_decode_paged`` → ``attend_decode_paged`` in XLA, or the
+    ``kernels/paged_attn.py`` scalar-prefetch kernel under
+    ``backend="pallas"``), and the only persistent sequence-axis write is
+    the new token's single row per layer, scattered once after the layer
+    scan.
 
     cache   slot-stacked non-sequence state, exactly the paged adapter's
             dense dict: "len" (S,) plus hybrid conv/ssm and encdec/vlm
@@ -860,48 +839,34 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
     lens    (S,) int32 per-lane lengths (== cache["len"]; the new token
             lands at position ``lens``).
     arena   :func:`init_paged_arena` dict (layer-leading block axis).
+    backend "xla" | "pallas" | "cascade" (see :mod:`repro.serve.backend`),
+            with the group metadata in ``cascade`` for the last.
     wbids   (S,) int32 arena block each lane's new row lands in — the
             caller routes lanes that must not write (inactive, at capacity,
             pre-copy-on-write) to the trash block.  ``None`` derives the
             block from the table, routing out-of-range lanes to block 0
             (the pool's reserved trash block).
 
-    Returns (new_arena, new_cache, logits (S, vocab_padded)).  With
-    ``kernel=False`` the logits are bitwise-identical to the gather tick /
+    Returns (new_arena, new_cache, logits (S, vocab_padded)).  Under
+    ``"xla"`` the logits are bitwise-identical to the gather tick /
     dense-adapter oracle (pinned per family in tests/test_paged_decode.py):
     every position a lane can read holds the same bits in both layouts and
     everything else is masked to NEG_INF before the softmax.
-
-    Maintenance note: the per-family layer bodies below deliberately
-    mirror :func:`decode_step` (only the cache plumbing differs — scan xs
-    are arena slices instead of per-layer dense caches, or none where the
-    Pallas kernel reads the whole arena at the layer index, and the write
-    is a row instead of a buffer).  Any numeric change to a family's decode
-    body must land in BOTH functions; the bitwise parity suite exists to
-    catch exactly that drift, so a paged-parity failure after touching
-    :func:`decode_step` means this copy is stale, not that paging broke.
     """
     fam = cfg.family
     assert fam in ("decoder", "moe", "hybrid", "encdec", "vlm"), \
         f"in-place paged decode: unsupported family {fam}"
-    # backend= is the per-layer read-path enum ("xla" | "pallas" |
-    # "cascade", see repro.serve.backend); kernel= survives as its
-    # deprecated boolean alias (True -> "pallas")
-    if backend is None:
-        backend = "pallas" if kernel else "xla"
     assert backend in ("xla", "pallas", "cascade"), \
         f"in-place paged decode: unknown backend {backend!r}"
     assert backend != "cascade" or cascade is not None, \
         "backend=\"cascade\" needs the group metadata in cascade="
-    # encdec/vlm cache full-dtype (init_cache ignores kv_quant there)
-    quant = cfg.kv_quant and fam not in ("encdec", "vlm")
-    assert not (quant and backend != "xla"), \
+    keys = _kv_keys(cfg)
+    assert not ("k_scale" in keys and backend != "xla"), \
         "in-place paged decode: only the XLA reference covers kv_quant"
     assert not (fam == "vlm" and backend != "xla"), \
         "in-place paged decode: only the XLA reference covers the vlm " \
         "grouped layout"
-    S = tokens.shape[0]
-    bs = arena["latent"].shape[-2] if cfg.mla else arena["k"].shape[-3]
+    bs = arena[keys[0]].shape[seq_axis(keys[0])]
     nb = tables.shape[1]
     pos = jnp.asarray(lens, jnp.int32)
     offs = pos % bs
@@ -909,13 +874,7 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
         blk = jnp.take_along_axis(tables, jnp.minimum(pos // bs, nb - 1)
                                   [:, None], axis=1)[:, 0]
         wbids = jnp.where(pos >= nb * bs, 0, blk)    # 0 = trash block
-    x = params["embed"][tokens]                       # (S, 1, d)
-    if cfg.pos_embedding == "sinusoidal":
-        i = jnp.arange(0, cfg.d_model, 2, dtype=jnp.float32)
-        ang = pos[:, None].astype(jnp.float32) / \
-            jnp.power(10000.0, i / cfg.d_model)
-        pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], -1)[:, None, :]
-        x = x + pe.astype(x.dtype)
+    x = _decode_embed(cfg, params, tokens, pos)       # (S, 1, d)
     new_cache = dict(cache)
     new_cache["len"] = pos + 1
 
@@ -925,12 +884,12 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
     # and tick.  The XLA reads take per-layer slices as scan inputs.
     whole = backend == "pallas"
 
-    def attn(lp, z, kv, layer, window=0):
-        """``kv``: this layer's arena slices (k, v[, k_scale, v_scale]),
-        empty when the kernel reads the whole arena at ``layer``.  Returns
-        (out, *rows): rows are the sequence-axis writes this layer owes
-        the arena — (k1, v1) plain, + (k1_scale, v1_scale) under the int8
-        kv_quant layout, or the one latent row under latent attention."""
+    def attend(lp, z, kv, layer, window=0):
+        """``kv``: this layer's arena slices in ``keys`` order, empty when
+        the kernel reads the whole arena at ``layer``.  Returns (out,
+        rows): the sequence-axis writes this layer owes the arena — (k1,
+        v1) plain, + (k1_scale, v1_scale) under the int8 kv_quant layout,
+        or the one latent row under latent attention."""
         if cfg.mla:
             out = lm.attn_decode_paged_mla(
                 cfg, lp, z, arena["latent"] if whole else kv[0], tables, pos,
@@ -947,158 +906,15 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
                                    scales=sc, layer=layer)
         return out[0], out[1:]
 
-    def layer_arenas(sl):
-        if whole:
-            return ()
-        if cfg.mla:
-            return (arena["latent"][sl],)
-        out = (arena["k"][sl], arena["v"][sl])
-        if quant:
-            out += (arena["k_scale"][sl], arena["v_scale"][sl])
-        return out
-
-    if fam in ("decoder", "moe"):
-        first = 1 if fam == "moe" else 0    # moe's layer 0 is dense0
-        L = cfg.n_layers - first
-
-        def body(x, inp):
-            lp, idx = inp[0], inp[-1]
-            h, rows = attn(lp["attn"], _norm_apply(cfg, lp["ln1"], x),
-                           inp[1:-1], idx + first,
-                           window=layer_window(cfg, idx))
-            x = x + h
-            z = _norm_apply(cfg, lp["ln2"], x)
-            if fam == "moe":
-                # per-lane dispatch groups of one token, exactly the
-                # vmapped dense tick's routing (a lane's output must not
-                # depend on which other lanes share its decode batch);
-                # decode_moe_work counts the rows this dispatch computes
-                y = jax.vmap(lambda zi: lm.moe_ffn_decode(
-                    cfg, lp["moe"], zi[None])[0][0])(z)
-            else:
-                y = _mlp_apply(cfg, lp["mlp"], z)
-            return x + y, rows
-
-        if fam == "moe":
-            p0 = jax.tree.map(lambda a: a[0], params["dense0"])
-            h, rows0 = attn(p0["attn"], _norm_apply(cfg, p0["ln1"], x),
-                            layer_arenas(0), 0)
-            x = x + h
-            x = x + _mlp_apply(cfg, p0["mlp"], _norm_apply(cfg, p0["ln2"], x))
-        x, rows = jax.lax.scan(
-            body, x, (params["blocks"],) + layer_arenas(slice(first, None))
-            + (jnp.arange(L, dtype=jnp.int32),))
-        if fam == "moe":
-            rows = tuple(jnp.concatenate([r0[None], r], 0)
-                         for r0, r in zip(rows0, rows))
-
-    elif fam == "hybrid":
-        def body(x, inp):
-            lp, conv_st, ssm_st, idx = inp[0], inp[-3], inp[-2], inp[-1]
-            z = _norm_apply(cfg, lp["ln1"], x)
-            att, kv_rows = attn(lp["attn"], z, inp[1:-3], idx,
-                                window=layer_window(cfg, idx))
-            xz = _proj(z, lp["in_proj"])
-            xm, gate = jnp.split(xz, 2, axis=-1)
-            xm, conv_st = _causal_conv(xm, lp["conv_w"], conv_st)
-            xm = jax.nn.silu(xm.astype(jnp.float32)).astype(x.dtype)
-            dtr = lp["dt_proj"].shape[0]
-            dbc = _proj(xm, lp["x_proj"])
-            dt = jax.nn.softplus(
-                _proj(dbc[..., :dtr], lp["dt_proj"]).astype(jnp.float32)
-                + lp["dt_bias"].astype(jnp.float32))
-            N = cfg.ssm_state
-            y1, ssm_st = ssm.selective_step(
-                xm[:, 0], dt[:, 0].astype(x.dtype), lp["A_log"],
-                dbc[:, 0, dtr:dtr + N], dbc[:, 0, dtr + N:], lp["D_skip"],
-                ssm_st)
-            y = (y1[:, None] * jax.nn.silu(gate.astype(jnp.float32)
-                                           ).astype(x.dtype))
-            y = _proj(y, lp["ssm_out"])
-            beta = lp["beta"].astype(jnp.float32)
-            mixed = (beta[0] * _norm_apply(cfg, lp["norm_attn"], att
-                                           ).astype(jnp.float32)
-                     + beta[1] * _norm_apply(cfg, lp["norm_ssm"], y
-                                             ).astype(jnp.float32)) * 0.5
-            x = x + mixed.astype(x.dtype)
-            x = x + _mlp_apply(cfg, lp["mlp"], _norm_apply(cfg, lp["ln2"], x))
-            return x, kv_rows + (conv_st, ssm_st)
-
-        x, outs = jax.lax.scan(
-            body, x, (params["blocks"],) + layer_arenas(slice(None))
-            + (jnp.moveaxis(cache["conv"], 1, 0)[:, :, 0],
-               jnp.moveaxis(cache["ssm"], 1, 0)[:, :, 0],
-               jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-        rows, (conv, ssm_s) = outs[:-2], outs[-2:]
-        new_cache["conv"] = jnp.moveaxis(conv, 1, 0)[:, :, None]
-        new_cache["ssm"] = jnp.moveaxis(ssm_s, 1, 0)[:, :, None]
-
-    elif fam == "encdec":
-        def body(x, inp):
-            lp, xk, xv, idx = inp[0], inp[-3], inp[-2], inp[-1]
-            h, rows = attn(lp["attn"], _norm_apply(cfg, lp["ln1"], x),
-                           inp[1:-3], idx)
-            x = x + h
-            q = _proj(_norm_apply(cfg, lp["ln_x"], x), lp["xattn"]["wq"],
-                      lp["xattn"].get("bq")).reshape(
-                S, 1, cfg.n_heads, cfg.d_head)
-            o = attention.attend_decode(q, xk, xv, xk.shape[1])
-            hx = _proj(o.reshape(S, 1, -1), lp["xattn"]["wo"],
-                       lp["xattn"].get("bo"))
-            gate = jnp.tanh(lp["gate_attn"].astype(jnp.float32)
-                            ).astype(x.dtype)
-            x = x + gate * hx
-            x = x + _mlp_apply(cfg, lp["mlp"], _norm_apply(cfg, lp["ln2"], x))
-            return x, rows
-
-        x, rows = jax.lax.scan(
-            body, x, (params["dec_blocks"],) + layer_arenas(slice(None))
-            + (jnp.moveaxis(cache["xk"], 1, 0)[:, :, 0],
-               jnp.moveaxis(cache["xv"], 1, 0)[:, :, 0],
-               jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-
-    elif fam == "vlm":
-        k_ = cfg.cross_every
-        G = cfg.n_layers // k_
-        self_pp = jax.tree.map(
-            lambda a: a.reshape((G, k_ - 1) + a.shape[1:]), params["blocks"])
-
-        def group(x, inp):
-            self_p, cross_p, kb_g, vb_g, kxb, vxb, xk, xv = inp
-
-            def inner(x, sinp):
-                lp, kb, vb = sinp
-                h, rows_i = attn(lp["attn"], _norm_apply(cfg, lp["ln1"], x),
-                                 (kb, vb), None)
-                x = x + h
-                x = x + _mlp_apply(cfg, lp["mlp"],
-                                   _norm_apply(cfg, lp["ln2"], x))
-                return x, rows_i
-            x, self_rows = jax.lax.scan(inner, x, (self_p, kb_g, vb_g))
-            h, x_rows = attn(cross_p["attn"],
-                             _norm_apply(cfg, cross_p["ln1"], x), (kxb, vxb),
-                             None)
-            x = x + h
-            q = _proj(_norm_apply(cfg, cross_p["ln_x"], x),
-                      cross_p["xattn"]["wq"]).reshape(
-                S, 1, cfg.n_heads, cfg.d_head)
-            o = attention.attend_decode(q, xk, xv, xk.shape[1])
-            hx = _proj(o.reshape(S, 1, -1), cross_p["xattn"]["wo"])
-            gate = jnp.tanh(cross_p["gate_attn"].astype(jnp.float32)
-                            ).astype(x.dtype)
-            x = x + gate * hx
-            x = x + _mlp_apply(cfg, cross_p["mlp"],
-                               _norm_apply(cfg, cross_p["ln2"], x))
-            return x, (self_rows, x_rows)
-
-        x, (self_rows, x_rows) = jax.lax.scan(
-            group, x, (self_pp, params["cross_blocks"], arena["k"],
-                       arena["v"], arena["kx_self"], arena["vx_self"],
-                       jnp.moveaxis(cache["xk"], 1, 0)[:, :, 0],
-                       jnp.moveaxis(cache["xv"], 1, 0)[:, :, 0]))
-        # grouped rows: self k/v (G, k-1, S, Hkv, Dh), cross-layer self
-        # k/v (G, S, Hkv, Dh) — ranks the generalized write below absorbs
-        rows = (self_rows[0], self_rows[1], x_rows[0], x_rows[1])
+    # slot-stacked (S, L, 1, ...) state <-> the layer-leading (L, S, ...)
+    # state the layer scan reads and writes
+    state_keys = _STATE_KEYS.get(fam, ())
+    x, rows, state = _decode_layers(
+        cfg, params, x, attend,
+        () if whole else tuple(arena[key] for key in keys),
+        tuple(jnp.moveaxis(cache[key], 1, 0)[:, :, 0] for key in state_keys))
+    for key, st in zip(state_keys, state):
+        new_cache[key] = jnp.moveaxis(st, 1, 0)[:, :, None]
 
     # the tick's only sequence-axis write: one (S, Hkv, Dh) row per layer
     # (+ the f32 scale rows under kv_quant), landed at (block, offset) per
@@ -1108,12 +924,6 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
     # of functionally rebuilding every layer slice (XLA donation already
     # covers the .at[].set reference leg).
     new_arena = dict(arena)
-    if fam == "vlm":
-        row_keys = ("k", "v", "kx_self", "vx_self")
-    elif cfg.mla:
-        row_keys = ("latent",)
-    else:
-        row_keys = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
     if backend == "pallas" and cfg.mla:
         from repro.kernels.paged_attn import scatter_latent_rows
         new_arena["latent"] = scatter_latent_rows(
@@ -1124,7 +934,7 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
             arena["k"], arena["v"], rows[0], rows[1], wbids, offs,
             interpret=interpret)
     else:
-        for key, r in zip(row_keys, rows):
+        for key, r in zip(keys, rows):
             # leading layer axes vary per key (one for decoder k/v, two
             # for vlm's grouped self k/v, one for its cross-layer self
             # k/v); the (block, B=1, offset) triple sits just before the
@@ -1132,9 +942,4 @@ def decode_step_paged(cfg: LMConfig, params, cache, tokens, *, tables, lens,
             idx = (slice(None),) * arena_block_axis(arena[key], key) + \
                 (wbids, 0, offs)
             new_arena[key] = arena[key].at[idx].set(r)
-
-    x = _norm_apply(cfg, params["final_norm"], x)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head,
-                        preferred_element_type=jnp.float32)
-    return new_arena, new_cache, logits[:, 0]
+    return new_arena, new_cache, _logits(cfg, params, x)

@@ -13,25 +13,26 @@ Decode tick (one jitted call, fixed shapes)
     The default tick is **in place**: :func:`engine.decode_step_paged`
     threads ``(tables, lens, arena)`` down into the attention layers, which
     read K/V straight out of the block arena (``attend_decode_paged`` in
-    XLA, or the ``kernels/paged_attn.py`` scalar-prefetch kernel with
-    ``kernel=True``) and write back exactly one row per layer — the new
-    token's position.  No dense per-slot cache is ever materialized and no
-    block is rescattered.  Inactive lanes write into the reserved trash
+    XLA, or the ``kernels/paged_attn.py`` scalar-prefetch kernel under
+    ``backend="pallas"``) and write back exactly one row per layer — the
+    new token's position.  No dense per-slot cache is ever materialized and
+    no block is rescattered.  Inactive lanes write into the reserved trash
     block 0, so the call never changes shape.  Because every position a
     lane can read (< len) holds the same bits in both layouts and
     everything else is masked at NEG_INF before the softmax, in-place
     paged decode is *bitwise* identical to dense decode — pinned per
-    family in tests/test_paged_decode.py.
+    family in tests/test_paged_decode.py.  Both run one layer stack
+    (``engine._decode_layers``); only the cache plumbing differs.
 
-    The PR 2 gather tick (gather each chain into the dense layout ->
-    vmapped :func:`engine.decode_step` -> scatter one block back) is kept
-    as ``inplace=False``: it is the parity oracle the in-place path is
-    asserted bitwise against.  Since PR 8 the in-place tick covers every
-    paged family — vlm's grouped cache (two leading layer axes) rides it
-    too, so decode slices of the disaggregated mesh never need the gather
-    path.  The int8 ``kv_quant`` layout rides the in-place tick as well:
-    the new row is quantized post-RoPE and written as one int8 row + one
-    f32 scale row per layer, and the attention read dequantizes the
+    The gather tick (gather each chain into the dense layout -> vmapped
+    :func:`engine.decode_step` -> scatter one block back) is kept as
+    ``backend="gather"``: it is the parity oracle the in-place path is
+    asserted bitwise against, and nothing else.  The in-place tick covers
+    every paged family — vlm's grouped cache (two leading layer axes)
+    included, so decode slices of the disaggregated mesh never need the
+    gather path.  The int8 ``kv_quant`` layout rides the in-place tick as
+    well: the new row is quantized post-RoPE and written as one int8 row +
+    one f32 scale row per layer, and the attention read dequantizes the
     gathered view — bitwise against the gather-tick oracle.
 
     Under the Pallas backend the arena lies in HBM in the layout the
@@ -548,10 +549,9 @@ class PagedKVSlotAdapter:
         return cache, logits, snapshots
 
     def _tick_impl(self, p, arena, dense, tables, tokens, mask, wbids):
-        """Legacy gather tick (PR 2; ``inplace=False``): gather -> vmapped
-        decode_step -> scatter the written blocks.  Kept as the parity
-        oracle for the in-place tick and as the fallback for the layouts it
-        does not cover (vlm, kv_quant)."""
+        """The gather tick (``backend="gather"``): gather -> vmapped
+        decode_step -> scatter the written blocks.  Kept only as the parity
+        oracle for the in-place tick, which covers every paged layout."""
         cache = dict(dense)
         for key in self.seq_keys:
             ax = self._bax[key]

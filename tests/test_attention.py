@@ -157,8 +157,8 @@ def test_absorbed_latent_decode_matches_expanded_form():
     full, rows = lm._mla_apply(cfg, p, x, jnp.arange(S)[None])
     cache = jnp.zeros((1, 16, cfg.latent_width)).at[:, :S - 1].set(
         rows[:, :S - 1])
-    out, cache = engine._decode_attn_mla(cfg, p, x[:, S - 1:], cache,
-                                         jnp.int32(S - 1))
+    out, (cache,) = engine._dense_attend(cfg, jnp.int32(S - 1))(
+        p, x[:, S - 1:], (cache,), 0)
     np.testing.assert_allclose(np.asarray(out[:, 0]),
                                np.asarray(full[:, S - 1]), rtol=1e-5,
                                atol=1e-5)

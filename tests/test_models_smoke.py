@@ -1,6 +1,8 @@
 """Per-architecture smoke tests: reduced same-family config, one forward /
 train-grad step on CPU, asserting output shapes and no NaNs; plus one
 prefill+decode round per family."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,18 +55,46 @@ def test_prefill_decode(arch):
         params, batch)
     assert logits.shape == (B, cfg.vocab_padded)
     assert np.isfinite(np.asarray(logits, np.float32)).all(), arch
-    # grow attention caches so decode has a free slot
+    nc, lg = jax.jit(lambda p, c, t: engine.decode_step(cfg, p, c, t))(
+        params, _grow(cache, 8), batch["tokens"][:, :1])
+    assert lg.shape == (B, cfg.vocab_padded)
+    assert np.isfinite(np.asarray(lg, np.float32)).all(), arch
+    assert int(nc["len"]) == S + 1
+
+
+def _grow(cache, n):
+    """A prefill cache with ``n`` free positions on each attention
+    cache's sequence axis, so decode has somewhere to write."""
     grown = dict(cache)
     for k in ("k", "v", "kx_self", "vx_self", "latent"):
         if k in grown:
             pad = [(0, 0)] * grown[k].ndim
-            pad[engine.seq_axis(k)] = (0, 8)
+            pad[engine.seq_axis(k)] = (0, n)
             grown[k] = jnp.pad(grown[k], pad)
-    nc, lg = jax.jit(lambda p, c, t: engine.decode_step(cfg, p, c, t))(
-        params, grown, batch["tokens"][:, :1])
-    assert lg.shape == (B, cfg.vocab_padded)
-    assert np.isfinite(np.asarray(lg, np.float32)).all(), arch
-    assert int(nc["len"]) == S + 1
+    return grown
+
+
+@pytest.mark.parametrize("arch", ["deepseek_moe_16b", "moonshot_v1_16b_a3b"])
+def test_batched_decode_matches_one_lane_calls(arch):
+    """Decode routes each lane's token as an expert dispatch group of its
+    own, so a lane's logits do not depend on the other lanes: a B=3
+    ``decode_step`` matches three B=1 calls (float32 params)."""
+    cfg = dataclasses.replace(configs.smoke_config(arch),
+                              param_dtype="float32")
+    params, _ = lm.init(jax.random.key(2), cfg, {})
+    B, S = 3, 12
+    batch = _batch(cfg, B, S, seed=5)
+    cache = _grow(engine.prefill(cfg, params, batch)[0], 4)
+    tokens = batch["tokens"][:, ::-1][:, :1]
+    _, logits = engine.decode_step(cfg, params, cache, tokens)
+    for b in range(B):
+        lane = {k: a if k == "len" else a[:, b:b + 1]
+                for k, a in cache.items()}
+        _, one = engine.decode_step(cfg, params, lane, tokens[b:b + 1])
+        np.testing.assert_allclose(np.asarray(logits[b]),
+                                   np.asarray(one[0]), rtol=1e-5,
+                                   atol=1e-5)
+        assert int(jnp.argmax(logits[b])) == int(jnp.argmax(one[0]))
 
 
 @pytest.mark.parametrize("arch", configs.ARCHS)
